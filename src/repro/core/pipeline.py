@@ -178,8 +178,14 @@ def pipeline_loss(cfg: ModelConfig, params: Params, batch: Dict[str, jax.Array],
     # axis 1 of (VPP, PP, Lc, ...) interleaved stacks; per-stage chunk row v
     blocks_axis = 0 if vpp == 1 else 1
     win_axis = None if win_stages is None else blocks_axis
+    # on a mesh the stage axis lives on "pp": say so, so a per-shard kernel
+    # (shard_map) inside a stage runs on its own stage's devices only
+    rules = sharding.active_rules()
+    spmd = ("pp" if rules is not None and rules.mesh.shape.get("pp", 1) > 1
+            else None)
     vstage = jax.vmap(stage_apply,
-                      in_axes=(blocks_axis, win_axis, 0, 0, seg_axis))
+                      in_axes=(blocks_axis, win_axis, 0, 0, seg_axis),
+                      spmd_axis_name=spmd)
 
     def embed_mb(tok, seg):
         x = L.embed_lookup(params["embed"], tok, dt)

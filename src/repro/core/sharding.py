@@ -84,6 +84,13 @@ def axis_rules(mesh: Mesh, mapping: Dict[str, Any]):
         _state.rules = old
 
 
+def active_rules() -> Optional[AxisRules]:
+    """The axis rules installed by the enclosing ``axis_rules`` block (None
+    outside one): code that must place work per shard — Pallas kernels, which
+    XLA cannot partition — reads the mesh from here."""
+    return _rules()
+
+
 def logical(*names: Optional[str]) -> Tuple[Optional[str], ...]:
     return names
 

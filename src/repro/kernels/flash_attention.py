@@ -52,7 +52,7 @@ def _block_relevant(q_start, k_start, *, bq: int, bk: int, causal: bool,
     """True iff any (q, k) pair in the (bq, bk) tile survives the mask —
     entirely masked-out tiles do no work (fwd AND bwd block skipping).
 
-    ``qseg``/``kseg`` are the tile's (bq,)/(bk,) segment-id vectors: when the
+    ``qseg``/``kseg`` are the tile's (bq, 1)/(1, bk) segment ids: when the
     id ranges cannot intersect, no ``seg[q] == seg[k]`` pair exists — a
     conservative interval test that is exact for the monotone ids the packer
     emits and safe (never skips live work) for any other layout."""
@@ -77,7 +77,7 @@ def _tile_mask(q_start, k_start, *, bq: int, bk: int, causal: bool,
     if window is not None:
         mask &= kpos > qpos - window
     if qseg is not None:
-        mask &= qseg[:, None] == kseg[None, :]
+        mask &= qseg == kseg                                 # (bq,1)==(1,bk)
     return mask
 
 
@@ -90,7 +90,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, bq: int, bk: int,
                 scale: float, has_seg: bool):
     if has_seg:
         qs_ref, ks_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref = rest
-        qseg, kseg = qs_ref[0], ks_ref[0]                    # (bq,), (bk,)
+        qseg, kseg = qs_ref[0], ks_ref[0, 0]                 # (bq,1), (1,bk)
     else:
         o_ref, lse_ref, acc_ref, m_ref, l_ref = rest
         qseg = kseg = None
@@ -116,19 +116,19 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, bq: int, bk: int,
         mask = _tile_mask(q_start, k_start, bq=bq, bk=bk, causal=causal,
                           window=window, qseg=qseg, kseg=kseg)
         s = jnp.where(mask, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1))
+        m_prev = m_ref[...]                                  # (bq, 1)
+        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_cur)
         # zero masked entries explicitly: exp(-inf − -inf) = 1 otherwise
-        p = jnp.exp(s - m_cur[:, None]) * mask
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + p @ v
+        p = jnp.exp(s - m_cur) * mask
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + p @ v
         m_ref[...] = m_cur
 
     @pl.when(ik == n_kv_blocks - 1)
     def _finalize():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
         lse_ref[0, 0] = m_ref[...] + jnp.log(l)
 
 
@@ -142,8 +142,17 @@ def _pad_head_dim(x: jax.Array) -> jax.Array:
     return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, Dp - D)])
 
 
+def _seg_operands(segment_ids, bq: int, bk: int):
+    """Segment ids laid out so every block's two minor dims are TPU-tileable:
+    the q side as a (B, S, 1) column (block (1, bq, 1) → a (bq, 1) tile) and
+    the k side as (B, S/bk, 1, bk) rows (block (1, 1, 1, bk) → a (1, bk)
+    tile), so the in-tile mask is one broadcast compare with no relayout."""
+    B, S = segment_ids.shape
+    return segment_ids[:, :, None], segment_ids.reshape(B, S // bk, 1, bk)
+
+
 def _forward(q, k, v, segment_ids, causal, window, bq, bk, interpret):
-    """Shared fwd implementation → (out (B,Sq,Hq,D), lse (B,Hq,Sq) f32)."""
+    """Shared fwd implementation → (out (B,Sq,Hq,D), lse (B,Hq,Sq,1) f32)."""
     B, Sq, Hq, D = q.shape
     Sk, Hkv = k.shape[1], k.shape[2]
     g = Hq // Hkv
@@ -174,10 +183,10 @@ def _forward(q, k, v, segment_ids, causal, window, bq, bk, interpret):
     inputs = [qt, kt, vt]
     if has_seg:
         in_specs += [
-            pl.BlockSpec((1, bq), lambda b, h, iq, ik: (b, iq)),
-            pl.BlockSpec((1, bk), lambda b, h, iq, ik: (b, ik)),
+            pl.BlockSpec((1, bq, 1), lambda b, h, iq, ik: (b, iq, 0)),
+            pl.BlockSpec((1, 1, 1, bk), lambda b, h, iq, ik: (b, ik, 0, 0)),
         ]
-        inputs += [segment_ids, segment_ids]
+        inputs += list(_seg_operands(segment_ids, bq, bk))
 
     out, lse = pl.pallas_call(
         kernel,
@@ -185,11 +194,11 @@ def _forward(q, k, v, segment_ids, causal, window, bq, bk, interpret):
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((1, 1, bq, Dp), lambda b, h, iq, ik: (b, h, iq, 0)),
-            pl.BlockSpec((1, 1, bq), lambda b, h, iq, ik: (b, h, iq)),
+            pl.BlockSpec((1, 1, bq, 1), lambda b, h, iq, ik: (b, h, iq, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, Hq, Sq, Dp), q.dtype),
-            jax.ShapeDtypeStruct((B, Hq, Sq), jnp.float32),
+            jax.ShapeDtypeStruct((B, Hq, Sq, 1), jnp.float32),
         ],
         scratch_shapes=_scratch(bq, Dp),
         compiler_params=_compiler_params(),
@@ -207,7 +216,7 @@ def _delta_kernel(o_ref, do_ref, delta_ref):
     shared by the dQ and dK sweeps."""
     delta_ref[0, 0] = jnp.sum(
         o_ref[0, 0].astype(jnp.float32) * do_ref[0, 0].astype(jnp.float32),
-        axis=1)
+        axis=1, keepdims=True)
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
@@ -215,7 +224,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
                window: Optional[int], scale: float, has_seg: bool):
     if has_seg:
         qs_ref, ks_ref, dq_ref, acc_ref = rest
-        qseg, kseg = qs_ref[0], ks_ref[0]
+        qseg, kseg = qs_ref[0], ks_ref[0, 0]                 # (bq,1), (1,bk)
     else:
         dq_ref, acc_ref = rest
         qseg = kseg = None
@@ -239,9 +248,9 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
         mask = _tile_mask(q_start, k_start, bq=bq, bk=bk, causal=causal,
                           window=window, qseg=qseg, kseg=kseg)
         s = jnp.where(mask, (q @ k.T) * scale, NEG_INF)
-        p = jnp.exp(s - lse_ref[0, 0][:, None]) * mask       # recomputed probs
+        p = jnp.exp(s - lse_ref[0, 0]) * mask               # recomputed probs
         dp = do @ v.T                                        # (bq, bk)
-        ds = p * (dp - delta_ref[0, 0][:, None])
+        ds = p * (dp - delta_ref[0, 0])
         acc_ref[...] += (ds @ k) * scale
 
     @pl.when(ik == n_kv_blocks - 1)
@@ -254,7 +263,7 @@ def _dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref, *rest,
                 window: Optional[int], scale: float, has_seg: bool):
     if has_seg:
         ks_ref, qs_ref, dk_ref, dv_ref, dk_acc, dv_acc = rest
-        qseg, kseg = qs_ref[0], ks_ref[0]
+        qseg, kseg = qs_ref[0], ks_ref[0, 0]                 # (bq,1), (1,bk)
     else:
         dk_ref, dv_ref, dk_acc, dv_acc = rest
         qseg = kseg = None
@@ -279,9 +288,9 @@ def _dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref, *rest,
         mask = _tile_mask(q_start, k_start, bq=bq, bk=bk, causal=causal,
                           window=window, qseg=qseg, kseg=kseg)
         s = jnp.where(mask, (q @ k.T) * scale, NEG_INF)
-        p = jnp.exp(s - lse_ref[0, 0][:, None]) * mask       # (bq, bk)
+        p = jnp.exp(s - lse_ref[0, 0]) * mask               # (bq, bk)
         dp = do @ v.T
-        ds = p * (dp - delta_ref[0, 0][:, None])
+        ds = p * (dp - delta_ref[0, 0])
         dv_acc[...] += p.T @ do
         dk_acc[...] += (ds.T @ q) * scale
 
@@ -316,8 +325,8 @@ def _backward(q, k, v, segment_ids, o, lse, do, causal, window, bq, bk,
             pl.BlockSpec((1, 1, bq, Dp), lambda b, h, iq: (b, h, iq, 0)),
             pl.BlockSpec((1, 1, bq, Dp), lambda b, h, iq: (b, h, iq, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, bq), lambda b, h, iq: (b, h, iq)),
-        out_shape=jax.ShapeDtypeStruct((B, Hq, Sq), jnp.float32),
+        out_specs=pl.BlockSpec((1, 1, bq, 1), lambda b, h, iq: (b, h, iq, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, Hq, Sq, 1), jnp.float32),
         compiler_params=_compiler_params(("parallel",) * 3),
         interpret=interpret,
     )(ot, dot)
@@ -329,16 +338,17 @@ def _backward(q, k, v, segment_ids, o, lse, do, causal, window, bq, bk,
         pl.BlockSpec((1, 1, bk, Dp), lambda b, h, iq, ik: (b, h // g, ik, 0)),
         pl.BlockSpec((1, 1, bk, Dp), lambda b, h, iq, ik: (b, h // g, ik, 0)),
         pl.BlockSpec((1, 1, bq, Dp), lambda b, h, iq, ik: (b, h, iq, 0)),
-        pl.BlockSpec((1, 1, bq), lambda b, h, iq, ik: (b, h, iq)),
-        pl.BlockSpec((1, 1, bq), lambda b, h, iq, ik: (b, h, iq)),
+        pl.BlockSpec((1, 1, bq, 1), lambda b, h, iq, ik: (b, h, iq, 0)),
+        pl.BlockSpec((1, 1, bq, 1), lambda b, h, iq, ik: (b, h, iq, 0)),
     ]
     dq_inputs = [qt, kt, vt, dot, lse, delta]
     if has_seg:
+        qseg, kseg = _seg_operands(segment_ids, bq, bk)
         dq_in_specs += [
-            pl.BlockSpec((1, bq), lambda b, h, iq, ik: (b, iq)),
-            pl.BlockSpec((1, bk), lambda b, h, iq, ik: (b, ik)),
+            pl.BlockSpec((1, bq, 1), lambda b, h, iq, ik: (b, iq, 0)),
+            pl.BlockSpec((1, 1, 1, bk), lambda b, h, iq, ik: (b, ik, 0, 0)),
         ]
-        dq_inputs += [segment_ids, segment_ids]
+        dq_inputs += [qseg, kseg]
 
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, bq=bq, bk=bk, n_kv_blocks=nk,
@@ -360,16 +370,16 @@ def _backward(q, k, v, segment_ids, o, lse, do, causal, window, bq, bk,
         pl.BlockSpec((1, 1, bk, Dp), lambda b, h, ik, iq: (b, h // g, ik, 0)),
         pl.BlockSpec((1, 1, bq, Dp), lambda b, h, ik, iq: (b, h, iq, 0)),
         pl.BlockSpec((1, 1, bq, Dp), lambda b, h, ik, iq: (b, h, iq, 0)),
-        pl.BlockSpec((1, 1, bq), lambda b, h, ik, iq: (b, h, iq)),
-        pl.BlockSpec((1, 1, bq), lambda b, h, ik, iq: (b, h, iq)),
+        pl.BlockSpec((1, 1, bq, 1), lambda b, h, ik, iq: (b, h, iq, 0)),
+        pl.BlockSpec((1, 1, bq, 1), lambda b, h, ik, iq: (b, h, iq, 0)),
     ]
     dkv_inputs = [kt, vt, qt, dot, lse, delta]
     if has_seg:
         dkv_in_specs += [
-            pl.BlockSpec((1, bk), lambda b, h, ik, iq: (b, ik)),
-            pl.BlockSpec((1, bq), lambda b, h, ik, iq: (b, iq)),
+            pl.BlockSpec((1, 1, 1, bk), lambda b, h, ik, iq: (b, ik, 0, 0)),
+            pl.BlockSpec((1, bq, 1), lambda b, h, ik, iq: (b, iq, 0)),
         ]
-        dkv_inputs += [segment_ids, segment_ids]
+        dkv_inputs += [kseg, qseg]
 
     dkh, dvh = pl.pallas_call(
         functools.partial(_dkv_kernel, bq=bq, bk=bk, n_q_blocks=nq,
@@ -453,12 +463,12 @@ def _scratch(bq: int, D: int):
     from jax.experimental.pallas import tpu as pltpu
     return [
         pltpu.VMEM((bq, D), jnp.float32),   # acc
-        pltpu.VMEM((bq,), jnp.float32),     # running max m
-        pltpu.VMEM((bq,), jnp.float32),     # running sum l
+        pltpu.VMEM((bq, 1), jnp.float32),   # running max m
+        pltpu.VMEM((bq, 1), jnp.float32),   # running sum l
     ]
 
 
 def _compiler_params(dimension_semantics=("parallel", "parallel", "parallel",
                                           "arbitrary")):
-    from repro.kernels.ops import tpu_compiler_params
-    return tpu_compiler_params(dimension_semantics=dimension_semantics)
+    from jax.experimental.pallas import tpu as pltpu
+    return pltpu.CompilerParams(dimension_semantics=dimension_semantics)

@@ -1,5 +1,5 @@
 """Jit'd dispatch wrappers: model code calls these; they pick the Pallas
-kernel (TPU target / interpret validation) and fall back to the jnp oracle.
+kernel (compiled on TPU, interpreted on CPU) and fall back to the jnp oracle.
 """
 
 from __future__ import annotations
@@ -7,20 +7,18 @@ from __future__ import annotations
 from typing import Optional
 
 import jax
+import numpy as np
+from jax.sharding import PartitionSpec as P
 
+from repro.core import sharding as shd
 from repro.kernels import ref
 from repro.runtime import flags
 
 
-def tpu_compiler_params(**kwargs):
-    """Version-compat shim: ``pltpu.TPUCompilerParams`` (jax <= 0.4.x) was
-    renamed ``pltpu.CompilerParams`` upstream.  Kernels build their compiler
-    params through here so they run on either side of the rename."""
-    from jax.experimental.pallas import tpu as pltpu
-    cls = getattr(pltpu, "CompilerParams", None)
-    if cls is None:
-        cls = pltpu.TPUCompilerParams
-    return cls(**kwargs)
+def _interpret() -> bool:
+    """Pallas interpret mode is decided by the backend alone: the CPU runs
+    the kernels through the interpreter (validation), a TPU compiles them."""
+    return jax.default_backend() == "cpu"
 
 
 def _flash_blocks(Sq: int, Sk: int):
@@ -55,6 +53,39 @@ def flash_supported(q, k, *, causal: bool = True,
     return Sq % bq == 0 and Sk % bk == 0
 
 
+def _per_shard(fn, q, k, v, segment_ids):
+    """Run ``fn(q, k, v, segment_ids)`` once per shard of the active mesh.
+
+    XLA cannot partition a Pallas kernel, so on a multi-device mesh the call
+    goes through ``shard_map``: rows over the data axes, heads over tp (when
+    both the query and the KV heads divide), sequence whole.  A vmap with an
+    ``spmd_axis_name`` (the pipeline's stage axis) adds its own mesh axis."""
+    rules = shd.active_rules()
+    if rules is None or rules.mesh.size == 1:
+        return fn(q, k, v, segment_ids)
+    mesh = rules.mesh
+
+    def ways(ax):
+        axes = ax if isinstance(ax, tuple) else (ax,)
+        return int(np.prod([mesh.shape[a] for a in axes if a is not None]))
+
+    rows = rules.resolve(("batch",))[0]
+    if rows is not None and q.shape[0] % ways(rows):
+        rows = None
+    heads = rules.resolve(("tp",))[0]
+    if heads is not None and (q.shape[2] % ways(heads)
+                              or k.shape[2] % ways(heads)):
+        heads = None
+    qkv = P(rows, None, heads, None)
+    seg = P(rows, None)
+    if segment_ids is None:
+        return jax.shard_map(lambda q, k, v: fn(q, k, v, None), mesh=mesh,
+                             in_specs=(qkv,) * 3, out_specs=qkv,
+                             check_vma=False)(q, k, v)
+    return jax.shard_map(fn, mesh=mesh, in_specs=(qkv,) * 3 + (seg,),
+                         out_specs=qkv, check_vma=False)(q, k, v, segment_ids)
+
+
 def flash_attention(q, k, v, *, causal: bool = True,
                     window: Optional[int] = None,
                     segment_ids=None) -> jax.Array:
@@ -66,9 +97,13 @@ def flash_attention(q, k, v, *, causal: bool = True,
         return ref.mha_reference(q, k, v, causal=causal, window=window,
                                  segment_ids=segment_ids)
     bq, bk = _flash_blocks(q.shape[1], k.shape[1])
-    return fa.flash_attention(q, k, v, segment_ids=segment_ids, causal=causal,
-                              window=window, bq=bq, bk=bk,
-                              interpret=flags.pallas_interpret())
+
+    def kernel(q, k, v, segment_ids):
+        return fa.flash_attention(q, k, v, segment_ids=segment_ids,
+                                  causal=causal, window=window, bq=bq, bk=bk,
+                                  interpret=_interpret())
+
+    return _per_shard(kernel, q, k, v, segment_ids)
 
 
 def decode_attention(q, k, v, kpos, *, t, window: Optional[int] = None) -> jax.Array:
@@ -78,7 +113,7 @@ def decode_attention(q, k, v, kpos, *, t, window: Optional[int] = None) -> jax.A
         return ref.decode_attention_reference(q, k, v, kpos, t=t, window=window)
     bk = 512 if S % 512 == 0 else 128
     return da.decode_attention(q, k, v, kpos, t=t, window=window, bk=bk,
-                               interpret=flags.pallas_interpret())
+                               interpret=_interpret())
 
 
 def paged_decode_attention(q, k_pool, v_pool, page_table, *, ts,
@@ -89,17 +124,17 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, *, ts,
     doesn't fill a TPU lane tile fall back to the gather-einsum oracle."""
     from repro.kernels import decode_attention as da
     ps = k_pool.shape[1]
-    if ps % 128 and not flags.pallas_interpret():
+    if ps % 128 and not _interpret():
         return ref.paged_decode_attention_reference(q, k_pool, v_pool,
                                                     page_table, ts=ts,
                                                     window=window)
     return da.paged_decode_attention(q, k_pool, v_pool, page_table, ts=ts,
                                      window=window,
-                                     interpret=flags.pallas_interpret())
+                                     interpret=_interpret())
 
 
 def rmsnorm(x, scale, *, eps: float = 1e-6) -> jax.Array:
     if not flags.use_fused_rmsnorm():
         return ref.rmsnorm_reference(x, scale, eps=eps)
     from repro.kernels import rmsnorm as rn
-    return rn.rmsnorm(x, scale, eps=eps, interpret=flags.pallas_interpret())
+    return rn.rmsnorm(x, scale, eps=eps, interpret=_interpret())

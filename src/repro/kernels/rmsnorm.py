@@ -11,8 +11,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import ops
-
 
 def _rmsnorm_kernel(x_ref, s_ref, o_ref, *, eps: float):
     x = x_ref[...].astype(jnp.float32)
@@ -42,7 +40,7 @@ def rmsnorm(x: jax.Array, scale: jax.Array, *, eps: float = 1e-6,
         ],
         out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, d), x.dtype),
-        compiler_params=ops.tpu_compiler_params(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
     )(x2, scale)
     return out.reshape(orig_shape)

@@ -119,9 +119,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool, out_dir: Path,
                     cfg, plan, mesh, shape, prefill_last_only=prefill_last_only)
             compiled = lowered.compile()
         mem = compiled.memory_analysis()
-        cost = compiled.cost_analysis()
-        if isinstance(cost, (list, tuple)):   # older jax: one dict per device
-            cost = cost[0] if cost else {}
+        cost = compiled.cost_analysis() or {}
         hlo = compiled.as_text()
         coll = collective_bytes(hlo)          # body-once (raw) counts
         # Pallas kernels are opaque custom-calls: credit the flash matmuls

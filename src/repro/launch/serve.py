@@ -20,6 +20,7 @@ import time
 import jax
 import numpy as np
 
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.session import InferenceSession
 
 
@@ -90,4 +91,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -3,10 +3,10 @@
   PYTHONPATH=src python -m repro.launch.train --arch granite_3_2b \
       --steps 200 --seq 256 --batch 32 --reduced --ckpt-dir /tmp/ckpt
 
-On this CPU container ``--reduced`` trains the smoke-size config for real
-(loss goes down); on a TPU fleet the same driver runs the full config under
-the recipe mesh.  SLURM/launcher integration: one process per host, jax
-distributed init from env (SLURM_PROCID etc.) — see launch/slurm.sh.
+On a CPU ``--reduced`` trains the smoke-size config for real (loss goes
+down).  A plan spanning more than one device (``--tp``/``--pp``/``--dp``)
+runs on the recipe mesh built from the local devices, e.g. one four-chip
+v5e host as ``--pp 2 --tp 2``.
 """
 
 from __future__ import annotations
@@ -14,9 +14,13 @@ from __future__ import annotations
 import argparse
 import time
 
+import jax
+
 from repro.core import stepfn
 from repro.core.recipe import ParallelismConfig
 from repro.data import DataConfig
+from repro.launch.mesh import describe, make_plan_mesh
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.session import TrainSession
 
 
@@ -31,6 +35,7 @@ def main(argv=None):
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--lr", type=float, default=3e-4)
+    ap.add_argument("--tp", type=int, default=1)
     ap.add_argument("--pp", type=int, default=1)
     ap.add_argument("--gas", type=int, default=1)
     ap.add_argument("--zero", type=int, default=1)
@@ -55,7 +60,8 @@ def main(argv=None):
                          "(repeatable; exercises the skip-consensus vote)")
     args = ap.parse_args(argv)
 
-    plan = ParallelismConfig(pp=args.pp, gas=max(args.gas, args.pp),
+    plan = ParallelismConfig(tp=args.tp, pp=args.pp,
+                             gas=max(args.gas, args.pp),
                              zero_stage=args.zero, dp=args.dp)
     tcfg = stepfn.TrainConfig(
         peak_lr=args.lr, total_steps=args.steps,
@@ -69,13 +75,21 @@ def main(argv=None):
         tcfg = _dc.replace(tcfg, resilience=ResilienceConfig(
             consensus_replicas=args.fleet_replicas))
 
+    # a plan wider than one device runs on its recipe mesh when the host has
+    # the devices; on fewer, the schedule runs on one device (dp then only
+    # counts replica groups for the skip vote and the fleet controller)
+    devices = jax.devices()
+    mesh = (make_plan_mesh(plan, devices[:plan.world])
+            if 1 < plan.world <= len(devices) else None)
     sess = TrainSession.from_recipe(
         args.arch, reduced=args.reduced, plan=plan, train_cfg=tcfg,
-        data_cfg=DataConfig(seq_len=args.seq, global_batch=args.batch))
+        data_cfg=DataConfig(seq_len=args.seq, global_batch=args.batch),
+        mesh=mesh)
     for k, v in sess.advice.items():
         print(f"[advisor:{k}] {v}")
     print(f"[train] {sess.cfg.name}: {sess.n_params/1e6:.1f}M params, "
-          f"plan={sess.plan}")
+          f"plan={sess.plan}, "
+          + (describe(mesh) if mesh is not None else "one device"))
 
     def parse_pairs(items):
         return {int(a): int(b) for a, b in
@@ -117,4 +131,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -162,9 +162,11 @@ def lm_init(key, cfg: ModelConfig) -> Params:
         p["pre_blocks"] = [block_init(jax.random.fold_in(kb, 1000 + i), cfg, kind=k)
                            for i, k in pre]
     if n_scanned:
+        # one vmapped block init (bitwise equal to stacking per-layer inits):
+        # a jitted init of a deep model compiles one block, not n_scanned
         keys = jax.random.split(kb, n_scanned)
-        stacked = [block_init(keys[i], cfg, kind=scanned_kind) for i in range(n_scanned)]
-        p["blocks"] = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *stacked)
+        p["blocks"] = jax.vmap(
+            lambda k: block_init(k, cfg, kind=scanned_kind))(keys)
     p["final_norm"] = layers.norm_init(cfg.norm, cfg.d_model)
     if not cfg.tie_embeddings:
         p["lm_head"] = layers.embed_init(kh, cfg.vocab_size, cfg.d_model)

@@ -9,7 +9,8 @@ enables the tiled path whenever the backend is not CPU and the shapes divide
 the block sizes (``kernels.ops.flash_supported``), with a clean fallback to
 the reference path otherwise.  On CPU the Pallas interpreter would be a
 slowdown, not a speedup, so ``auto`` resolves to off there; ``=1`` forces the
-kernel (interpret mode on CPU — the validation path), ``=0`` forces it off.
+kernel, ``=0`` forces it off.  Whether a kernel runs compiled or interpreted
+is not a flag: the backend decides (``kernels.ops``), CPU means interpret.
 """
 
 from __future__ import annotations
@@ -21,7 +22,6 @@ _FLAGS = {
     "flash_attention": os.environ.get("REPRO_FLASH_ATTENTION", "auto"),
     "flash_decode": os.environ.get("REPRO_FLASH_DECODE", "0") == "1",
     "fused_rmsnorm": os.environ.get("REPRO_FUSED_RMSNORM", "0") == "1",
-    "pallas_interpret": os.environ.get("REPRO_PALLAS_INTERPRET", "auto"),
     # flash block-size overrides (autotuning hook): None → heuristic in
     # kernels.ops; threaded down from ParallelismConfig.flash_bq/flash_bk
     # by the step factories in core.stepfn.
@@ -51,15 +51,6 @@ def use_fused_rmsnorm() -> bool:
 def flash_block_sizes():
     """(bq, bk) overrides for the flash kernels; None entries → heuristic."""
     return _FLAGS["flash_block_q"], _FLAGS["flash_block_k"]
-
-
-def pallas_interpret() -> bool:
-    """interpret=True on CPU (validation), False on real TPU."""
-    mode = _FLAGS["pallas_interpret"]
-    if mode == "auto":
-        import jax
-        return jax.default_backend() == "cpu"
-    return mode == "1"
 
 
 def set_flag(name: str, value) -> None:

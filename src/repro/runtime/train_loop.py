@@ -113,6 +113,12 @@ def run_training(state, train_step: Callable, batches, loop_cfg: LoopConfig,
             loop_cfg.ckpt_dir, canonicalize_state(state, plan),
             retry=retry, log=log, fault_hook=read_fault)
         if restored is not None:
+            # the loop consumes the state it is handed (the first step would
+            # donate it): free it before the restored copy lands, so a resume
+            # needs device room for one train state, not two
+            for x in jax.tree_util.tree_leaves(state):
+                if isinstance(x, jax.Array):
+                    x.delete()
             state = reshard_state(restored, plan)
             state = jax.tree_util.tree_map(jax.numpy.asarray, state)
             start_step = int(extra.get("next_step", step))

@@ -66,7 +66,13 @@ class InferenceSession:
                 lambda x: x.astype(cfg.compute_dtype), p)
 
         key = jax.random.PRNGKey(seed)
-        params = jax.eval_shape(mk, key) if abstract else mk(key)
+        params = jax.eval_shape(mk, key)
+        if not abstract:
+            # jitted: each leaf is cast as it is made (the fp32 and
+            # compute-dtype trees never coexist) and lands on its own shards
+            out_sh = (plans_mod.serve_param_sharding(params, mesh)
+                      if mesh is not None else None)
+            params = jax.jit(mk, out_shardings=out_sh)(key)
         return cls(cfg, params, plan=plan, mesh=mesh, abstract=abstract)
 
     @classmethod

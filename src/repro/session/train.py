@@ -63,16 +63,21 @@ class TrainSession:
             self.plan, n_layers=cfg.n_layers)
 
         key = jax.random.PRNGKey(seed)
+
+        def init(k):
+            return stepfn.init_state(cfg, self.plan, k, self.train_cfg)
+
         if abstract:
-            self.state = jax.eval_shape(
-                lambda k: stepfn.init_state(cfg, self.plan, k, self.train_cfg), key)
+            self.state = jax.eval_shape(init, key)
             self.train_step = None       # composed per-lowering in .lower()
         else:
-            self.state = stepfn.init_state(cfg, self.plan, key, self.train_cfg)
+            # one jitted init that writes each shard straight to its device:
+            # no chip ever holds state that is not its own
+            out_sh = None
             if mesh is not None:
-                self.state = jax.device_put(
-                    self.state,
-                    stepfn.state_shardings(cfg, self.state, mesh, self.plan))
+                out_sh = stepfn.state_shardings(
+                    cfg, jax.eval_shape(init, key), mesh, self.plan)
+            self.state = jax.jit(init, out_shardings=out_sh)(key)
             step = stepfn.make_train_step(cfg, self.plan, self.train_cfg, mesh)
             self.train_step = jax.jit(step, donate_argnums=(0,) if donate else ())
         self._donate = donate
@@ -127,9 +132,11 @@ class TrainSession:
         the restart path may re-request the same step)."""
         if step not in self._batch_cache:
             self._batch_cache.clear()
-            b = self.dataset.batch(step)
-            self._batch_cache[step] = add_modality_inputs(
-                b, self.cfg, step, self.dataset.cfg.seed)
+            b = add_modality_inputs(self.dataset.batch(step), self.cfg, step,
+                                    self.dataset.cfg.seed)
+            if self.mesh is not None:
+                b = jax.device_put(b, stepfn.batch_shardings(b, self.mesh))
+            self._batch_cache[step] = b
         return self._batch_cache[step]
 
     # ------------------------------------------------------------------
@@ -218,13 +225,18 @@ class TrainSession:
     # hand-offs
     # ------------------------------------------------------------------
     def lower(self, batch_specs):
-        """Abstract-mode: lower the sharded train step for ``batch_specs``
-        on this session's mesh (the dry-run's compile-only path)."""
-        if not (self.abstract and self.mesh is not None):
-            raise RuntimeError("lower() needs abstract=True and a mesh")
+        """Abstract-mode: lower the train step for ``batch_specs`` — sharded
+        on this session's mesh (the dry-run's compile-only path), or for the
+        default device without one (sizing a run from
+        ``compile().memory_analysis()`` before any state exists)."""
+        if not self.abstract:
+            raise RuntimeError("lower() needs abstract=True")
+        step = stepfn.make_train_step(self.cfg, self.plan, self.train_cfg, self.mesh)
+        if self.mesh is None:
+            return jax.jit(step, donate_argnums=(0,)).lower(self.state,
+                                                            batch_specs)
         state_sh = stepfn.state_shardings(self.cfg, self.state, self.mesh, self.plan)
         batch_sh = stepfn.batch_shardings(batch_specs, self.mesh)
-        step = stepfn.make_train_step(self.cfg, self.plan, self.train_cfg, self.mesh)
         jitted = jax.jit(step, in_shardings=(state_sh, batch_sh),
                          out_shardings=(state_sh, None), donate_argnums=(0,))
         return jitted.lower(self.state, batch_specs)

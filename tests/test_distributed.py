@@ -138,6 +138,45 @@ def test_recipe_mesh_factorization():
     assert "MESH_OK" in out
 
 
+def test_plan_mesh_flash_session_matches_one_device():
+    """A pp=2 x tp=2 session on the mesh built from four local devices, with
+    the Pallas flash kernel per shard (segment ids on), takes the same first
+    step as the one-device session, and its state lands sharded."""
+    out = _run("""
+        import dataclasses, jax, pytest
+        from repro.configs import get_config
+        from repro.core import stepfn
+        from repro.core.recipe import ParallelismConfig
+        from repro.data import DataConfig
+        from repro.launch.mesh import make_plan_mesh
+        from repro.runtime import flags
+        from repro.session import TrainSession
+        cfg = dataclasses.replace(get_config("granite_3_2b").reduced(),
+                                  n_layers=4)
+        plan4 = ParallelismConfig(pp=2, tp=2, gas=4)
+        mesh = make_plan_mesh(plan4)
+        assert dict(mesh.shape) == {"pod": 1, "data": 1, "pp": 2, "tp": 2}
+        with pytest.raises(ValueError):
+            make_plan_mesh(ParallelismConfig(tp=2), jax.devices())
+        dc = DataConfig(seq_len=128, global_batch=8, pack_documents=True)
+        tc = stepfn.TrainConfig(total_steps=2, warmup=1)
+        def first(plan, mesh):
+            s = TrainSession.from_recipe(cfg, plan=plan, mesh=mesh,
+                                         train_cfg=tc, data_cfg=dc, seed=0)
+            w = s.state["params"]["blocks"]["mlp"]["w_gate"]
+            h = s.run(1, log_every=1, log=lambda m: None)["history"][0]
+            return float(h["loss"]), float(h["grad_norm"]), w
+        with flags.flag_ctx(flash_attention=True):
+            l4, g4, w4 = first(plan4, mesh)
+            l1, g1, _ = first(ParallelismConfig(gas=4), None)
+        assert len(w4.sharding.device_set) == 4, w4.sharding
+        assert abs(l4 - l1) <= 1e-4 * abs(l1), (l4, l1)
+        assert abs(g4 - g1) <= 1e-3 * abs(g1), (g4, g1)
+        print("PLAN_MESH_OK", l4, l1, g4, g1)
+    """, devices=4)
+    assert "PLAN_MESH_OK" in out
+
+
 def test_consensus_skip_bitwise_identical_across_replicas():
     """ISSUE-9 acceptance: one divergent replica's gradient on a real dp>=2
     mesh must yield the IDENTICAL vote on every replica — survivors update,

@@ -124,7 +124,7 @@ def test_sdpa_segment_flash_training_path_matches_reference():
                 .astype(jnp.float32) * cot).sum()
 
     base = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
-    with flags.flag_ctx(flash_attention=True, pallas_interpret="1"):
+    with flags.flag_ctx(flash_attention=True):
         fast = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
     for g_b, g_f in zip(base, fast):
         np.testing.assert_allclose(np.asarray(g_b), np.asarray(g_f),
@@ -176,7 +176,7 @@ def test_sdpa_flash_training_path_matches_reference():
                 .astype(jnp.float32) * cot).sum()
 
     base = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
-    with flags.flag_ctx(flash_attention=True, pallas_interpret="1"):
+    with flags.flag_ctx(flash_attention=True):
         fast = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
     for g_b, g_f in zip(base, fast):
         np.testing.assert_allclose(np.asarray(g_b), np.asarray(g_f),
@@ -193,7 +193,7 @@ def test_block_size_override_threads_through_ops():
     with flags.flag_ctx(flash_block_q=96, flash_block_k=96):
         assert not ops.flash_supported(q, k, causal=True, window=None)
     with flags.flag_ctx(flash_block_q=32, flash_block_k=64,
-                        flash_attention=True, pallas_interpret="1"):
+                        flash_attention=True):
         assert ops.flash_supported(q, k, causal=True, window=None)
         out = ops.flash_attention(q, k, v, causal=True)
     want = ref.mha_reference(q, k, v, causal=True)

@@ -98,6 +98,6 @@ def test_model_attention_matches_kernel_path():
     from repro.runtime import flags
     q, k, v = _qkv(2, 128, 128, 4, 2, 64, jnp.float32)
     base = sdpa(q, k, v, None, causal=True, window=None)
-    with flags.flag_ctx(flash_attention=True, pallas_interpret="1"):
+    with flags.flag_ctx(flash_attention=True):
         fast = sdpa(q, k, v, None, causal=True, window=None)
     np.testing.assert_allclose(np.asarray(base), np.asarray(fast), atol=2e-5, rtol=2e-5)

@@ -202,7 +202,7 @@ def test_packed_loss_flash_path_matches_reference():
         return model_api.loss_fn(cfg, p, packed)[0]
 
     base, gbase = jax.value_and_grad(loss)(params)
-    with flags.flag_ctx(flash_attention=True, pallas_interpret="1"):
+    with flags.flag_ctx(flash_attention=True):
         fast, gfast = jax.value_and_grad(loss)(params)
     np.testing.assert_allclose(float(base), float(fast), rtol=2e-5)
     for a, b in zip(jax.tree_util.tree_leaves(gbase),
