@@ -1,0 +1,22 @@
+"""Published peaks of the chips the benchmark runs on, keyed by JAX's
+``device_kind``.  A device that is not in the table is an error, never a
+default: a share of a peak that is not the chip's own means nothing."""
+
+from __future__ import annotations
+
+PEAKS = {
+    # Google Cloud documentation, "TPU v5e" (system architecture page):
+    # 197 TFLOP/s bf16, 393 TOP/s int8, 16 GB HBM at 819 GB/s per chip.
+    "TPU v5 lite": {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9,
+                    "hbm_bytes": 16e9,
+                    "source": "Google Cloud documentation, TPU v5e"},
+}
+
+
+def peak(device_kind: str) -> dict:
+    """The peak table entry of ``device_kind``; KeyError if it has none."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind {device_kind!r}; "
+                       f"known: {sorted(PEAKS)}") from None
