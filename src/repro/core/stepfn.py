@@ -114,22 +114,26 @@ def make_train_step(cfg: ModelConfig, plan: ParallelismConfig,
     mapping = axis_mapping(plan)
 
     def loss_fn(params, batch):
-        if plan.gather_params_once and mesh is not None:
-            # ZeRO-3 + pipeline: one bf16 cast + all-gather of the fp32
-            # masters up front; the superstep scan then reuses the gathered
-            # copy instead of re-gathering every iteration.  The cast's
-            # transpose delivers bf16 gradient accumulation (Table 1's 2 B
-            # gradients).
-            dtp = cfg.compute_dtype
-            nofsdp = dataclasses.replace(plan, zero_stage=min(plan.zero_stage, 1))
-            g_sh = zero.param_shardings(cfg, params, mesh, nofsdp)
-            params = jax.tree_util.tree_map(
-                lambda x, s: jax.lax.with_sharding_constraint(
-                    x.astype(dtp) if x.dtype == jnp.float32 else x, s),
-                params, g_sh)
-        if plan.pp > 1:
-            return pp_mod.pipeline_loss(cfg, params, batch, plan)
-        return model_api.loss_fn(cfg, params, batch, remat_policy=plan.remat_policy)
+        # under value_and_grad the forward's ops lower as ``jvp(forward)``
+        # and the backward's, remat's recompute included, as
+        # ``transpose(jvp(forward))``: the phases apart by name stack
+        with jax.named_scope("forward"):
+            if plan.gather_params_once and mesh is not None:
+                # ZeRO-3 + pipeline: one bf16 cast + all-gather of the fp32
+                # masters up front; the superstep scan then reuses the gathered
+                # copy instead of re-gathering every iteration.  The cast's
+                # transpose delivers bf16 gradient accumulation (Table 1's 2 B
+                # gradients).
+                dtp = cfg.compute_dtype
+                nofsdp = dataclasses.replace(plan, zero_stage=min(plan.zero_stage, 1))
+                g_sh = zero.param_shardings(cfg, params, mesh, nofsdp)
+                params = jax.tree_util.tree_map(
+                    lambda x, s: jax.lax.with_sharding_constraint(
+                        x.astype(dtp) if x.dtype == jnp.float32 else x, s),
+                    params, g_sh)
+            if plan.pp > 1:
+                return pp_mod.pipeline_loss(cfg, params, batch, plan)
+            return model_api.loss_fn(cfg, params, batch, remat_policy=plan.remat_policy)
 
     n_groups = plan.dp * plan.pods if mesh is not None else 1
     rs = train_cfg.resilience
@@ -406,82 +410,85 @@ def make_train_step(cfg: ModelConfig, plan: ParallelismConfig,
             loss, metrics, grads, aux = grads_and_metrics(
                 state["params"], batch, chaos_scale, rstat)
 
-            # --- in-step anomaly signals (free: no extra device sync — they
-            # return with the metrics the loop already transfers).  On the
-            # consensus path every input below is already a fleet-reduced
-            # value, so the verdict — and the zero-update it gates — is
-            # bit-identical on every replica. ------------------------------
-            gnorm = adamw.global_norm(grads)
-            finite = aux["usable"] & jnp.isfinite(gnorm)
-            armed = rstat["n"] >= rs.warmup_steps
-            std = jnp.sqrt(jnp.maximum(rstat["var"], 1e-12))
-            z = (gnorm - rstat["ema"]) / std
-            z = jnp.where(finite, z, jnp.float32(jnp.inf))
-            spike = (armed & (z > rs.zscore_threshold)
-                     & (gnorm > rs.spike_factor * rstat["ema"]))
-            if rs.enabled:
-                skip = (~finite) | spike
-                if n_rep > 1 and not rs.mask_divergent_replicas:
-                    # strict mode: one bad replica vetoes the whole step
-                    skip = skip | (aux["bad_replicas"] > 0)
-            else:
-                skip = jnp.zeros((), bool)
-
-            # EMA/variance track ACCEPTED steps only (a skipped spike must
-            # not drag the baseline toward the anomaly); the re-warm
-            # countdown set by the loop's rollback path decrements here
-            first = rstat["n"] == 0
-            d = jnp.float32(rs.ema_decay)
-            ema_new = jnp.where(first, gnorm,
-                                d * rstat["ema"] + (1 - d) * gnorm)
-            var_new = jnp.where(first, rstat["var"],
-                                d * rstat["var"]
-                                + (1 - d) * jnp.square(gnorm - rstat["ema"]))
-            accept = (~skip) & finite
-            new_rstat = {
-                "ema": jnp.where(accept, ema_new, rstat["ema"]),
-                "var": jnp.where(accept, var_new, rstat["var"]),
-                "n": rstat["n"] + accept.astype(jnp.int32),
-                "rewarm": jnp.maximum(rstat["rewarm"] - 1, 0),
-            }
-
-            grads, ef = apply_compression(grads, train_cfg.compression, state.get("ef"))
-            if mesh is not None and plan.zero_stage >= 2:
-                p_sh = zero.param_shardings(cfg, state["params"], mesh, plan)
-                o_sh = zero.opt_shardings(p_sh, state["params"], mesh, plan)
-                grads = zero.grad_constraint(grads, mesh, plan, o_sh)
-            lr = schedule.lr_schedule(state["step"], peak=train_cfg.peak_lr,
-                                      warmup=train_cfg.warmup,
-                                      total=train_cfg.total_steps)
-            lr = lr * schedule.rewarm_factor(rstat["rewarm"], rs.rewarm_steps)
-            params, opt, om = adamw.adamw_update(grads, state["opt"], state["params"],
-                                                 lr, train_cfg.adam)
-            if rs.enabled:
-                # skip → zero-update: keep params/opt (incl. Adam's step/bias
-                # correction) untouched; the data cursor still advances
-                keep = lambda new, old: jnp.where(skip, old, new)
-                params = jax.tree_util.tree_map(keep, params, state["params"])
-                opt = jax.tree_util.tree_map(keep, opt, state["opt"])
-            new_state = {"params": params, "opt": opt,
-                         "step": state["step"] + 1, "rstat": new_rstat}
-            if ef is not None:
+            # global norm, anomaly gate, compression, LR, AdamW and the
+            # skip select: the step's third phase
+            with jax.named_scope("optimizer"):
+                # --- in-step anomaly signals (free: no extra device sync — they
+                # return with the metrics the loop already transfers).  On the
+                # consensus path every input below is already a fleet-reduced
+                # value, so the verdict — and the zero-update it gates — is
+                # bit-identical on every replica. ------------------------------
+                gnorm = adamw.global_norm(grads)
+                finite = aux["usable"] & jnp.isfinite(gnorm)
+                armed = rstat["n"] >= rs.warmup_steps
+                std = jnp.sqrt(jnp.maximum(rstat["var"], 1e-12))
+                z = (gnorm - rstat["ema"]) / std
+                z = jnp.where(finite, z, jnp.float32(jnp.inf))
+                spike = (armed & (z > rs.zscore_threshold)
+                         & (gnorm > rs.spike_factor * rstat["ema"]))
                 if rs.enabled:
-                    ef = jax.tree_util.tree_map(keep, ef, state["ef"])
-                new_state["ef"] = ef
-            metrics = dict(metrics, loss=loss, **om)
-            # resilience signals win over om's post-compression grad_norm:
-            # the skip gate keyed on the pre-compression norm is the one the
-            # loop's policy must see
-            metrics.update(
-                grad_norm=gnorm,
-                all_finite=finite.astype(jnp.float32),
-                skipped=skip.astype(jnp.float32),
-                gnorm_z=jnp.where(armed & finite, z, 0.0),
-                nonfinite_micros=aux["nonfinite_micros"].astype(jnp.float32),
-                bad_replicas=aux["bad_replicas"].astype(jnp.float32),
-                n_replicas=jnp.float32(n_rep),
-                bad_micro_bits=aux["bad_micro_bits"],
-                lr=lr)
+                    skip = (~finite) | spike
+                    if n_rep > 1 and not rs.mask_divergent_replicas:
+                        # strict mode: one bad replica vetoes the whole step
+                        skip = skip | (aux["bad_replicas"] > 0)
+                else:
+                    skip = jnp.zeros((), bool)
+
+                # EMA/variance track ACCEPTED steps only (a skipped spike must
+                # not drag the baseline toward the anomaly); the re-warm
+                # countdown set by the loop's rollback path decrements here
+                first = rstat["n"] == 0
+                d = jnp.float32(rs.ema_decay)
+                ema_new = jnp.where(first, gnorm,
+                                    d * rstat["ema"] + (1 - d) * gnorm)
+                var_new = jnp.where(first, rstat["var"],
+                                    d * rstat["var"]
+                                    + (1 - d) * jnp.square(gnorm - rstat["ema"]))
+                accept = (~skip) & finite
+                new_rstat = {
+                    "ema": jnp.where(accept, ema_new, rstat["ema"]),
+                    "var": jnp.where(accept, var_new, rstat["var"]),
+                    "n": rstat["n"] + accept.astype(jnp.int32),
+                    "rewarm": jnp.maximum(rstat["rewarm"] - 1, 0),
+                }
+
+                grads, ef = apply_compression(grads, train_cfg.compression, state.get("ef"))
+                if mesh is not None and plan.zero_stage >= 2:
+                    p_sh = zero.param_shardings(cfg, state["params"], mesh, plan)
+                    o_sh = zero.opt_shardings(p_sh, state["params"], mesh, plan)
+                    grads = zero.grad_constraint(grads, mesh, plan, o_sh)
+                lr = schedule.lr_schedule(state["step"], peak=train_cfg.peak_lr,
+                                          warmup=train_cfg.warmup,
+                                          total=train_cfg.total_steps)
+                lr = lr * schedule.rewarm_factor(rstat["rewarm"], rs.rewarm_steps)
+                params, opt, om = adamw.adamw_update(grads, state["opt"], state["params"],
+                                                     lr, train_cfg.adam)
+                if rs.enabled:
+                    # skip → zero-update: keep params/opt (incl. Adam's step/bias
+                    # correction) untouched; the data cursor still advances
+                    keep = lambda new, old: jnp.where(skip, old, new)
+                    params = jax.tree_util.tree_map(keep, params, state["params"])
+                    opt = jax.tree_util.tree_map(keep, opt, state["opt"])
+                new_state = {"params": params, "opt": opt,
+                             "step": state["step"] + 1, "rstat": new_rstat}
+                if ef is not None:
+                    if rs.enabled:
+                        ef = jax.tree_util.tree_map(keep, ef, state["ef"])
+                    new_state["ef"] = ef
+                metrics = dict(metrics, loss=loss, **om)
+                # resilience signals win over om's post-compression grad_norm:
+                # the skip gate keyed on the pre-compression norm is the one the
+                # loop's policy must see
+                metrics.update(
+                    grad_norm=gnorm,
+                    all_finite=finite.astype(jnp.float32),
+                    skipped=skip.astype(jnp.float32),
+                    gnorm_z=jnp.where(armed & finite, z, 0.0),
+                    nonfinite_micros=aux["nonfinite_micros"].astype(jnp.float32),
+                    bad_replicas=aux["bad_replicas"].astype(jnp.float32),
+                    n_replicas=jnp.float32(n_rep),
+                    bad_micro_bits=aux["bad_micro_bits"],
+                    lr=lr)
         return new_state, metrics
 
     return train_step

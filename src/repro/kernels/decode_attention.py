@@ -148,6 +148,7 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, g, D), q.dtype),
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
+        name="paged_decode_attention",
     )(pt_flat, ts_arr, q.reshape(B, Hkv, g, D), k_pool, v_pool)
     return out.reshape(B, 1, Hq, D)
 
@@ -187,5 +188,6 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array, kpos: jax.Array,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, g, D), q.dtype),
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
+        name="decode_attention",
     )(t_arr, q.reshape(B, Hkv, g, D), k, v, kpos.reshape(B, nk, 1, bk))
     return out.reshape(B, 1, Hq, D)

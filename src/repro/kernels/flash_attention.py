@@ -203,6 +203,7 @@ def _forward(q, k, v, segment_ids, causal, window, bq, bk, interpret):
         scratch_shapes=_scratch(bq, Dp),
         compiler_params=_compiler_params(),
         interpret=interpret,
+        name="flash_attention_fwd",
     )(*inputs)
     return out[..., :D].transpose(0, 2, 1, 3), lse
 
@@ -329,6 +330,7 @@ def _backward(q, k, v, segment_ids, o, lse, do, causal, window, bq, bk,
         out_shape=jax.ShapeDtypeStruct((B, Hq, Sq, 1), jnp.float32),
         compiler_params=_compiler_params(("parallel",) * 3),
         interpret=interpret,
+        name="flash_attention_delta",
     )(ot, dot)
 
     from jax.experimental.pallas import tpu as pltpu
@@ -361,6 +363,7 @@ def _backward(q, k, v, segment_ids, o, lse, do, causal, window, bq, bk,
         scratch_shapes=[pltpu.VMEM((bq, Dp), jnp.float32)],
         compiler_params=_compiler_params(),
         interpret=interpret,
+        name="flash_attention_dq",
     )(*dq_inputs)
 
     # dK/dV: per *query* head tiles (the K/V index maps mirror the forward's
@@ -399,6 +402,7 @@ def _backward(q, k, v, segment_ids, o, lse, do, causal, window, bq, bk,
                         pltpu.VMEM((bk, Dp), jnp.float32)],
         compiler_params=_compiler_params(),
         interpret=interpret,
+        name="flash_attention_dkv",
     )(*dkv_inputs)
 
     if g > 1:

@@ -55,7 +55,8 @@ def run_stream(sess, args):
                              paged=args.paged, page_size=args.page_size)
     dt = time.time() - t0
     print(f"[serve] {cfg.name}: {stats.requests} requests "
-          f"({sum(gens)} tokens) through {args.slots} slots in {dt:.2f}s")
+          f"({sum(gens)} tokens) through {args.slots} slots in {dt:.2f}s "
+          f"({stats.generated_tokens / dt:.1f} tok/s, compiles included)")
     print(f"[serve] {stats}")
     if args.paged:
         print(f"[serve] pool: {stats.pool_pages} pages of {stats.page_size} "

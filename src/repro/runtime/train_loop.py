@@ -18,6 +18,7 @@ from typing import Any, Callable, Dict, Optional
 
 import jax
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.checkpoint import RetryPolicy, restore_latest, save_checkpoint
 from repro.checkpoint.elastic import (canonicalize_state, replan_state,
@@ -167,7 +168,8 @@ def run_training(state, train_step: Callable, batches, loop_cfg: LoopConfig,
             return None
         if not block and not writer.done():
             return writer
-        err = writer.exception()
+        with TraceAnnotation("train.ckpt"):
+            err = writer.exception()
         if err is not None:
             log(f"[loop] background checkpoint write for step {writer.step} "
                 f"FAILED after retries: {err}")
@@ -181,11 +183,12 @@ def run_training(state, train_step: Callable, batches, loop_cfg: LoopConfig,
         tag = loop_cfg.total_steps + 1_000_000 if emergency else step
         extra = {"next_step": step, "data_offset": data_offset}
         try:
-            writer = save_checkpoint(
-                loop_cfg.ckpt_dir, tag, canonicalize_state(state, plan),
-                extra=extra, keep=loop_cfg.keep_ckpts,
-                background=loop_cfg.async_ckpt and not emergency,
-                retry=retry, log=log, fault_hook=write_fault)
+            with TraceAnnotation("train.ckpt"):
+                writer = save_checkpoint(
+                    loop_cfg.ckpt_dir, tag, canonicalize_state(state, plan),
+                    extra=extra, keep=loop_cfg.keep_ckpts,
+                    background=loop_cfg.async_ckpt and not emergency,
+                    retry=retry, log=log, fault_hook=write_fault)
         except Exception as e:               # noqa: BLE001 — surfaced
             log(f"[loop] checkpoint write for step {step} FAILED after "
                 f"retries: {e}")
@@ -196,184 +199,190 @@ def run_training(state, train_step: Callable, batches, loop_cfg: LoopConfig,
     step = start_step
     try:
         while step < loop_cfg.total_steps:
-            if preempt["flag"]:
-                raise Preempted()
-            if chaos is not None:
-                chaos.maybe_crash(step)
-                chaos.maybe_sigterm(step)
-            wd.begin_step(step)
-            batch = batches(step + data_offset)
-            state, metrics = train_step(state, batch)
-            if chaos is not None:
-                chaos.maybe_slow(step)       # inside the watchdog window
-            wd.end_step(step)
-            while straggler_cursor < len(stragglers):
-                s, el = stragglers[straggler_cursor]
-                straggler_cursor += 1
-                emit(s, "straggler", elapsed_s=float(el),
-                     deadline_s=loop_cfg.step_deadline_s, source="deadline")
-            # measured straggling (no deadline needed): last completed step
-            # vs the median — the quantitative signal the deadline thread
-            # can't give
-            sf = wd.slowdown_factor()
-            if sf is not None and sf > loop_cfg.straggler_factor:
-                emit(step, "straggler", source="measured",
-                     elapsed_s=float(wd.last_step_time() or 0.0),
-                     median_s=float(wd.median_step_time() or 0.0),
-                     slowdown=float(sf))
-
-            # --- fleet liveness: heartbeats in, re-plan decisions out ------
-            if fleet is not None:
-                t_local = float(wd.last_step_time() or 0.0)
-                for r in range(fleet.n_replicas):
-                    if not fleet.alive(r):
-                        continue
-                    t_r = t_local
-                    if chaos is not None and r != fleet.local_replica:
-                        t_r = chaos.peer_step_time(r, step, t_local)
-                    fleet.heartbeat(r, step, t_r)
+            with StepTraceAnnotation("train", step_num=step):
+                if preempt["flag"]:
+                    raise Preempted()
                 if chaos is not None:
-                    lost = chaos.maybe_lose_replica(step)
-                    if lost is not None:
-                        fleet.mark_lost(lost, step, reason="chaos")
-                        emit(step, "replica_lost", replica=lost,
-                             reason="chaos")
-                decision = fleet.observe(step)
-                if decision is not None:
-                    if decision.kind == "straggler":
-                        emit(step, "straggler", source="fleet",
-                             replica=decision.replica, **decision.detail)
-                    elif decision.detail.get("reason") == "missed_heartbeats":
-                        emit(step, "replica_lost", replica=decision.replica,
-                             **decision.detail)
-                    # ---- elastic re-plan ------------------------------
-                    t0 = clock()
-                    new_plan = None
-                    try:
-                        new_plan = fleet.shrink_plan(plan)
-                    except ValueError as e:
-                        emit(step, "replan_unavailable", reason=str(e),
-                             trigger=decision.kind)
-                    if new_plan is not None and make_step is None:
-                        emit(step, "replan_unavailable", trigger=decision.kind,
-                             reason="no step factory (make_step=None)")
-                        log(f"[fleet] step {step}: re-plan wanted "
-                            f"({decision.kind}, replica {decision.replica}) "
-                            f"but no make_step factory — continuing degraded")
+                    chaos.maybe_crash(step)
+                    chaos.maybe_sigterm(step)
+                wd.begin_step(step)
+                with TraceAnnotation("train.batch"):
+                    batch = batches(step + data_offset)
+                with TraceAnnotation("train.dispatch"):
+                    state, metrics = train_step(state, batch)
+                if chaos is not None:
+                    chaos.maybe_slow(step)       # inside the watchdog window
+                wd.end_step(step)
+                while straggler_cursor < len(stragglers):
+                    s, el = stragglers[straggler_cursor]
+                    straggler_cursor += 1
+                    emit(s, "straggler", elapsed_s=float(el),
+                         deadline_s=loop_cfg.step_deadline_s, source="deadline")
+                # measured straggling (no deadline needed): last completed step
+                # vs the median — the quantitative signal the deadline thread
+                # can't give
+                sf = wd.slowdown_factor()
+                if sf is not None and sf > loop_cfg.straggler_factor:
+                    emit(step, "straggler", source="measured",
+                         elapsed_s=float(wd.last_step_time() or 0.0),
+                         median_s=float(wd.median_step_time() or 0.0),
+                         slowdown=float(sf))
+
+                # --- fleet liveness: heartbeats in, re-plan decisions out ------
+                if fleet is not None:
+                    t_local = float(wd.last_step_time() or 0.0)
+                    for r in range(fleet.n_replicas):
+                        if not fleet.alive(r):
+                            continue
+                        t_r = t_local
+                        if chaos is not None and r != fleet.local_replica:
+                            t_r = chaos.peer_step_time(r, step, t_local)
+                        fleet.heartbeat(r, step, t_r)
+                    if chaos is not None:
+                        lost = chaos.maybe_lose_replica(step)
+                        if lost is not None:
+                            fleet.mark_lost(lost, step, reason="chaos")
+                            emit(step, "replica_lost", replica=lost,
+                                 reason="chaos")
+                    decision = fleet.observe(step)
+                    if decision is not None:
+                        if decision.kind == "straggler":
+                            emit(step, "straggler", source="fleet",
+                                 replica=decision.replica, **decision.detail)
+                        elif decision.detail.get("reason") == "missed_heartbeats":
+                            emit(step, "replica_lost", replica=decision.replica,
+                                 **decision.detail)
+                        # ---- elastic re-plan ------------------------------
+                        t0 = clock()
                         new_plan = None
-                    if new_plan is not None:
-                        pending_writer = reap_writer(pending_writer,
-                                                     block=True, at_step=step)
-                        restored = extra2 = None
-                        if loop_cfg.ckpt_dir:
-                            restored, extra2, ck = restore_latest(
-                                loop_cfg.ckpt_dir,
-                                canonicalize_state(state, plan),
-                                retry=retry, log=log, fault_hook=read_fault)
-                        if restored is not None:
-                            target = int(extra2.get("next_step", ck))
-                            data_offset = int(
-                                extra2.get("data_offset", data_offset))
-                            state = reshard_state(restored, new_plan)
-                        else:
-                            # no checkpoint: the live params are clean
-                            # (anomalies never landed), so re-plan the live
-                            # state in place — zero steps lost
-                            target = step + 1
-                            state = replan_state(state, plan, new_plan)
-                        state = jax.tree_util.tree_map(
-                            jax.numpy.asarray, state)
-                        train_step = make_step(new_plan)
-                        detail = {
-                            "trigger": decision.kind,
-                            "replica": decision.replica,
-                            "old_plan": str(plan), "new_plan": str(new_plan),
-                            "restored_step": (target if restored is not None
-                                              else None),
-                            "steps_lost": step + 1 - target,
-                            "latency_s": float(clock() - t0)}
-                        emit(step, "replan", **detail)
-                        log(f"[fleet] step {step}: {decision.kind} (replica "
-                            f"{decision.replica}) — re-planned "
-                            f"{detail['old_plan']} -> {detail['new_plan']}, "
-                            f"resuming at step {target} "
-                            f"({detail['steps_lost']} steps lost)")
-                        n_replans += 1
-                        plan = new_plan
-                        fleet.on_replanned(step)
+                        try:
+                            new_plan = fleet.shrink_plan(plan)
+                        except ValueError as e:
+                            emit(step, "replan_unavailable", reason=str(e),
+                                 trigger=decision.kind)
+                        if new_plan is not None and make_step is None:
+                            emit(step, "replan_unavailable", trigger=decision.kind,
+                                 reason="no step factory (make_step=None)")
+                            log(f"[fleet] step {step}: re-plan wanted "
+                                f"({decision.kind}, replica {decision.replica}) "
+                                f"but no make_step factory — continuing degraded")
+                            new_plan = None
+                        if new_plan is not None:
+                            pending_writer = reap_writer(pending_writer,
+                                                         block=True, at_step=step)
+                            restored = extra2 = None
+                            if loop_cfg.ckpt_dir:
+                                restored, extra2, ck = restore_latest(
+                                    loop_cfg.ckpt_dir,
+                                    canonicalize_state(state, plan),
+                                    retry=retry, log=log, fault_hook=read_fault)
+                            if restored is not None:
+                                target = int(extra2.get("next_step", ck))
+                                data_offset = int(
+                                    extra2.get("data_offset", data_offset))
+                                state = reshard_state(restored, new_plan)
+                            else:
+                                # no checkpoint: the live params are clean
+                                # (anomalies never landed), so re-plan the live
+                                # state in place — zero steps lost
+                                target = step + 1
+                                state = replan_state(state, plan, new_plan)
+                            state = jax.tree_util.tree_map(
+                                jax.numpy.asarray, state)
+                            train_step = make_step(new_plan)
+                            detail = {
+                                "trigger": decision.kind,
+                                "replica": decision.replica,
+                                "old_plan": str(plan), "new_plan": str(new_plan),
+                                "restored_step": (target if restored is not None
+                                                  else None),
+                                "steps_lost": step + 1 - target,
+                                "latency_s": float(clock() - t0)}
+                            emit(step, "replan", **detail)
+                            log(f"[fleet] step {step}: {decision.kind} (replica "
+                                f"{decision.replica}) — re-planned "
+                                f"{detail['old_plan']} -> {detail['new_plan']}, "
+                                f"resuming at step {target} "
+                                f"({detail['steps_lost']} steps lost)")
+                            n_replans += 1
+                            plan = new_plan
+                            fleet.on_replanned(step)
+                            step = target
+                            continue
+
+                # --- recovery policy: reads the in-step anomaly scalars that
+                # already ride the metrics transfer -----------------------------
+                with TraceAnnotation("train.observe"):
+                    action = policy.observe(step, metrics)
+                if action == SKIP:
+                    forensics(policy.events[-1].detail, batch, metrics, step)
+                    log(f"[resilience] step {step}: anomalous update skipped "
+                        f"(grad_norm={policy.events[-1].detail['grad_norm']:.4g}, "
+                        f"{policy.consecutive_skips} consecutive)")
+                    log_event(tracker, step, policy.events[-1].kind,
+                              policy.events[-1].detail)
+                elif action == ROLLBACK:
+                    forensics(policy.events[-1].detail, batch, metrics, step)
+                    log_event(tracker, step, policy.events[-1].kind,
+                              policy.events[-1].detail)
+                    t0 = clock()
+                    restored = extra2 = None
+                    if loop_cfg.ckpt_dir:
+                        pending_writer = reap_writer(pending_writer, block=True,
+                                                     at_step=step)
+                        restored, extra2, ck = restore_latest(
+                            loop_cfg.ckpt_dir, canonicalize_state(state, plan),
+                            retry=retry, log=log, fault_hook=read_fault)
+                    if restored is not None:
+                        target = int(extra2.get("next_step", ck))
+                        jump = (step + 1 - target) + rs.skip_window_margin
+                        data_offset += jump
+                        state = reshard_state(restored, plan)
+                        if rs.rewarm_steps > 0 and "rstat" in state:
+                            state["rstat"] = dict(
+                                state["rstat"],
+                                rewarm=np.asarray(rs.rewarm_steps, np.int32))
+                        state = jax.tree_util.tree_map(jax.numpy.asarray, state)
+                        detail = {"steps_lost": step + 1 - target,
+                                  "data_skipped": jump,
+                                  "rewarm_steps": rs.rewarm_steps,
+                                  "latency_s": float(clock() - t0)}
+                        policy.on_rollback(step, target, **detail)
+                        emit_detail = dict(detail, restored_step=target)
+                        log_event(tracker, step, ROLLBACK, emit_detail)
+                        log(f"[resilience] step {step}: {rs.max_consecutive_skips}"
+                            f" consecutive skips — rolled back to step {target}, "
+                            f"data cursor +{jump}, LR re-warm "
+                            f"{rs.rewarm_steps} steps")
                         step = target
                         continue
+                    # no checkpoint to roll back to: the skipped updates never
+                    # touched params, so training continues on the next batch —
+                    # but say so loudly
+                    reason = ("no checkpoint directory" if not loop_cfg.ckpt_dir
+                              else "no restorable checkpoint")
+                    policy.on_rollback(step, None, reason=reason)
+                    log_event(tracker, step, "rollback_unavailable",
+                              {"reason": reason})
+                    log(f"[resilience] step {step}: rollback wanted but no "
+                        f"checkpoint available — continuing (updates were "
+                        f"skipped, params are clean)")
 
-            # --- recovery policy: reads the in-step anomaly scalars that
-            # already ride the metrics transfer -----------------------------
-            action = policy.observe(step, metrics)
-            if action == SKIP:
-                forensics(policy.events[-1].detail, batch, metrics, step)
-                log(f"[resilience] step {step}: anomalous update skipped "
-                    f"(grad_norm={policy.events[-1].detail['grad_norm']:.4g}, "
-                    f"{policy.consecutive_skips} consecutive)")
-                log_event(tracker, step, policy.events[-1].kind,
-                          policy.events[-1].detail)
-            elif action == ROLLBACK:
-                forensics(policy.events[-1].detail, batch, metrics, step)
-                log_event(tracker, step, policy.events[-1].kind,
-                          policy.events[-1].detail)
-                t0 = clock()
-                restored = extra2 = None
-                if loop_cfg.ckpt_dir:
-                    pending_writer = reap_writer(pending_writer, block=True,
-                                                 at_step=step)
-                    restored, extra2, ck = restore_latest(
-                        loop_cfg.ckpt_dir, canonicalize_state(state, plan),
-                        retry=retry, log=log, fault_hook=read_fault)
-                if restored is not None:
-                    target = int(extra2.get("next_step", ck))
-                    jump = (step + 1 - target) + rs.skip_window_margin
-                    data_offset += jump
-                    state = reshard_state(restored, plan)
-                    if rs.rewarm_steps > 0 and "rstat" in state:
-                        state["rstat"] = dict(
-                            state["rstat"],
-                            rewarm=np.asarray(rs.rewarm_steps, np.int32))
-                    state = jax.tree_util.tree_map(jax.numpy.asarray, state)
-                    detail = {"steps_lost": step + 1 - target,
-                              "data_skipped": jump,
-                              "rewarm_steps": rs.rewarm_steps,
-                              "latency_s": float(clock() - t0)}
-                    policy.on_rollback(step, target, **detail)
-                    emit_detail = dict(detail, restored_step=target)
-                    log_event(tracker, step, ROLLBACK, emit_detail)
-                    log(f"[resilience] step {step}: {rs.max_consecutive_skips}"
-                        f" consecutive skips — rolled back to step {target}, "
-                        f"data cursor +{jump}, LR re-warm "
-                        f"{rs.rewarm_steps} steps")
-                    step = target
-                    continue
-                # no checkpoint to roll back to: the skipped updates never
-                # touched params, so training continues on the next batch —
-                # but say so loudly
-                reason = ("no checkpoint directory" if not loop_cfg.ckpt_dir
-                          else "no restorable checkpoint")
-                policy.on_rollback(step, None, reason=reason)
-                log_event(tracker, step, "rollback_unavailable",
-                          {"reason": reason})
-                log(f"[resilience] step {step}: rollback wanted but no "
-                    f"checkpoint available — continuing (updates were "
-                    f"skipped, params are clean)")
-
-            if step % loop_cfg.log_every == 0:
-                m = {k: float(np.asarray(v)) for k, v in metrics.items()}
-                history.append({"step": step, **m})
-                if tracker is not None:
-                    tracker.log_metrics(step, m)
-                log(f"[loop] step {step}: " +
-                    " ".join(f"{k}={v:.4g}" for k, v in m.items()))
-            # never checkpoint mid skip-streak: a rollback target must be a
-            # step the policy considered healthy
-            if (loop_cfg.ckpt_dir and (step + 1) % loop_cfg.ckpt_every == 0
-                    and policy.healthy):
-                write_ckpt(step + 1)
-            step += 1
+                if step % loop_cfg.log_every == 0:
+                    with TraceAnnotation("train.log"):
+                        m = {k: float(np.asarray(v))
+                             for k, v in metrics.items()}
+                        history.append({"step": step, **m})
+                        if tracker is not None:
+                            tracker.log_metrics(step, m)
+                        log(f"[loop] step {step}: " +
+                            " ".join(f"{k}={v:.4g}" for k, v in m.items()))
+                # never checkpoint mid skip-streak: a rollback target must be a
+                # step the policy considered healthy
+                if (loop_cfg.ckpt_dir and (step + 1) % loop_cfg.ckpt_every == 0
+                        and policy.healthy):
+                    write_ckpt(step + 1)
+                step += 1
     except Preempted:
         if loop_cfg.ckpt_dir:
             write_ckpt(step, emergency=True)

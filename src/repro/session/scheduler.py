@@ -41,6 +41,7 @@ from typing import Dict, List, Optional, Tuple
 
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 
 @dataclasses.dataclass
@@ -51,7 +52,8 @@ class Request:
     max_new_tokens: int
     stop_token: Optional[int] = None
     submit_time: float = 0.0
-    admit_time: Optional[float] = None
+    admit_time: Optional[float] = None        # admission (prefill) start
+    first_token_time: Optional[float] = None  # first token read
 
 
 @dataclasses.dataclass
@@ -60,10 +62,9 @@ class ServingStats:
     requests: int = 0
     generated_tokens: int = 0
     decode_steps: int = 0
-    wall_time_s: float = 0.0
-    tok_per_s: float = 0.0
     occupancy: float = 0.0             # mean active-slot fraction per decode step
-    mean_queue_wait_s: float = 0.0     # submit → admission (prefill start)
+    admit_s: float = 0.0               # time in admission: prefills and the
+    #                                    first-token reads that wait on them
     max_queue_depth: int = 0
     # memory accounting (see repro.session.kvpool): stranded_fraction is the
     # mean over decode steps of 1 - live_tokens / reserved_token_capacity —
@@ -77,14 +78,61 @@ class ServingStats:
     pool_occupancy: float = 0.0        # mean allocated-page fraction per step
     prefix_hits: int = 0               # admissions that shared ≥ 1 token
     prefix_hit_rate: float = 0.0       # shared prompt tokens / prompt tokens
+    # the run's clock: one stamp per decode step, taken once its tokens are
+    # read; per request, in the order they finished, its stamps and the
+    # decode steps [first, end) that gave it a token after the first
+    step_times: List[float] = dataclasses.field(default_factory=list)
+    submit_times: List[float] = dataclasses.field(default_factory=list)
+    admit_times: List[float] = dataclasses.field(default_factory=list)
+    first_token_times: List[float] = dataclasses.field(default_factory=list)
+    token_steps: List[Tuple[int, int]] = dataclasses.field(
+        default_factory=list)
+
+    def finished(self, st: "_Slot", steps: int) -> None:
+        """Record request ``st`` retiring after ``steps`` decode steps."""
+        req = st.req
+        self.submit_times.append(req.submit_time)
+        self.admit_times.append(req.admit_time)
+        self.first_token_times.append(req.first_token_time)
+        self.token_steps.append((st.first_step, steps))
+
+    @property
+    def mean_queue_wait_s(self) -> float:
+        """Submit → admission (prefill start), over finished requests."""
+        return _mean_gap(self.submit_times, self.admit_times)
+
+    @property
+    def mean_ttft_s(self) -> float:
+        """Submit → first token read, over finished requests."""
+        return _mean_gap(self.submit_times, self.first_token_times)
+
+    def finish_times(self) -> List[float]:
+        """When each finished request's last token was read."""
+        return [self.step_times[b - 1] if b > a else t0
+                for t0, (a, b) in zip(self.first_token_times,
+                                      self.token_steps)]
+
+    def token_gaps(self) -> List[float]:
+        """Every finished request's gaps between consecutive tokens."""
+        gaps = []
+        for t0, (a, b) in zip(self.first_token_times, self.token_steps):
+            times = [t0] + self.step_times[a:b]
+            gaps += [y - x for x, y in zip(times, times[1:])]
+        return gaps
 
     def __str__(self) -> str:
+        latency = _mean_gap(self.submit_times, self.finish_times())
         return (f"ServingStats(requests={self.requests}, "
-                f"tok/s={self.tok_per_s:.1f}, "
                 f"occupancy={self.occupancy:.2f}, "
                 f"stranded={self.stranded_fraction:.2f}, "
                 f"steps={self.decode_steps}, "
-                f"queue_wait={self.mean_queue_wait_s * 1e3:.1f}ms)")
+                f"queue_wait={self.mean_queue_wait_s * 1e3:.1f}ms, "
+                f"ttft={self.mean_ttft_s * 1e3:.1f}ms, "
+                f"latency={latency * 1e3:.1f}ms)")
+
+
+def _mean_gap(starts: List[float], ends: List[float]) -> float:
+    return float(np.mean(np.subtract(ends, starts))) if starts else 0.0
 
 
 class RequestQueue:
@@ -137,6 +185,7 @@ class _Slot:
     last: int                          # last emitted token (next step's input)
     out: List[int]                     # prompt + generated so far
     remaining: int                     # new tokens still owed
+    first_step: int = 0                # first decode step it takes part in
 
 
 class ContinuousBatchingScheduler:
@@ -246,12 +295,12 @@ class ContinuousBatchingScheduler:
             logits, group_c = sess.prefill_cache_step(
                 sess.params, batch, self._fresh_cache(W))
             toks0 = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
-            admit_time = clock()
+            first_token_time = clock()
             for j, (slot_idx, req) in enumerate(items):
                 slot_c = group_c if W == 1 else sess.take_slot(
                     group_c, jnp.int32(j))
                 caches = sess.insert_slot(caches, slot_c, jnp.int32(slot_idx))
-                req.admit_time = admit_time
+                req.first_token_time = first_token_time
                 P = len(req.prompt)
                 states[slot_idx] = _Slot(
                     req=req, t=P, last=int(toks0[j]),
@@ -263,6 +312,13 @@ class ContinuousBatchingScheduler:
     def _finished(state: _Slot) -> bool:
         stop = state.req.stop_token
         return state.remaining <= 0 or (stop is not None and state.last == stop)
+
+    @staticmethod
+    def _admitted(state: _Slot, t_admit: float, steps: int) -> None:
+        """Stamp an admission that started at ``t_admit``, before decode
+        step ``steps``."""
+        state.req.admit_time = t_admit
+        state.first_step = steps
 
     # ------------------------------------------------------------------
     def run(self, queue: RequestQueue,
@@ -281,19 +337,19 @@ class ContinuousBatchingScheduler:
         caches = sess.init_cache(B, self.max_len)
         slots: List[Optional[_Slot]] = [None] * B
         outputs: Dict[int, np.ndarray] = {}
-        waits: List[float] = []
+        stats = ServingStats()
         steps = 0
         occupied = 0
         generated = 0
         n_requests = 0
         prompt_tokens = 0
         stranded = 0.0
-        t0 = clock()
 
         def retire(i: int):
             nonlocal generated, caches
             st = slots[i]
             outputs[st.req.rid] = np.asarray(st.out, np.int32)
+            stats.finished(st, steps)
             generated += len(st.out) - len(st.req.prompt)
             # reset the freed slot on device (pos → -1, state → 0): stale
             # K/V must be invalid the moment the slot is free, not only
@@ -306,53 +362,58 @@ class ContinuousBatchingScheduler:
             # same-width prompts share a single batched mixed-length prefill
             free = [i for i in range(B) if slots[i] is None]
             if free and len(queue):
-                assignments = [(i, queue.pop())
-                               for i in free[:min(len(free), len(queue))]]
-                caches, admitted = self._admit_many(caches, assignments, clock)
-                for i, st in admitted.items():
-                    slots[i] = st
-                    waits.append(st.req.admit_time - st.req.submit_time)
-                    n_requests += 1
-                    prompt_tokens += len(st.req.prompt)
-                    if self._finished(st):         # stop token in prefill,
-                        retire(i)                  # or max_new_tokens == 1
+                with TraceAnnotation("serve.admit"):
+                    t_admit = clock()
+                    assignments = [(i, queue.pop())
+                                   for i in free[:min(len(free), len(queue))]]
+                    caches, admitted = self._admit_many(caches, assignments,
+                                                        clock)
+                    for i, st in admitted.items():
+                        slots[i] = st
+                        self._admitted(st, t_admit, steps)
+                        n_requests += 1
+                        prompt_tokens += len(st.req.prompt)
+                        if self._finished(st):     # stop token in prefill,
+                            retire(i)              # or max_new_tokens == 1
+                    stats.admit_s += clock() - t_admit
 
             active = [i for i in range(B) if slots[i] is not None]
             if not active:
                 continue
 
             # one decode step at full batch width, per-slot positions
-            toks = np.zeros((B,), np.int32)
-            ts = np.zeros((B,), np.int32)
-            for i in active:
-                toks[i] = slots[i].last
-                ts[i] = slots[i].t
-            nxt, caches = sess.slot_step(sess.params, jnp.asarray(toks),
-                                         jnp.asarray(ts), caches)
-            nxt = np.asarray(nxt)
-            steps += 1
-            occupied += len(active)
-            live = sum(slots[i].t for i in active)
-            stranded += 1.0 - live / (len(active) * self.max_len)
+            with TraceAnnotation("serve.decode"):
+                toks = np.zeros((B,), np.int32)
+                ts = np.zeros((B,), np.int32)
+                for i in active:
+                    toks[i] = slots[i].last
+                    ts[i] = slots[i].t
+                nxt, caches = sess.slot_step(sess.params, jnp.asarray(toks),
+                                             jnp.asarray(ts), caches)
+            with TraceAnnotation("serve.read"):
+                nxt = np.asarray(nxt)
+            stats.step_times.append(clock())
+            with TraceAnnotation("serve.retire"):
+                steps += 1
+                occupied += len(active)
+                live = sum(slots[i].t for i in active)
+                stranded += 1.0 - live / (len(active) * self.max_len)
 
-            for i in active:
-                st = slots[i]
-                st.last = int(nxt[i])
-                st.out.append(st.last)
-                st.t += 1
-                st.remaining -= 1
-                if self._finished(st):
-                    retire(i)
+                for i in active:
+                    st = slots[i]
+                    st.last = int(nxt[i])
+                    st.out.append(st.last)
+                    st.t += 1
+                    st.remaining -= 1
+                    if self._finished(st):
+                        retire(i)
 
-        wall = max(clock() - t0, 1e-9)
-        stats = ServingStats(
+        stats = dataclasses.replace(
+            stats,
             requests=n_requests,
             generated_tokens=generated,
             decode_steps=steps,
-            wall_time_s=wall,
-            tok_per_s=generated / wall,
             occupancy=occupied / (steps * B) if steps else 0.0,
-            mean_queue_wait_s=float(np.mean(waits)) if waits else 0.0,
             max_queue_depth=queue.max_depth,
             stranded_fraction=stranded / steps if steps else 0.0,
             prompt_tokens=prompt_tokens,
@@ -450,10 +511,10 @@ class ContinuousBatchingScheduler:
                 sess.params, batch, holder["pool"],
                 jnp.asarray(mgr.tables[slot_ids]))
             toks0 = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
-            admit_time = clock()
+            first_token_time = clock()
             for j, (slot_idx, req, hist) in enumerate(rows):
                 mgr.register(slot_idx, req.prompt)  # publish for future hits
-                req.admit_time = admit_time
+                req.first_token_time = first_token_time
                 P = len(req.prompt)
                 prompt_toks += P
                 prefill_toks += P - hist
@@ -478,16 +539,16 @@ class ContinuousBatchingScheduler:
         hits0 = mgr.cache.hits if mgr.cache is not None else 0
         slots: List[Optional[_Slot]] = [None] * B
         outputs: Dict[int, np.ndarray] = {}
-        waits: List[float] = []
+        stats = ServingStats()
         steps = occupied = generated = n_requests = 0
         prompt_tokens = prefill_tokens = shared_tokens = 0
         pool_occ = stranded = 0.0
-        t0 = clock()
 
         def retire(i: int):
             nonlocal generated
             st = slots[i]
             outputs[st.req.rid] = np.asarray(st.out, np.int32)
+            stats.finished(st, steps)
             generated += len(st.out) - len(st.req.prompt)
             mgr.free_slot(i)        # release pages; no device zeroing needed:
             slots[i] = None         # unmapped rows are masked at read time
@@ -495,74 +556,80 @@ class ContinuousBatchingScheduler:
         while len(queue) or any(s is not None for s in slots):
             free = [i for i in range(B) if slots[i] is None]
             if free and len(queue):
-                assignments = [(i, queue.pop())
-                               for i in free[:min(len(free), len(queue))]]
-                reserved = sum(self._reserve_pages(slots[i].req)
-                               for i in range(B) if slots[i] is not None)
-                admitted, deferred, ptk, ftk, stk = self._admit_many_paged(
-                    mgr, holder, assignments, clock, reserved)
-                for req in reversed(deferred):
-                    queue.push_front(req)
-                if deferred and not admitted and \
-                        all(s is None for s in slots):
-                    raise MemoryError(
-                        f"paged pool ({self.n_pages - 1} pages of "
-                        f"{self.page_size}) cannot admit request "
-                        f"{deferred[0].rid} even with every slot idle — "
-                        "grow n_pages or shrink max_len")
-                prompt_tokens += ptk
-                prefill_tokens += ftk
-                shared_tokens += stk
-                for i, st in admitted.items():
-                    slots[i] = st
-                    waits.append(st.req.admit_time - st.req.submit_time)
-                    n_requests += 1
-                    if self._finished(st):
-                        retire(i)
+                with TraceAnnotation("serve.admit"):
+                    t_admit = clock()
+                    assignments = [(i, queue.pop())
+                                   for i in free[:min(len(free), len(queue))]]
+                    reserved = sum(self._reserve_pages(slots[i].req)
+                                   for i in range(B) if slots[i] is not None)
+                    admitted, deferred, ptk, ftk, stk = \
+                        self._admit_many_paged(mgr, holder, assignments,
+                                               clock, reserved)
+                    for req in reversed(deferred):
+                        queue.push_front(req)
+                    if deferred and not admitted and \
+                            all(s is None for s in slots):
+                        raise MemoryError(
+                            f"paged pool ({self.n_pages - 1} pages of "
+                            f"{self.page_size}) cannot admit request "
+                            f"{deferred[0].rid} even with every slot idle — "
+                            "grow n_pages or shrink max_len")
+                    prompt_tokens += ptk
+                    prefill_tokens += ftk
+                    shared_tokens += stk
+                    for i, st in admitted.items():
+                        slots[i] = st
+                        self._admitted(st, t_admit, steps)
+                        n_requests += 1
+                        if self._finished(st):
+                            retire(i)
+                    stats.admit_s += clock() - t_admit
 
             active = [i for i in range(B) if slots[i] is not None]
             if not active:
                 continue
 
-            # next write position must be mapped & exclusively owned (lazy
-            # page growth + the COW copy of shared/registered pages)
-            for i in active:
-                mgr.ensure_writable(i, slots[i].t)
+            with TraceAnnotation("serve.decode"):
+                # next write position must be mapped & exclusively owned
+                # (lazy page growth + the COW copy of shared/registered
+                # pages)
+                for i in active:
+                    mgr.ensure_writable(i, slots[i].t)
 
-            toks = np.zeros((B,), np.int32)
-            ts = np.zeros((B,), np.int32)
-            for i in active:
-                toks[i] = slots[i].last
-                ts[i] = slots[i].t
-            nxt, holder["pool"] = sess.paged_slot_step(
-                sess.params, jnp.asarray(toks), jnp.asarray(ts),
-                holder["pool"], jnp.asarray(mgr.tables))
-            nxt = np.asarray(nxt)
-            steps += 1
-            occupied += len(active)
-            pool_occ += mgr.pool.n_used / (self.n_pages - 1)
-            live = sum(slots[i].t for i in active)
-            cap = sum(mgr.capacity_tokens(i) for i in active)
-            stranded += 1.0 - live / cap if cap else 0.0
+                toks = np.zeros((B,), np.int32)
+                ts = np.zeros((B,), np.int32)
+                for i in active:
+                    toks[i] = slots[i].last
+                    ts[i] = slots[i].t
+                nxt, holder["pool"] = sess.paged_slot_step(
+                    sess.params, jnp.asarray(toks), jnp.asarray(ts),
+                    holder["pool"], jnp.asarray(mgr.tables))
+            with TraceAnnotation("serve.read"):
+                nxt = np.asarray(nxt)
+            stats.step_times.append(clock())
+            with TraceAnnotation("serve.retire"):
+                steps += 1
+                occupied += len(active)
+                pool_occ += mgr.pool.n_used / (self.n_pages - 1)
+                live = sum(slots[i].t for i in active)
+                cap = sum(mgr.capacity_tokens(i) for i in active)
+                stranded += 1.0 - live / cap if cap else 0.0
 
-            for i in active:
-                st = slots[i]
-                st.last = int(nxt[i])
-                st.out.append(st.last)
-                st.t += 1
-                st.remaining -= 1
-                if self._finished(st):
-                    retire(i)
+                for i in active:
+                    st = slots[i]
+                    st.last = int(nxt[i])
+                    st.out.append(st.last)
+                    st.t += 1
+                    st.remaining -= 1
+                    if self._finished(st):
+                        retire(i)
 
-        wall = max(clock() - t0, 1e-9)
-        stats = ServingStats(
+        stats = dataclasses.replace(
+            stats,
             requests=n_requests,
             generated_tokens=generated,
             decode_steps=steps,
-            wall_time_s=wall,
-            tok_per_s=generated / wall,
             occupancy=occupied / (steps * B) if steps else 0.0,
-            mean_queue_wait_s=float(np.mean(waits)) if waits else 0.0,
             max_queue_depth=queue.max_depth,
             stranded_fraction=stranded / steps if steps else 0.0,
             prompt_tokens=prompt_tokens,

@@ -87,6 +87,48 @@ def test_slot_reuse_mid_flight():
         np.testing.assert_array_equal(outputs[rid], ref)
 
 
+class _Tick:
+    """A clock that reads one unit later at every call."""
+
+    def __init__(self):
+        self.t = 0.0
+
+    def __call__(self) -> float:
+        self.t += 1.0
+        return self.t
+
+
+@pytest.mark.parametrize("paged", [False, True], ids=["slots", "paged"])
+def test_stats_stamp_steps_and_requests(paged):
+    """On a clock that ticks once a call: three requests, two slots.  1 and
+    2 are admitted together (ticks 4-6: start, first tokens, end); 2 ends
+    after decode step 1 (tick 7); 3 is admitted into its slot (ticks 8-10)
+    with budget 1, so it ends at its first token; 1 decodes alone to step
+    4 (ticks 11-13)."""
+    sess = _session("granite_3_2b")
+    clock = _Tick()
+    queue = RequestQueue(clock=clock)
+    rids = [queue.submit(p, m)
+            for p, m in zip(_prompts(sess, (6, 6, 6)), (5, 2, 1))]
+    sched = ContinuousBatchingScheduler(sess, n_slots=2, max_len=11,
+                                        paged=paged, page_size=4)
+    outputs, stats = sched.run(queue, clock=clock)
+    assert stats.decode_steps == 4
+    assert stats.step_times == [7.0, 11.0, 12.0, 13.0]
+    assert stats.admit_s == 4.0
+    finish_order = [rids[1], rids[2], rids[0]]
+    assert stats.submit_times == [2.0, 3.0, 1.0]
+    assert stats.admit_times == [4.0, 8.0, 4.0]
+    assert stats.first_token_times == [5.0, 9.0, 5.0]
+    assert stats.finish_times() == [7.0, 9.0, 13.0]
+    assert stats.token_steps == [(0, 1), (1, 1), (0, 4)]
+    for rid, (a, b) in zip(finish_order, stats.token_steps):
+        assert len(outputs[rid]) - 6 == 1 + b - a        # first token + steps
+    assert stats.mean_queue_wait_s == pytest.approx((2 + 5 + 3) / 3)
+    assert stats.mean_ttft_s == pytest.approx((3 + 6 + 4) / 3)
+    assert sorted(stats.token_gaps()) == [1.0, 1.0, 2.0, 2.0, 4.0]
+
+
 def test_stop_token_frees_slot_early():
     """A request whose greedy decode hits its stop token ends there: the
     scheduler returns the truncated sequence and the static ``generate``

@@ -12,6 +12,7 @@ tests and only the worker given this file loads the TPU library).
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -67,8 +68,10 @@ def test_flash_fwd_bwd_compiles(one_chip, packed):
         return out.astype(jnp.float32).sum()
 
     hlo = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv, seg)
-    # forward, delta preprocess, dQ sweep, dK/dV sweep
+    # forward, delta preprocess, dQ sweep, dK/dV sweep, each by its name
     assert hlo.count("custom_call_target=\"tpu_custom_call\"") == 4
+    for part in ("fwd", "delta", "dq", "dkv"):
+        assert re.search(rf"%flash_attention_{part}(\.\d+)? = ", hlo), part
 
 
 def test_decode_compiles(one_chip):
@@ -81,6 +84,7 @@ def test_decode_compiles(one_chip):
                                                   interpret=False),
         q, kv, kv, kpos, t)
     assert "tpu_custom_call" in hlo
+    assert re.search(r"%decode_attention(\.\d+)? = ", hlo)
 
 
 def test_paged_decode_compiles(one_chip):
@@ -93,6 +97,7 @@ def test_paged_decode_compiles(one_chip):
             q, kp, vp, pt, ts=ts, interpret=False),
         q, pool, pool, table, ts)
     assert "tpu_custom_call" in hlo
+    assert re.search(r"%paged_decode_attention(\.\d+)? = ", hlo)
 
 
 def test_rmsnorm_compiles(one_chip):
