@@ -321,9 +321,9 @@ def default_kernel_captures(cfg=None) -> List[KernelCapture]:
 
     records: List[KernelCapture] = []
     with capture_pallas_calls(records):
-        o, lse = fa._forward(q, k, v, None, True, None, bq, bk, False)
+        o, lse = fa._forward(q, k, v, None, True, None, bq, bk, None, False)
         fa._backward(q, k, v, None, o, lse, jnp.ones_like(o),
-                     True, None, bq, bk, False)
+                     True, None, bq, bk, None, False)
 
         Sc, bkd = 512, 128
         kc = jax.random.normal(key, (B, Sc, Hkv, D), jnp.float32)

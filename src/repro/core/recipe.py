@@ -33,7 +33,7 @@ class ParallelismConfig:
     # params to bf16 and all-gather them ONCE per step instead of letting XLA
     # re-gather the fp32 masters inside every pipeline superstep.
     flash_bq: Optional[int] = None    # flash-attention Q/K block-size override
-    flash_bk: Optional[int] = None    # (autotuning hook; None → 128/64 heuristic)
+    flash_bk: Optional[int] = None    # (autotuning hook; None → ops.py table)
     vpp: int = 1             # virtual pipeline stages per physical stage
     # (Megatron interleaved-1F1B, arXiv 2104.04473): each physical stage holds
     # ``vpp`` model chunks of L/(PP·VPP) layers; micro-batches loop the stage

@@ -21,13 +21,48 @@ def _interpret() -> bool:
     return jax.default_backend() == "cpu"
 
 
-def _flash_blocks(Sq: int, Sk: int):
-    """(bq, bk) for the flash kernels: the ParallelismConfig/flags override
-    when set (autotuning hook), else the largest of 128/64 that divides."""
+# Flash tiles by shape class, measured on a TPU v5e at the packed training
+# cell's shapes (PERF.md §5): a step folds gf·bq = MAX_FOLD_ROWS query rows,
+# and sweeps K blocks of bk by whether segment ids are present.
+_FLASH_BK = {True: 512, False: 1024}
+# f32 (gf·bq, bk) tile elements at a padded head dim up to 128; a wider head
+# gets proportionally fewer (the compiler's VMEM limit)
+_FLASH_TILE_ELEMS = 1 << 20
+
+
+def _fit(want: int, S: int) -> int:
+    """The largest of ``want``, ``want/2``, ... 64 that divides ``S`` (``S``
+    itself when shorter); 64 when none does, which flash_supported refuses."""
+    b = want
+    while b > 64 and S % b:
+        b //= 2
+    return min(b, S)
+
+
+def _flash_blocks(Sq: int, Sk: int, *, g: int = 1, D: int = 64,
+                  packed: bool = False):
+    """(bq, bk, gf) for the flash kernels from the call's shape: the fold
+    takes the largest divisor of the GQA group ``g = Hq/Hkv`` that leaves
+    bq ≥ 128, bq fills the fold's MAX_FOLD_ROWS, and bk comes from the
+    table, held to the VMEM budget of the head dim; each cut to what
+    divides the sequence.  A ParallelismConfig / flags override of bq or bk
+    (autotuning hook) wins over the table, and the fold follows its bq."""
+    from repro.kernels.flash_attention import MAX_FOLD_ROWS, group_fold
     obq, obk = flags.flash_block_sizes()
-    bq = obq or (128 if Sq % 128 == 0 else 64)
-    bk = obk or (128 if Sk % 128 == 0 else 64)
-    return min(bq, Sq), min(bk, Sk)
+    if obq:
+        bq = min(obq, Sq)
+        gf = group_fold(g, bq)
+    else:
+        gf = group_fold(g, 128)
+        rows = 1 << (MAX_FOLD_ROWS // gf).bit_length() - 1     # power of 2
+        bq = _fit(rows, Sq)
+    if obk:
+        bk = min(obk, Sk)
+    else:
+        Dp = max(128, -(-D // 128) * 128)
+        budget = _FLASH_TILE_ELEMS * 128 // Dp // (gf * bq)
+        bk = _fit(min(_FLASH_BK[packed], max(budget, 128)), Sk)
+    return bq, bk, gf
 
 
 def flash_supported(q, k, *, causal: bool = True,
@@ -49,7 +84,8 @@ def flash_supported(q, k, *, causal: bool = True,
         return False        # traced per-layer window (Hymba) → reference path
     if (causal or window is not None or segment_ids is not None) and Sq != Sk:
         return False
-    bq, bk = _flash_blocks(Sq, Sk)
+    bq, bk, _ = _flash_blocks(Sq, Sk, g=q.shape[2] // k.shape[2],
+                              D=q.shape[3], packed=segment_ids is not None)
     return Sq % bq == 0 and Sk % bk == 0
 
 
@@ -96,12 +132,14 @@ def flash_attention(q, k, v, *, causal: bool = True,
                            segment_ids=segment_ids):
         return ref.mha_reference(q, k, v, causal=causal, window=window,
                                  segment_ids=segment_ids)
-    bq, bk = _flash_blocks(q.shape[1], k.shape[1])
+    bq, bk, gf = _flash_blocks(q.shape[1], k.shape[1],
+                               g=q.shape[2] // k.shape[2], D=q.shape[3],
+                               packed=segment_ids is not None)
 
     def kernel(q, k, v, segment_ids):
         return fa.flash_attention(q, k, v, segment_ids=segment_ids,
                                   causal=causal, window=window, bq=bq, bk=bk,
-                                  interpret=_interpret())
+                                  gf=gf, interpret=_interpret())
 
     return _per_shard(kernel, q, k, v, segment_ids)
 

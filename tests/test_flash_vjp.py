@@ -33,6 +33,9 @@ def _grads(fn, q, k, v, cot):
     (True, 32, 4, 2, 96),       # window + GQA + padded head dim
     (False, None, 4, 1, 64),    # bidirectional MQA
     (True, None, 4, 4, 120),    # odd head dim (pad to 128 inside the kernel)
+    (True, None, 32, 1, 64),    # MQA: the fold cap splits the group of 32
+    (False, None, 2, 2, 64),    # g = 1: no fold, dead-tile clamp only
+    (True, 48, 4, 2, 64),       # sliding window + g = 2 folded
 ])
 def test_flash_vjp_matches_reference_autodiff(causal, window, Hq, Hkv, D):
     B, S = 2, 128
@@ -61,17 +64,29 @@ def _segments(B, S, lens):
     return jnp.asarray(np.broadcast_to(seg, (B, S)).copy())
 
 
-@pytest.mark.parametrize("causal,window,Hq,Hkv,D", [
+_SEG_CASES = [
     (True, None, 4, 4, 64),     # packed causal MHA
     (True, 32, 8, 2, 64),       # packed + sliding window + GQA
     (False, None, 4, 2, 96),    # packed bidirectional + padded head dim
+    (True, None, 8, 2, 64),     # packed causal, g = 4 in one fold
+]
+
+
+@pytest.mark.parametrize("causal,window,Hq,Hkv,D,S,lens", [
+    pytest.param(*c, 128, (40, 50, 38), id="-".join(map(str, c)))
+    for c in _SEG_CASES
+] + [
+    # documents on block edges: whole tile rows and columns are dead, so the
+    # index maps clamp in fwd, dQ and dK/dV, before and after the live range
+    pytest.param(True, None, 8, 2, 64, 256, (64, 64, 128), id="dead-rows"),
 ])
-def test_flash_vjp_segment_ids_match_reference_autodiff(causal, window, Hq, Hkv, D):
+def test_flash_vjp_segment_ids_match_reference_autodiff(causal, window, Hq,
+                                                        Hkv, D, S, lens):
     """Segment-aware kernels (block-skip + in-tile mask, fwd AND the three
     bwd sweeps) against reference autodiff with the same equality mask."""
-    B, S = 2, 128
+    B = 2
     q, k, v = _qkv(B, S, Hq, Hkv, D)
-    seg = _segments(B, S, (40, 50, 38))
+    seg = _segments(B, S, lens)
     cot = jax.random.normal(jax.random.fold_in(KEY, 3), (B, S, Hq, D))
 
     def fl(q, k, v):
@@ -199,3 +214,63 @@ def test_block_size_override_threads_through_ops():
     want = ref.mha_reference(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("S,Hq,Hkv,packed", [
+    (2048, 32, 8, True),        # the packed training cell: GQA g = 4
+    (2048, 32, 8, False),
+    (4096, 32, 32, False),      # MHA: no fold
+    (1024, 64, 1, True),        # MQA: the fold cap splits the group
+    (640, 8, 2, True),          # only 128 divides
+    (32, 4, 2, False),          # shorter than a block: the block is S
+])
+def test_flash_block_table_fits_the_shape(S, Hq, Hkv, packed):
+    """ops._flash_blocks: tiles that divide S, a fold that divides the group
+    and keeps gf·bq within the cap, gf = 1 for MHA."""
+    from repro.kernels import ops
+    from repro.kernels.flash_attention import MAX_FOLD_ROWS
+    g = Hq // Hkv
+    bq, bk, gf = ops._flash_blocks(S, S, g=g, packed=packed)
+    assert S % bq == 0 and S % bk == 0
+    assert g % gf == 0 and gf * bq <= max(MAX_FOLD_ROWS, bq)
+    if g == 1:
+        assert gf == 1
+    if g * bq > MAX_FOLD_ROWS:
+        assert gf < g
+    q, k, _ = _qkv(1, S, Hq, Hkv, 64)
+    seg = jnp.zeros((1, S), jnp.int32) if packed else None
+    assert ops.flash_supported(q, k, causal=True, segment_ids=seg)
+
+
+def test_flash_block_table_falls_back_when_nothing_divides():
+    from repro.kernels import ops
+    q, k, _ = _qkv(1, 96, 4, 2, 64)
+    assert ops._flash_blocks(96, 96, g=2, packed=True)[:2] == (64, 64)
+    assert not ops.flash_supported(q, k, causal=True,
+                                   segment_ids=jnp.zeros((1, 96), jnp.int32))
+    assert not ops.flash_supported(q, k, causal=True)
+
+
+def test_tile_table_clamps_dead_tiles_and_mirrors_cost_model():
+    """The kernels' tile table: documents on block edges give each q block a
+    live K range strictly inside the row (the clamp has work to do), and the
+    live count equals cost_model.flash_block_skip_fraction's mirror."""
+    from repro.core.cost_model import flash_block_skip_fraction
+    from repro.kernels import flash_attention as fa
+    S, bq, bk = 256, 64, 64
+    seg = _segments(2, S, (64, 64, 128))
+    lo, hi, *bounds = fa._tile_table(seg, 2, S // bq, S // bk, bq=bq, bk=bk,
+                                     causal=True, window=None, sweep_axis=2)
+    assert lo[:4].tolist() == [0, 1, 2, 2] and hi[:4].tolist() == [0, 1, 2, 3]
+    lo, hi, *_ = fa._tile_table(seg, 2, S // bq, S // bk, bq=bq, bk=bk,
+                                causal=True, window=None, sweep_axis=1)
+    assert lo[:4].tolist() == [0, 1, 2, 3] and hi[:4].tolist() == [0, 1, 3, 3]
+    rng = np.random.default_rng(0)
+    for causal, window in ((True, None), (True, 100), (False, None)):
+        ids = np.sort(rng.integers(0, 6, (3, S)), axis=1).astype(np.int32)
+        bounds = fa._seg_bounds(jnp.asarray(ids), bq, bk)
+        live = fa._live_tiles(bounds, 3, S // bq, S // bk, bq=bq, bk=bk,
+                              causal=causal, window=window)
+        skip = flash_block_skip_fraction(ids, bq=bq, bk=bk, causal=causal,
+                                         window=window)
+        assert float(1 - live.mean()) == pytest.approx(skip)

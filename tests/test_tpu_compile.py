@@ -58,13 +58,18 @@ def _compiled_text(fn, *specs) -> str:
 
 @pytest.mark.parametrize("packed", [False, True], ids=["plain", "segment_ids"])
 def test_flash_fwd_bwd_compiles(one_chip, packed):
-    q = _spec((B, S, HQ, D), jnp.bfloat16, one_chip)
-    kv = _spec((B, S, HKV, D), jnp.bfloat16, one_chip)
-    seg = _spec((B, S), jnp.int32, one_chip)
+    """At the training cell's shapes (batch 4) and the tiles and GQA fold
+    that ``ops._flash_blocks`` picks for them."""
+    from repro.kernels import ops
+    q = _spec((4, S, HQ, D), jnp.bfloat16, one_chip)
+    kv = _spec((4, S, HKV, D), jnp.bfloat16, one_chip)
+    seg = _spec((4, S), jnp.int32, one_chip)
+    bq, bk, gf = ops._flash_blocks(S, S, g=HQ // HKV, D=D, packed=packed)
 
     def loss(q, k, v, seg):
         out = flash_attention(q, k, v, segment_ids=seg if packed else None,
-                              causal=True, bq=128, bk=128, interpret=False)
+                              causal=True, bq=bq, bk=bk, gf=gf,
+                              interpret=False)
         return out.astype(jnp.float32).sum()
 
     hlo = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv, seg)
