@@ -2,7 +2,10 @@
 
 Set-up builds one session, puts the seed's weights in its state, and drives
 it through its first three steps with ``run`` on the feed the window uses;
-those steps are what the reference checks.  Two more steps time one step,
+those steps are what the reference checks.  A plan over the cell's chips
+(``world`` equal to the cell's ``chips``) runs on the recipe mesh of those
+chips, the state and the feed placed by the session's shardings; a plan of
+one chip runs on the first device with no mesh.  Two more steps time one step,
 and the window is the whole steps that fit in ``--seconds`` at that time,
 run by one more ``run`` call that ends in ``block_until_ready``.  No
 checkpoint directory is given, so nothing is saved, and ``log_every`` keeps
@@ -52,6 +55,22 @@ def _run(sess, steps: int) -> dict:
     return sess.run(steps, log=lambda s: None)
 
 
+def stacked_layers(cfg) -> int:
+    """Layers in the ``blocks/`` stack: a model whose first layers are
+    unstacked (``pre_blocks``) stacks fewer than ``n_layers``."""
+    from repro.models.transformer import layer_plan
+    return layer_plan(cfg)[1]
+
+
+def counts(job):
+    """``train_flops`` and ``flash_train_work``: the configuration's
+    reference module's own where it defines them (a block the dense count
+    does not describe), else ``bench/flops``'s."""
+    ref = spec.reference(job.conf)
+    return (getattr(ref, "train_flops", flops.train_flops),
+            getattr(ref, "flash_train_work", flops.flash_train_work))
+
+
 def layer_norms(tree, n_layers: int, scale: float = 1.0) -> dict:
     """Per-layer norms of a program tree, layers in canonical order."""
     flat = jax.tree_util.tree_flatten_with_path(tree)[0]
@@ -84,6 +103,13 @@ def _change_norms(params, seed, n_layers, shardings):
     return out
 
 
+def host_batches(job, n: int) -> list:
+    """The first ``n`` batches of the cell's feed, on the host."""
+    return [traffic.train_batch(job.mix, job.conf["train"]["batch"],
+                                job.model_cfg.vocab_size, job.seed, i)
+            for i in range(n)]
+
+
 def setup(job):
     """The session with the seed's weights, driven through the checked
     steps.  → (session, host batches, the program's readings)."""
@@ -94,10 +120,11 @@ def setup(job):
     cfg, plan = job.model_cfg, job.plan
     train = conf["train"]
     opt = train["optimizer"]
-    batch, n_feed = train["batch"], mix["distinct_batches"]
-    if plan.world != 1 or job.cell["chips"] != 1:
-        raise ValueError("training cells run on one chip: a plan over "
-                         "several has no cell yet")
+    n_feed = mix["distinct_batches"]
+    chips = job.cell["chips"]
+    if plan.world != chips:
+        raise ValueError(f"the plan spans {plan.world} chip(s), the cell "
+                         f"{chips}")
     tcfg = stepfn.TrainConfig(
         peak_lr=opt["peak_lr"], warmup=opt["warmup"],
         total_steps=opt["total_steps"],
@@ -107,33 +134,42 @@ def setup(job):
     if opt["warmup"] <= CHECK_STEPS:
         raise ValueError("the reference follows the warm-up phase only")
 
-    host = [traffic.train_batch(mix, batch, cfg.vocab_size, seed, i)
-            for i in range(n_feed)]
-    feed = [jax.device_put(b, job.devices[0]) for b in host]
-    sess = _session_class()(cfg, plan=plan, train_cfg=tcfg, feed=feed)
+    host = host_batches(job, n_feed)
+    mesh = None
+    if chips > 1:
+        from repro.launch.mesh import make_plan_mesh
+        mesh = make_plan_mesh(plan, job.devices[:chips])
+        feed = [jax.device_put(b, stepfn.batch_shardings(b, mesh))
+                for b in host]
+    else:
+        feed = [jax.device_put(b, job.devices[0]) for b in host]
+    sess = _session_class()(cfg, plan=plan, train_cfg=tcfg, mesh=mesh,
+                            feed=feed)
+    n_layers = stacked_layers(cfg)
     params = sess.state["params"]
     shardings = jax.tree_util.tree_map(lambda x: x.sharding, params)
     abstract = jax.tree_util.tree_map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), params)
     harness.free(params)
-    sess.state["params"] = weights.make(abstract, seed, cfg.n_layers,
+    sess.state["params"] = weights.make(abstract, seed, n_layers,
                                         np.float32, shardings)
 
     # --- the checked steps, through the window's call and feed ----------
     out = _run(sess, 1)
     losses = [h["loss"] for h in out["history"]]
-    grad = layer_norms(sess.state["opt"]["m"], cfg.n_layers,
+    grad = layer_norms(sess.state["opt"]["m"], n_layers,
                        1.0 / (1.0 - opt["b1"]))
     out = _run(sess, CHECK_STEPS - 1)
     losses += [h["loss"] for h in out["history"]]
-    delta = _change_norms(sess.state["params"], seed, cfg.n_layers, shardings)
+    delta = _change_norms(sess.state["params"], seed, n_layers, shardings)
     return sess, host, {"loss": losses, "grad": grad, "delta": delta}
 
 
 def reference(job, host, precision="f32") -> dict:
     return spec.reference(job.conf).train_readings(
         job.conf["model"], job.conf["train"]["optimizer"], host[:CHECK_STEPS],
-        job.seed, precision, eps=job.conf["norm_eps"])
+        job.seed, precision, eps=job.conf["norm_eps"],
+        devices=job.devices[:job.cell["chips"]])
 
 
 def run(job) -> dict:
@@ -154,7 +190,7 @@ def run(job) -> dict:
         jax.block_until_ready(sess.state)
     window_s = win.seconds
     skipped = int(out["skipped_steps"])
-    peak = harness.peak_bytes(job.devices[:1])
+    peak = harness.peak_bytes(job.devices[:job.cell["chips"]])
     harness.free(sess.state)
     sess = None
 
@@ -162,16 +198,16 @@ def run(job) -> dict:
 
     tokens = steps * batch * mix["seq_len"]
     pairs = flops.causal_pairs(flops.segment_lengths(host[0]["segment_ids"]))
-    f_ops, f_bytes = flops.flash_train_work(
-        cfg, batch, mix["seq_len"], pairs)
+    train_flops, flash_train_work = counts(job)
+    f_ops, f_bytes = flash_train_work(cfg, batch, mix["seq_len"], pairs)
     return {
         "numbers": numbers,
         "attempted": steps, "failed": skipped,
         "e2e": {"train_tokens_per_s": tokens / window_s},
         "memory_peak_bytes": peak,
         "ctx": {"driver": "train", "window_s": window_s, "steps": steps,
-                "tokens": tokens, "chips": 1,
-                "model_flops": steps * flops.train_flops(
+                "tokens": tokens, "chips": job.cell["chips"],
+                "model_flops": steps * train_flops(
                     cfg, batch * mix["seq_len"], pairs),
                 "flash_ops": steps * f_ops, "flash_bytes": steps * f_bytes},
     }

@@ -3,7 +3,9 @@ attention with rotary positions and a (gated) MLP, a final norm and an
 unembedding by the embedding table.  Written from the configuration file
 alone, in float32 at the highest matmul precision, one row (or sequence) at
 a time and one layer at a time, so that it fits beside nothing.  It imports
-nothing of the program.
+nothing of the program.  Over several chips its weights, gradients and
+Adam's moments are split across them (``spread``), and each product runs
+split as GSPMD places it; on one chip they stay whole.
 
 ``precision="fp8"`` is the control: the same computation with both operands
 of every matrix product rounded to float8 e4m3 with one scale per tensor,
@@ -13,6 +15,8 @@ the step below the bf16 the configurations compute in.
 from __future__ import annotations
 
 import math
+import sys
+import time
 from typing import Dict, List
 
 import jax
@@ -42,6 +46,29 @@ def param_shapes(m: dict) -> Dict[str, tuple]:
     if not m.get("tie_embeddings", True):
         out["lm_head"] = (V, d)
     return out
+
+
+def spread(shapes: Dict[str, tuple], devices):
+    """``{name: sharding}`` of the reference's weights on ``devices``, or
+    None on one device.  Each matrix is split over a 1-D mesh of the devices
+    along the axis a Megatron split cuts: the input of ``wo`` and ``w_out``,
+    the output of every other matrix (the embedding's d_model).  The layer
+    axis stays whole, as the layer scan slices it; vectors, and an axis the
+    devices do not divide, stay whole too."""
+    if len(devices) == 1:
+        return None
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    mesh = Mesh(np.asarray(devices), ("d",))
+
+    def one(name, shape):
+        inner = shape[1:] if name.startswith("blocks/") else shape
+        axis = -2 if name.rsplit("/", 1)[-1] in ("wo", "w_out") else -1
+        spec = [None] * len(shape)
+        if len(inner) >= 2 and shape[axis] % len(devices) == 0:
+            spec[axis] = "d"
+        return NamedSharding(mesh, P(*spec))
+
+    return {n: one(n, s) for n, s in shapes.items()}
 
 
 def _q8(x):
@@ -162,23 +189,38 @@ def layer_norms(tree) -> Dict[str, np.ndarray]:
 
 
 def train_readings(m: dict, opt: dict, batches: List[dict], seed: int,
-                   precision: str = "f32", eps: float = 1e-6) -> dict:
+                   precision: str = "f32", eps: float = 1e-6,
+                   devices=None) -> dict:
     """Three AdamW steps from the seed's weights on ``batches``: each step's
     loss (mean over the loss mask), the per-layer norms of the first step's
-    clipped gradient, and of the weights' change after the last step."""
+    clipped gradient, and of the weights' change after the last step.
+    Weights, gradients and moments are split over ``devices`` (default:
+    the first device alone)."""
     import functools
     from bench import weights as wmod
     shapes = param_shapes(m)
-    w = nested(wmod.make_canonical(shapes, seed, jnp.float32))
+    layout = spread(shapes, devices or jax.devices()[:1])
+    w = nested(wmod.make_canonical(shapes, seed, jnp.float32, layout))
+    out_sh = None
+    if layout is not None:
+        whole = jax.sharding.NamedSharding(
+            next(iter(layout.values())).mesh, jax.sharding.PartitionSpec())
+        out_sh = (whole, nested(layout))     # gradients split as the weights
     row_grad = jax.jit(jax.value_and_grad(functools.partial(
-        _row_loss, m, precision, eps)))
+        _row_loss, m, precision, eps)), out_shardings=out_sh)
     add = jax.jit(lambda a, b: jax.tree_util.tree_map(jnp.add, a, b),
                   donate_argnums=0)
     decay = _decay_mask(w, opt)
     b1, b2 = opt["b1"], opt["b2"]
-    # Adam's moments wait on the host while the gradients are on the chip
-    mom = jax.tree_util.tree_map(lambda x: np.zeros(x.shape, np.float32), w)
-    vel = jax.tree_util.tree_map(lambda x: np.zeros(x.shape, np.float32), w)
+    # Adam's moments wait on the host while the gradients are on one chip;
+    # over several they stay on the devices, split as the weights are
+    if layout is None:
+        zeros, home = (lambda x: np.zeros(x.shape, np.float32)), np.asarray
+    else:
+        zeros = lambda x: jnp.zeros(x.shape, jnp.float32, device=x.sharding)
+        home = lambda x: x
+    mom = jax.tree_util.tree_map(zeros, w)
+    vel = jax.tree_util.tree_map(zeros, w)
 
     @functools.partial(jax.jit, static_argnums=5, donate_argnums=(0, 1, 2))
     def update(p, a, b, g, lr_t, dec):
@@ -191,15 +233,18 @@ def train_readings(m: dict, opt: dict, batches: List[dict], seed: int,
         return p - lr * u, a, b
 
     losses, grad_norms = [], None
+    t0, t_rows = time.perf_counter(), 0.0
     with jax.default_matmul_precision("highest"):
         for step, b in enumerate(batches):
             tot, g = 0.0, None
+            t_r = time.perf_counter()
             for r in range(b["tokens"].shape[0]):
                 lr_, gr = row_grad(w, *(jnp.asarray(b[k][r]) for k in
                                         ("tokens", "labels", "segment_ids", "loss_mask")))
                 tot += float(lr_)
                 g = gr if g is None else add(g, gr)
                 del gr
+            t_rows += time.perf_counter() - t_r
             n = float(np.sum(b["loss_mask"]))
             losses.append(tot / n)
             leaves = jax.tree_util.tree_leaves(g)
@@ -216,19 +261,23 @@ def train_readings(m: dict, opt: dict, batches: List[dict], seed: int,
                                          jax.tree_util.tree_leaves(vel),
                                          jax.tree_util.tree_leaves(g),
                                          jax.tree_util.tree_leaves(decay)):
-                p, a, bb = update(p, jnp.asarray(a), jnp.asarray(bb), gg, lr_t,
+                p, a, bb = update(p, jax.device_put(a, p.sharding),
+                                  jax.device_put(bb, p.sharding), gg, lr_t,
                                   bool(dec))
                 out_w.append(p)
-                out_m.append(np.asarray(a))
-                out_v.append(np.asarray(bb))
+                out_m.append(home(a))
+                out_v.append(home(bb))
                 del a, bb
             w = jax.tree_util.tree_unflatten(tree, out_w)
             mom = jax.tree_util.tree_unflatten(tree, out_m)
             vel = jax.tree_util.tree_unflatten(tree, out_v)
             del g, leaves
     del mom, vel
-    w0 = nested(wmod.make_canonical(shapes, seed, jnp.float32))
+    w0 = nested(wmod.make_canonical(shapes, seed, jnp.float32, layout))
     delta = layer_norms(jax.tree_util.tree_map(jnp.subtract, w, w0))
+    print(f"reference ({precision}): {len(batches)} steps in "
+          f"{time.perf_counter() - t0:.1f} s, of them row gradients "
+          f"{t_rows:.1f} s", file=sys.stderr)
     return {"loss": losses, "grad": grad_norms, "delta": delta}
 
 

@@ -62,8 +62,10 @@ class Job:
         win = Window()
         tracer = None
         if self.trace:
-            tracer = harness.Tracer(self.workload,
-                                    self.mix.get("trace_seconds"))
+            # the traced stretch: the mix's cap, else the configuration's
+            # (four chips' events of a whole window take minutes to collect)
+            tracer = harness.Tracer(self.workload, self.mix.get(
+                "trace_seconds", self.conf.get("trace_seconds")))
         self.counter.count = 0
         self.counter.armed = True
         try:
@@ -112,7 +114,10 @@ def execute(job) -> dict:
         for m in wanted:
             metrics[m["name"]] = {"value": vals[m["name"]], "unit": m["unit"]}
     else:
+        t0 = time.perf_counter()
         red = job.tracer.reduce()
+        print(f"trace reduced in {time.perf_counter() - t0:.1f} s",
+              file=sys.stderr)
         ctx = dict(res["ctx"], trace=red, peak=peaks.peak(dev.device_kind),
                    cfg=job.model_cfg)
         for m in wanted:

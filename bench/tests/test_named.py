@@ -2,7 +2,12 @@
 the numbers ``bench/breakdown.py`` prints: interval arithmetic and
 readers by hand, and two recorded TPU traces (``data/v5e_flash.xplane.pb``,
 no program names; ``data/v5e_train_toy.xplane.pb``, three steps of a toy
-train session, ``tests/record_train_trace.py``)."""
+train session, ``tests/record_train_trace.py``).
+
+This is the file under ``bench/`` that tier 1 collects, so it also holds
+the trace reduction's collective arithmetic (``trace.collective_times``)
+by hand, and the train driver's layer count for a model whose first layer
+is not stacked."""
 
 from pathlib import Path
 
@@ -144,3 +149,115 @@ def test_recorded_train_trace():
     assert out["step_forward_ms"] + out["step_backward_ms"] + \
         out["step_optimizer_ms"] + out["step_other_ms"] == \
         pytest.approx(out["busy_ms_per_step"])
+
+
+@pytest.mark.parametrize("events,want", [
+    # a collective wholly under a fusion, and one beside it
+    ([(0, 10, "fusion.1"), (2, 6, "all-reduce.3")], (4, 0)),
+    # half covered: the uncovered half is exposed
+    ([(0, 4, "fusion.1"), (2, 6, "collective-permute-done.2")], (4, 2)),
+    # a loop encloses its body: its span is no work of its own
+    ([(0, 20, "while.4"), (1, 5, "fusion.2"), (5, 9, "all-gather-start"),
+      (9, 12, "all-gather-done")], (7, 7)),
+    ([(0, 3, "fusion.1"), (3, 5, "copy.2")], (0, 0)),
+])
+def test_collective_times_by_hand(events, want):
+    assert trace.collective_times(events) == want
+
+
+def test_collective_names():
+    for name in ("all-reduce.12", "all-reduce-start.3", "all-reduce-done",
+                 "collective-permute-start.1", "reduce-scatter.2",
+                 "all-to-all.7", "all-gather-done.5"):
+        assert trace.COLLECTIVE.match(name), name
+    for name in ("fusion.12", "all-reduce-scatter-fusion", "copy.4",
+                 "flash_attention_fwd.2"):
+        assert not trace.COLLECTIVE.match(name), name
+
+
+def test_collective_exposed_share_is_the_mean_over_chips():
+    from bench import spec
+    red = trace.Reduced(window_s=10.0, devices=2, busy_s=[9.0, 9.0],
+                        op_self_s={}, idle_gaps=[], collective_s=[3.0, 1.0],
+                        collective_exposed_s=[2.0, 0.0])
+    read = spec.metric_reader("collective_exposed_share")
+    assert read({"driver": "train", "trace": red}) == pytest.approx(10.0)
+    none = trace.Reduced(10.0, 1, [9.0], {}, [], [0.0], [0.0])
+    assert read({"driver": "train", "trace": none}) is None
+
+
+def test_flash_roofline_of_a_trace_cut_short():
+    """A trace of the window's first half holds half its work: half the
+    kernel time reads the same share as the whole window's."""
+    from bench import peaks, spec
+    read = spec.metric_reader("flash_attention_roofline")
+    ctx = {"driver": "train", "window_s": 30.0, "chips": 4,
+           "flash_ops": 4 * 197e12, "flash_bytes": 0.0,
+           "peak": peaks.peak("TPU v5 lite")}
+
+    def red(window_s, kernel_s):
+        return trace.Reduced(window_s, 4, [window_s] * 4,
+                             {"flash_attention_fwd.1": 4 * kernel_s}, [],
+                             [0.0] * 4, [0.0] * 4)
+    whole = read(dict(ctx, trace=red(30.0001, 20.0)))
+    assert whole == pytest.approx(5.0)
+    assert read(dict(ctx, trace=red(15.0, 10.0))) == pytest.approx(whole)
+
+
+def test_recorded_traces_hold_no_collective():
+    for f in ("v5e_flash.xplane.pb", "v5e_train_toy.xplane.pb"):
+        red = trace.reduce(DATA / f)
+        assert red.collective_s == [0.0] == red.collective_exposed_s
+
+
+def test_dense_prefix_tree_goes_through_weights_and_norms():
+    """DeepSeekMoE's first layer is dense and unstacked (``pre_blocks``):
+    its ``blocks/`` stack holds ``n_layers - 1`` layers, which the driver
+    hands to ``weights.make`` and ``layer_norms``."""
+    import jax
+    import numpy as np
+    from bench import weights
+    from bench.drivers import train
+    from repro.configs import get_config
+    from repro.models import api as model_api
+    cfg = get_config("deepseek_moe_16b").reduced()
+    assert cfg.first_k_dense == 1
+    n = train.stacked_layers(cfg)
+    assert n == cfg.n_layers - 1
+    abstract = jax.eval_shape(lambda: model_api.init_params(
+        cfg, jax.random.PRNGKey(0)))
+    params = weights.make(abstract, 5, n, np.float32)
+    norms = train.layer_norms(params, n)
+    assert any(k.startswith("pre_blocks/") for k in norms)
+    assert all(v.shape == (n,) for k, v in norms.items()
+               if k.startswith("blocks/"))
+    with pytest.raises(ValueError):
+        weights.canonical_shape("blocks/attn/wq",
+                                abstract["blocks"]["attn"]["wq"].shape,
+                                cfg.n_layers)
+
+
+def test_granite_tiny_readings_unchanged():
+    """The one-chip train cell's tiny job (``tiny.py``): the program's and
+    the reference's readings as the driver gave them before it counted
+    stacked layers and could spread the reference over chips."""
+    from bench import traffic
+    from bench.drivers import train
+    from bench.tests import tiny
+    job = tiny.job(tiny.TRAIN)
+    sess, host, prog = train.setup(job)
+    ref = train.reference(job, host[:train.CHECK_STEPS])
+    assert prog["loss"] == pytest.approx(
+        [6.33021354675293, 6.351413249969482, 6.325653553009033], rel=1e-5)
+    assert ref["loss"] == pytest.approx(
+        [6.330146603467988, 6.351176440231199, 6.325819806354802], rel=1e-6)
+    assert list(prog["grad"]["blocks/mlp/w_out"]) == pytest.approx(
+        [0.2054683417081833, 0.11930213868618011], rel=1e-4)
+    assert list(ref["grad"]["blocks/mlp/w_out"]) == pytest.approx(
+        [0.20545929670333862, 0.1192716434597969], rel=1e-5)
+    assert list(prog["delta"]["embed"]) == pytest.approx(
+        [0.002748674713075161], rel=1e-4)
+    assert list(ref["delta"]["embed"]) == pytest.approx(
+        [0.0027480819262564182], rel=1e-5)
+    assert traffic.train_batch(job.mix, 2, job.model_cfg.vocab_size,
+                               job.seed, 0)["tokens"].shape == (2, 128)

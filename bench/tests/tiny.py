@@ -10,6 +10,7 @@ from bench import harness, spec
 from bench.run import Job
 
 TRAIN = "granite_3_2b_d8.train.pack2k"
+TRAIN_MESH = "granite_3_2b.train.pp2tp2"
 SERVE = "granite_3_2b.serve.decode"
 WIDTHS = dict(d_model=512, n_heads=8, n_kv_heads=2, head_dim=64, d_ff=1024,
               vocab_size=512)
@@ -25,7 +26,7 @@ def job(workload: str, seed: int = 2 ** 33 + 11, *, real_traffic: bool = False,
     if real_traffic:
         pass                        # the mix as the cell sends it
     elif mix["driver"] == "train":
-        conf["train"]["batch"] = 2
+        conf["train"]["batch"] = conf["train"]["plan"].get("gas", 2)
         mix.update(seq_len=128, doc_mean=32, distinct_batches=3)
     else:
         conf["serve"].update(n_slots=4, page_size=16)

@@ -9,6 +9,11 @@ is a union and an operation's own time excludes what it encloses.
 
 The window is the host event ``bench_window`` the harness opens around the
 traced stretch; everything is clipped to it.
+
+A collective (``COLLECTIVE``) is exposed where it runs and no other
+operation does.  The other operations counted are those that enclose no
+other operation: a loop spans the collectives of its body and is no work
+of its own.
 """
 
 from __future__ import annotations
@@ -24,6 +29,9 @@ WINDOW = "bench_window"
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OPS_LINE = "XLA Ops"
 HOST_PLANE = "/host:CPU"
+COLLECTIVE = re.compile(r"^(all-reduce|all-gather|reduce-scatter|"
+                        r"collective-permute|all-to-all)(-start|-done)?"
+                        r"(\.\d+)?$")
 
 
 def op_name(event_name: str) -> str:
@@ -59,6 +67,31 @@ def self_times(events: List[Tuple[float, float, str]]) -> Dict[str, float]:
     return dict(out)
 
 
+def leaves(events: List[Tuple[float, float, str]]):
+    """The events that enclose no other event of the list."""
+    out, stack = [], []             # stack: [event, encloses another]
+    for ev in sorted(events, key=lambda t: (t[0], -(t[1] - t[0]))):
+        while stack and ev[0] >= stack[-1][0][1]:
+            top = stack.pop()
+            if not top[1]:
+                out.append(top[0])
+        if stack and ev[1] <= stack[-1][0][1]:
+            stack[-1][1] = True
+        stack.append([ev, False])
+    out.extend(top[0] for top in stack if not top[1])
+    return out
+
+
+def collective_times(events: List[Tuple[float, float, str]]):
+    """(time a collective runs, time one runs and no other operation does)
+    on one chip's line, in the events' unit."""
+    coll = [(s, e) for s, e, n in events if COLLECTIVE.match(n)]
+    other = [(s, e) for s, e, _ in
+             leaves([ev for ev in events if not COLLECTIVE.match(ev[2])])]
+    return (union_length(coll),
+            union_length(coll + other) - union_length(other))
+
+
 @dataclasses.dataclass
 class Reduced:
     window_s: float
@@ -66,6 +99,8 @@ class Reduced:
     busy_s: List[float]                  # per chip
     op_self_s: Dict[str, float]          # op name → own seconds, summed over chips
     idle_gaps: List[Tuple[str, float]]   # longest gaps on chip 0, by host span
+    collective_s: List[float]            # per chip: a collective runs
+    collective_exposed_s: List[float]    # per chip: one runs, nothing else
 
     @property
     def mean_busy_s(self) -> float:
@@ -120,7 +155,7 @@ def reduce(path, window: str = WINDOW, n_gaps: int = 10) -> Reduced:
                   for pl in planes if DEVICE_PLANE.match(pl.name))
     if not devs:
         raise ValueError(f"trace {path} has no TPU device plane")
-    busy = []
+    busy, coll, exposed = [], [], []
     op_self: Dict[str, float] = defaultdict(float)
     gaps: List[Tuple[str, float]] = []
     for idx, (_, pl) in enumerate(devs):
@@ -129,12 +164,16 @@ def reduce(path, window: str = WINDOW, n_gaps: int = 10) -> Reduced:
                     _events(lines[OPS_LINE])]) if OPS_LINE in lines else []
         iv = [(s, e) for s, e, _ in ops]
         busy.append(union_length(iv) / 1e9)
+        c, x = collective_times(ops)
+        coll.append(c / 1e9)
+        exposed.append(x / 1e9)
         for n, t in self_times(ops).items():
             op_self[n] += t / 1e9
         if idx == 0:
             gaps = _idle_gaps(sorted(iv), w0, w1, host_spans, n_gaps)
     return Reduced(window_s=(w1 - w0) / 1e9, devices=len(devs), busy_s=busy,
-                   op_self_s=dict(op_self), idle_gaps=gaps)
+                   op_self_s=dict(op_self), idle_gaps=gaps,
+                   collective_s=coll, collective_exposed_s=exposed)
 
 
 def _idle_gaps(busy: List[Tuple[float, float]], w0: float, w1: float,

@@ -79,8 +79,12 @@ def make(abstract_tree, seed: int, n_layers: int, dtype, shardings=None):
     return jax.jit(build, out_shardings=shardings)(seed_key(seed))
 
 
-def make_canonical(names_shapes, seed: int, dtype):
+def make_canonical(names_shapes, seed: int, dtype, shardings=None):
     """The same draws for the reference: ``{name: canonical shape}`` →
-    ``{name: array}``."""
+    ``{name: array}``, each leaf moved to ``shardings[name]`` (where given)
+    as soon as it is drawn."""
     key = seed_key(seed)
-    return {n: draw(key, n, s, dtype) for n, s in names_shapes.items()}
+    place = ((lambda n, x: x) if shardings is None
+             else (lambda n, x: jax.device_put(x, shardings[n])))
+    return {n: place(n, draw(key, n, s, dtype))
+            for n, s in names_shapes.items()}
