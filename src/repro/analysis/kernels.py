@@ -321,9 +321,10 @@ def default_kernel_captures(cfg=None) -> List[KernelCapture]:
 
     records: List[KernelCapture] = []
     with capture_pallas_calls(records):
-        o, lse = fa._forward(q, k, v, None, True, None, bq, bk, None, False)
+        o, lse = fa._forward(q, k, v, None, True, None, bq, bk, None,
+                             D ** -0.5, False)
         fa._backward(q, k, v, None, o, lse, jnp.ones_like(o),
-                     True, None, bq, bk, None, False)
+                     True, None, bq, bk, None, D ** -0.5, False)
 
         Sc, bkd = 512, 128
         kc = jax.random.normal(key, (B, Sc, Hkv, D), jnp.float32)

@@ -141,7 +141,7 @@ def pipeline_loss(cfg: ModelConfig, params: Params, batch: Dict[str, jax.Array],
             w = cfg.swa_window if win_stage is None else layer_in[1]
             x, a = T.block_apply(cfg, bp, x, positions, kind=scanned_kind, window=w,
                                  segment_ids=seg)
-            return (x, aux + a), None
+            return (x, aux + a["aux"]), None
         body = one_layer
         if plan.remat_policy != "none":
             pol = (jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims
@@ -260,5 +260,5 @@ def pipeline_loss(cfg: ModelConfig, params: Params, batch: Dict[str, jax.Array],
 
     xent = loss_sum / jnp.maximum(denom, 1.0)
     aux = aux_sum / gas
-    loss = xent + T.AUX_LOSS_COEF * aux
+    loss = xent + cfg.moe_aux_coef * aux
     return loss, {"xent": xent, "aux": aux}

@@ -6,7 +6,9 @@ repeat).
 TPU adaptation (DESIGN.md §2): the GPU flash kernel tunes for SRAM/warps; here
 the block shape is chosen for VMEM and the MXU — q/k blocks are multiples of
 128 in the sequence dims, the head dim is padded to a lane multiple so
-D = 64/96/120/128 all work.  Grid order (B, Hq/gf, nQ, nK) with the K
+D = 64/96/120/128 all work.  V's head dim may differ from Q/K's (latent
+attention: 192 and 128), each padded to its own lane multiple, and the
+softmax scale is an argument (default ``D ** -0.5``).  Grid order (B, Hq/gf, nQ, nK) with the K
 dimension innermost and "arbitrary" semantics so the f32 accumulators live in
 VMEM scratch across the K sweep.
 
@@ -280,9 +282,11 @@ def _tile_table(segment_ids, B: int, nq: int, nk: int, *, bq: int, bk: int,
     return _sweep_range(live, sweep_axis) + (bounds or ())
 
 
-def _forward(q, k, v, segment_ids, causal, window, bq, bk, gf, interpret):
-    """Shared fwd implementation → (out (B,Sq,Hq,D), lse (B,Hq,Sq,1) f32)."""
+def _forward(q, k, v, segment_ids, causal, window, bq, bk, gf, scale,
+             interpret):
+    """Shared fwd implementation → (out (B,Sq,Hq,Dv), lse (B,Hq,Sq,1) f32)."""
     B, Sq, Hq, D = q.shape
+    Dv = v.shape[-1]
     B, Hkv, g, gf, bq, bk, nq, nk = _geometry(q, k, segment_ids, bq, bk, gf)
     ng = g // gf
     # head-major layout so a block is (1, gf, seq_block, D); zero-padded head
@@ -290,14 +294,14 @@ def _forward(q, k, v, segment_ids, causal, window, bq, bk, gf, interpret):
     qt = _pad_head_dim(q.transpose(0, 2, 1, 3))          # (B, Hq, Sq, Dp)
     kt = _pad_head_dim(k.transpose(0, 2, 1, 3))          # (B, Hkv, Sk, Dp)
     vt = _pad_head_dim(v.transpose(0, 2, 1, 3))
-    Dp = qt.shape[-1]
+    Dp, Dvp = qt.shape[-1], vt.shape[-1]
     has_seg = segment_ids is not None
     table = _tile_table(segment_ids, B, nq, nk, bq=bq, bk=bk, causal=causal,
                         window=window, sweep_axis=2)
 
     kernel = functools.partial(
         _fwd_kernel, bq=bq, bk=bk, gf=gf, nq=nq, nk=nk, causal=causal,
-        window=window, scale=D ** -0.5, has_seg=has_seg)
+        window=window, scale=scale, has_seg=has_seg)
 
     def q_map(b, h, iq, ik, *t):
         return (b, h, iq, 0)
@@ -308,7 +312,7 @@ def _forward(q, k, v, segment_ids, causal, window, bq, bk, gf, interpret):
     in_specs = [
         pl.BlockSpec((1, gf, bq, Dp), q_map),
         pl.BlockSpec((1, 1, bk, Dp), kv_map),
-        pl.BlockSpec((1, 1, bk, Dp), kv_map),
+        pl.BlockSpec((1, 1, bk, Dvp), kv_map),
     ]
     inputs = [qt, kt, vt]
     if has_seg:
@@ -326,20 +330,20 @@ def _forward(q, k, v, segment_ids, causal, window, bq, bk, gf, interpret):
             grid=(B, Hkv * ng, nq, nk),
             in_specs=in_specs,
             out_specs=[
-                pl.BlockSpec((1, gf, bq, Dp), q_map),
+                pl.BlockSpec((1, gf, bq, Dvp), q_map),
                 pl.BlockSpec((1, gf, bq, 1), q_map),
             ],
-            scratch_shapes=_scratch(gf, bq, Dp),
+            scratch_shapes=_scratch(gf, bq, Dvp),
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((B, Hq, Sq, Dp), q.dtype),
+            jax.ShapeDtypeStruct((B, Hq, Sq, Dvp), q.dtype),
             jax.ShapeDtypeStruct((B, Hq, Sq, 1), jnp.float32),
         ],
         compiler_params=_compiler_params(),
         interpret=interpret,
         name="flash_attention_fwd",
     )(*table, *inputs)
-    return out[..., :D].transpose(0, 2, 1, 3), lse
+    return out[..., :Dv].transpose(0, 2, 1, 3), lse
 
 
 # ---------------------------------------------------------------------------
@@ -442,12 +446,11 @@ def _dkv_kernel(*refs, bq: int, bk: int, gf: int, ng: int, nq: int, nk: int,
 
 
 def _backward(q, k, v, segment_ids, o, lse, do, causal, window, bq, bk, gf,
-              interpret):
+              scale, interpret):
     B, Sq, Hq, D = q.shape
-    Sk = k.shape[1]
+    Sk, Dv = k.shape[1], v.shape[-1]
     B, Hkv, g, gf, bq, bk, nq, nk = _geometry(q, k, segment_ids, bq, bk, gf)
     ng = g // gf
-    scale = D ** -0.5
     has_seg = segment_ids is not None
 
     qt = _pad_head_dim(q.transpose(0, 2, 1, 3))          # (B, Hq, Sq, Dp)
@@ -455,7 +458,7 @@ def _backward(q, k, v, segment_ids, o, lse, do, causal, window, bq, bk, gf,
     vt = _pad_head_dim(v.transpose(0, 2, 1, 3))
     ot = _pad_head_dim(o.transpose(0, 2, 1, 3))
     dot = _pad_head_dim(do.transpose(0, 2, 1, 3))
-    Dp = qt.shape[-1]
+    Dp, Dvp = qt.shape[-1], vt.shape[-1]
     mask_kw = dict(bq=bq, bk=bk, causal=causal, window=window)
 
     def row_map(b, h, iq):
@@ -465,8 +468,8 @@ def _backward(q, k, v, segment_ids, o, lse, do, causal, window, bq, bk, gf,
         _delta_kernel,
         grid=(B, Hkv * ng, nq),
         in_specs=[
-            pl.BlockSpec((1, gf, bq, Dp), row_map),
-            pl.BlockSpec((1, gf, bq, Dp), row_map),
+            pl.BlockSpec((1, gf, bq, Dvp), row_map),
+            pl.BlockSpec((1, gf, bq, Dvp), row_map),
         ],
         out_specs=pl.BlockSpec((1, gf, bq, 1), row_map),
         out_shape=jax.ShapeDtypeStruct((B, Hq, Sq, 1), jnp.float32),
@@ -490,8 +493,8 @@ def _backward(q, k, v, segment_ids, o, lse, do, causal, window, bq, bk, gf,
     dq_in_specs = [
         pl.BlockSpec((1, gf, bq, Dp), q_map),
         pl.BlockSpec((1, 1, bk, Dp), kv_map),
-        pl.BlockSpec((1, 1, bk, Dp), kv_map),
-        pl.BlockSpec((1, gf, bq, Dp), q_map),
+        pl.BlockSpec((1, 1, bk, Dvp), kv_map),
+        pl.BlockSpec((1, gf, bq, Dvp), q_map),
         pl.BlockSpec((1, gf, bq, 1), q_map),
         pl.BlockSpec((1, gf, bq, 1), q_map),
     ]
@@ -532,9 +535,9 @@ def _backward(q, k, v, segment_ids, o, lse, do, causal, window, bq, bk, gf,
 
     dkv_in_specs = [
         pl.BlockSpec((1, 1, bk, Dp), k_map),
-        pl.BlockSpec((1, 1, bk, Dp), k_map),
+        pl.BlockSpec((1, 1, bk, Dvp), k_map),
         pl.BlockSpec((1, gf, bq, Dp), qrow_map),
-        pl.BlockSpec((1, gf, bq, Dp), qrow_map),
+        pl.BlockSpec((1, gf, bq, Dvp), qrow_map),
         pl.BlockSpec((1, gf, bq, 1), qrow_map),
         pl.BlockSpec((1, gf, bq, 1), qrow_map),
     ]
@@ -556,46 +559,48 @@ def _backward(q, k, v, segment_ids, o, lse, do, causal, window, bq, bk, gf,
             grid=(B, Hkv, nk, ng, nq),
             in_specs=dkv_in_specs,
             out_specs=[pl.BlockSpec((1, 1, bk, Dp), k_map),
-                       pl.BlockSpec((1, 1, bk, Dp), k_map)],
+                       pl.BlockSpec((1, 1, bk, Dvp), k_map)],
             scratch_shapes=[pltpu.VMEM((bk, Dp), jnp.float32),
-                            pltpu.VMEM((bk, Dp), jnp.float32)],
+                            pltpu.VMEM((bk, Dvp), jnp.float32)],
         ),
         out_shape=[jax.ShapeDtypeStruct((B, Hkv, Sk, Dp), k.dtype),
-                   jax.ShapeDtypeStruct((B, Hkv, Sk, Dp), v.dtype)],
+                   jax.ShapeDtypeStruct((B, Hkv, Sk, Dvp), v.dtype)],
         compiler_params=_compiler_params(
             ("parallel", "parallel", "parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
         name="flash_attention_dkv",
     )(*table, *dkv_inputs)
 
-    def unpad(x):
-        return x[..., :D].transpose(0, 2, 1, 3)
+    def unpad(x, n):
+        return x[..., :n].transpose(0, 2, 1, 3)
 
-    return unpad(dq), unpad(dk), unpad(dv)
+    return unpad(dq, D), unpad(dk, D), unpad(dv, Dv)
 
 
 # ---------------------------------------------------------------------------
 # custom_vjp plumbing + public entry point
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
-def _flash(q, k, v, segment_ids, causal, window, bq, bk, gf, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10))
+def _flash(q, k, v, segment_ids, causal, window, bq, bk, gf, scale,
+           interpret):
     out, _ = _forward(q, k, v, segment_ids, causal, window, bq, bk, gf,
-                      interpret)
+                      scale, interpret)
     return out
 
 
-def _flash_fwd(q, k, v, segment_ids, causal, window, bq, bk, gf, interpret):
+def _flash_fwd(q, k, v, segment_ids, causal, window, bq, bk, gf, scale,
+               interpret):
     out, lse = _forward(q, k, v, segment_ids, causal, window, bq, bk, gf,
-                        interpret)
+                        scale, interpret)
     # residuals are O(B·S·(3D + 1)) — the S×S score matrix is never saved
     return out, (q, k, v, segment_ids, out, lse)
 
 
-def _flash_bwd(causal, window, bq, bk, gf, interpret, res, do):
+def _flash_bwd(causal, window, bq, bk, gf, scale, interpret, res, do):
     q, k, v, segment_ids, out, lse = res
     dq, dk, dv = _backward(q, k, v, segment_ids, out, lse, do, causal, window,
-                           bq, bk, gf, interpret)
+                           bq, bk, gf, scale, interpret)
     return dq, dk, dv, None          # segment ids carry no tangent
 
 
@@ -603,13 +608,19 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "bq", "bk",
-                                             "gf", "interpret"))
+                                             "gf", "scale", "interpret"))
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     segment_ids: Optional[jax.Array] = None,
                     causal: bool = True, window: Optional[int] = None,
                     bq: int = 128, bk: int = 128, gf: Optional[int] = None,
+                    scale: Optional[float] = None,
                     interpret: bool = False) -> jax.Array:
-    """q: (B, Sq, Hq, D); k/v: (B, Sk, Hkv, D) → (B, Sq, Hq, D).
+    """q: (B, Sq, Hq, D); k: (B, Sk, Hkv, D); v: (B, Sk, Hkv, Dv)
+    → (B, Sq, Hq, Dv).
+
+    The softmax scale is ``scale`` (default ``D ** -0.5``); V's head dim
+    ``Dv`` may differ from Q/K's (latent attention: 192 and 128), and each
+    is padded to its own lane multiple.
 
     Differentiable: gradients run through the fused Pallas backward kernels
     (recompute-style — no (B, H, S, S) intermediate), so training can route
@@ -626,7 +637,10 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     """
     if segment_ids is not None:
         segment_ids = segment_ids.astype(jnp.int32)
-    return _flash(q, k, v, segment_ids, causal, window, bq, bk, gf, interpret)
+    if scale is None:
+        scale = q.shape[-1] ** -0.5
+    return _flash(q, k, v, segment_ids, causal, window, bq, bk, gf, scale,
+                  interpret)
 
 
 def _scratch(gf: int, bq: int, D: int):

@@ -40,12 +40,13 @@ def _fit(want: int, S: int) -> int:
 
 
 def _flash_blocks(Sq: int, Sk: int, *, g: int = 1, D: int = 64,
-                  packed: bool = False):
+                  Dv: Optional[int] = None, packed: bool = False):
     """(bq, bk, gf) for the flash kernels from the call's shape: the fold
     takes the largest divisor of the GQA group ``g = Hq/Hkv`` that leaves
     bq ≥ 128, bq fills the fold's MAX_FOLD_ROWS, and bk comes from the
-    table, held to the VMEM budget of the head dim; each cut to what
-    divides the sequence.  A ParallelismConfig / flags override of bq or bk
+    table, held to the VMEM budget of the wider of the Q/K head dim ``D``
+    and the V head dim ``Dv`` (default ``D``); each cut to what divides the
+    sequence.  A ParallelismConfig / flags override of bq or bk
     (autotuning hook) wins over the table, and the fold follows its bq."""
     from repro.kernels.flash_attention import MAX_FOLD_ROWS, group_fold
     obq, obk = flags.flash_block_sizes()
@@ -59,7 +60,7 @@ def _flash_blocks(Sq: int, Sk: int, *, g: int = 1, D: int = 64,
     if obk:
         bk = min(obk, Sk)
     else:
-        Dp = max(128, -(-D // 128) * 128)
+        Dp = max(128, -(-max(D, Dv or D) // 128) * 128)
         budget = _FLASH_TILE_ELEMS * 128 // Dp // (gf * bq)
         bk = _fit(min(_FLASH_BK[packed], max(budget, 128)), Sk)
     return bq, bk, gf
@@ -67,7 +68,7 @@ def _flash_blocks(Sq: int, Sk: int, *, g: int = 1, D: int = 64,
 
 def flash_supported(q, k, *, causal: bool = True,
                     window: Optional[int] = None,
-                    segment_ids=None) -> bool:
+                    segment_ids=None, v=None) -> bool:
     """True iff the tiled flash path covers these shapes — callers fall back
     to the reference/chunked paths otherwise (never a silent wrong answer).
 
@@ -84,9 +85,16 @@ def flash_supported(q, k, *, causal: bool = True,
         return False        # traced per-layer window (Hymba) → reference path
     if (causal or window is not None or segment_ids is not None) and Sq != Sk:
         return False
-    bq, bk, _ = _flash_blocks(Sq, Sk, g=q.shape[2] // k.shape[2],
-                              D=q.shape[3], packed=segment_ids is not None)
+    bq, bk, _ = _flash_blocks_for(q, k, v, segment_ids)
     return Sq % bq == 0 and Sk % bk == 0
+
+
+def _flash_blocks_for(q, k, v, segment_ids):
+    """``_flash_blocks`` for a call's operands (``v`` None: V's head dim is
+    Q/K's)."""
+    return _flash_blocks(q.shape[1], k.shape[1], g=q.shape[2] // k.shape[2],
+                         D=q.shape[3], Dv=None if v is None else v.shape[3],
+                         packed=segment_ids is not None)
 
 
 def _per_shard(fn, q, k, v, segment_ids):
@@ -124,22 +132,22 @@ def _per_shard(fn, q, k, v, segment_ids):
 
 def flash_attention(q, k, v, *, causal: bool = True,
                     window: Optional[int] = None,
-                    segment_ids=None) -> jax.Array:
+                    segment_ids=None, scale: Optional[float] = None) -> jax.Array:
     """Differentiable flash attention (fused fwd+bwd Pallas kernels), with a
-    clean fallback to the jnp oracle for shapes the tiling can't cover."""
+    clean fallback to the jnp oracle for shapes the tiling can't cover.
+    ``scale`` is the softmax scale (default ``D ** -0.5``); V's head dim may
+    differ from Q/K's."""
     from repro.kernels import flash_attention as fa
     if not flash_supported(q, k, causal=causal, window=window,
-                           segment_ids=segment_ids):
+                           segment_ids=segment_ids, v=v):
         return ref.mha_reference(q, k, v, causal=causal, window=window,
-                                 segment_ids=segment_ids)
-    bq, bk, gf = _flash_blocks(q.shape[1], k.shape[1],
-                               g=q.shape[2] // k.shape[2], D=q.shape[3],
-                               packed=segment_ids is not None)
+                                 segment_ids=segment_ids, scale=scale)
+    bq, bk, gf = _flash_blocks_for(q, k, v, segment_ids)
 
     def kernel(q, k, v, segment_ids):
         return fa.flash_attention(q, k, v, segment_ids=segment_ids,
                                   causal=causal, window=window, bq=bq, bk=bk,
-                                  gf=gf, interpret=_interpret())
+                                  gf=gf, scale=scale, interpret=_interpret())
 
     return _per_shard(kernel, q, k, v, segment_ids)
 

@@ -14,15 +14,17 @@ import jax.numpy as jnp
 
 def mha_reference(q: jax.Array, k: jax.Array, v: jax.Array, *,
                   causal: bool = True, window: Optional[int] = None,
-                  segment_ids: Optional[jax.Array] = None) -> jax.Array:
-    """q: (B, Sq, Hq, D); k/v: (B, Sk, Hkv, D); GQA by head grouping.
+                  segment_ids: Optional[jax.Array] = None,
+                  scale: Optional[float] = None) -> jax.Array:
+    """q: (B, Sq, Hq, D); k: (B, Sk, Hkv, D); v: (B, Sk, Hkv, Dv); GQA by
+    head grouping; softmax scale ``scale`` (default ``D ** -0.5``).
     Assumes q positions are aligned with k positions (self-attention).
     ``segment_ids`` (B, S) int32 restricts attention to equal ids — the
     packed-sequence mask the flash kernel shares (Sq must equal Sk)."""
     B, Sq, Hq, D = q.shape
     Sk, Hkv = k.shape[1], k.shape[2]
     g = Hq // Hkv
-    qf = q.astype(jnp.float32) * (D ** -0.5)
+    qf = q.astype(jnp.float32) * (D ** -0.5 if scale is None else scale)
     qf = qf.reshape(B, Sq, Hkv, g, D)
     scores = jnp.einsum("bqhgd,bkhd->bhgqk", qf, k.astype(jnp.float32))
     qpos = jnp.arange(Sq)[:, None]
@@ -39,7 +41,7 @@ def mha_reference(q: jax.Array, k: jax.Array, v: jax.Array, *,
         scores = jnp.where(ok[None, None, None], scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bhgqk,bkhd->bqhgd", probs, v.astype(jnp.float32))
-    return out.reshape(B, Sq, Hq, D).astype(q.dtype)
+    return out.reshape(B, Sq, Hq, v.shape[-1]).astype(q.dtype)
 
 
 def decode_attention_reference(q: jax.Array, k: jax.Array, v: jax.Array,

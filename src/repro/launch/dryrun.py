@@ -79,7 +79,7 @@ def _lint_cell(rec: dict, hlo: str, cfg, plan, mesh, kind: str,
 
 
 def run_cell(arch: str, shape_name: str, *, multi_pod: bool, out_dir: Path,
-             verbose: bool = True, sp: bool = False, moe: str = "einsum",
+             verbose: bool = True, sp: bool = False,
              prefill_last_only: bool = False, remat: str = None,
              gather_once: bool = False, tag: str = "",
              lint: bool = False) -> dict:
@@ -89,7 +89,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool, out_dir: Path,
     mesh_tag = ("multipod" if multi_pod else "pod") + (f"-{tag}" if tag else "")
     rec = {"arch": arch, "shape": shape_name, "mesh": mesh_tag,
            "status": "skip", "reason": why,
-           "variant": {"sp": sp, "moe": moe,
+           "variant": {"sp": sp,
                        "prefill_last_only": prefill_last_only, "remat": remat}}
     if not ok:
         if verbose:
@@ -108,10 +108,9 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool, out_dir: Path,
     else:
         mesh = make_production_mesh(multi_pod=multi_pod)
 
-    from repro.models.moe import moe_impl
     t0 = time.time()
     try:
-        with mesh, moe_impl(moe):
+        with mesh:
             if shape.kind == "train":
                 lowered, aux = _train_artifacts(cfg, plan, mesh, shape)
             else:
@@ -205,7 +204,6 @@ def main():
     ap.add_argument("--both-meshes", action="store_true")
     ap.add_argument("--out", default="results/dryrun")
     ap.add_argument("--sp", action="store_true", help="sequence parallelism")
-    ap.add_argument("--moe-impl", default="einsum", choices=["einsum", "sort"])
     ap.add_argument("--prefill-last-only", action="store_true")
     ap.add_argument("--remat", default=None, choices=[None, "none", "dots", "full", "stage"])
     ap.add_argument("--gather-once", action="store_true")
@@ -233,7 +231,7 @@ def main():
         for mp in meshes:
             results.append(run_cell(
                 arch, shape, multi_pod=mp, out_dir=out_dir, sp=args.sp,
-                moe=args.moe_impl, prefill_last_only=args.prefill_last_only,
+                prefill_last_only=args.prefill_last_only,
                 remat=args.remat, gather_once=args.gather_once, tag=args.tag,
                 lint=args.lint))
     n_ok = sum(r["status"] == "ok" for r in results)

@@ -1,5 +1,6 @@
 """Multi-head attention: GQA, causal / sliding-window / bidirectional masks,
-RoPE, ring-buffer KV caches for sub-quadratic long-context decode.
+RoPE, ring-buffer KV caches for sub-quadratic long-context decode; latent
+attention (MLA, with YaRN rope) for training.
 
 The einsum path here is the paper-faithful ("out-of-the-box XLA") baseline.
 ``repro.kernels.ops`` provides the Pallas flash-attention fast path; model code
@@ -20,6 +21,8 @@ Params = Dict[str, Any]
 
 
 def attention_init(key, cfg: ModelConfig, *, cross: bool = False) -> Params:
+    if cfg.is_mla:
+        return mla_init(key, cfg)
     d, hd = cfg.d_model, cfg.hd
     q_dim, kv_dim = cfg.n_heads * hd, cfg.n_kv_heads * hd
     kq, kk, kv, ko = jax.random.split(key, 4)
@@ -34,6 +37,87 @@ def attention_init(key, cfg: ModelConfig, *, cross: bool = False) -> Params:
         p["bk"] = jnp.zeros((kv_dim,), jnp.float32)
         p["bv"] = jnp.zeros((kv_dim,), jnp.float32)
     return p
+
+
+# ---------------------------------------------------------------------------
+# latent attention (MLA, DeepSeek-V2): training path
+# ---------------------------------------------------------------------------
+
+def mla_init(key, cfg: ModelConfig) -> Params:
+    """Leaves of latent attention without a query latent (``q_lora_rank``
+    null): ``wq`` (d, H·(nope+rope)), ``wkv_a`` (d, latent+rope) giving the
+    latent c_kv and the rotary key shared by all heads, ``kv_norm`` on the
+    latent, ``wkv_b`` (latent, H·(nope+v)) and ``wo`` (H·v, d)."""
+    d, H, r = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    kq, ka, kb, ko = jax.random.split(key, 4)
+    return {
+        "wq": layers.dense_init(kq, d, H * (dn + dr)),
+        "wkv_a": layers.dense_init(ka, d, r + dr),
+        "kv_norm": layers.rmsnorm_init(r),
+        "wkv_b": layers.dense_init(kb, r, H * (dn + dv)),
+        "wo": layers.dense_init(ko, H * dv, d, scale=1.0 / (
+            (H * dv) ** 0.5 * (2 * cfg.n_layers) ** 0.5)),
+    }
+
+
+def _mla_rope(cfg: ModelConfig):
+    """(rotary frequencies or None for plain rope, the factor YaRN puts on
+    the rotated dims' cos and sin)."""
+    f = cfg.rope_scaling_factor
+    if f <= 1:
+        return None, 1.0
+    freqs = layers.yarn_freqs(cfg.qk_rope_head_dim, cfg.rope_theta, f,
+                              cfg.rope_original_max, cfg.yarn_beta_fast,
+                              cfg.yarn_beta_slow)
+    return freqs, (layers.yarn_mscale(f, cfg.yarn_mscale)
+                   / layers.yarn_mscale(f, cfg.yarn_mscale_all_dim))
+
+
+def mla_softmax_scale(cfg: ModelConfig) -> float:
+    """``(nope + rope) ** -0.5``, times YaRN's m² for the whole score."""
+    m = (layers.yarn_mscale(cfg.rope_scaling_factor, cfg.yarn_mscale_all_dim)
+         if cfg.yarn_mscale_all_dim else 1.0)
+    return (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5 * m * m
+
+
+def mla_apply(cfg: ModelConfig, p: Params, x: jax.Array, positions: jax.Array,
+              *, causal: bool = True, window: Optional[int] = None,
+              segment_ids: Optional[jax.Array] = None) -> jax.Array:
+    """Latent attention over a full sequence: q = x·W_q; [c_kv, k_rope] =
+    x·W_kv_a; c_kv normed; [k_nope, v] = c_kv·W_kv_b per head; rotary
+    positions (YaRN where configured) on q's rope dims and on the one
+    shared k_rope; attention at head dims nope+rope (Q/K) and v (V)."""
+    B, S, _ = x.shape
+    H, r = cfg.n_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    dt = x.dtype
+    with jax.named_scope("mla"):
+        q = (x @ p["wq"].astype(dt)).reshape(B, S, H, dn + dr)
+        kv_a = x @ p["wkv_a"].astype(dt)
+        c_kv = layers.rmsnorm(p["kv_norm"], kv_a[..., :r])
+        kv = (c_kv @ p["wkv_b"].astype(dt)).reshape(B, S, H, dn + dv)
+        freqs, m = _mla_rope(cfg)
+        q_rope = layers.apply_rope(q[..., dn:], positions, cfg.rope_theta, freqs)
+        k_rope = layers.apply_rope(kv_a[..., None, r:], positions,
+                                   cfg.rope_theta, freqs)
+        if m != 1.0:
+            q_rope, k_rope = q_rope * m, k_rope * m
+        q = jnp.concatenate([q[..., :dn], q_rope], axis=-1)
+        k = jnp.concatenate([kv[..., :dn],
+                             jnp.broadcast_to(k_rope, (B, S, H, dr))], axis=-1)
+        v = kv[..., dn:]
+    out = sdpa(q, k, v, None, causal=causal, window=window,
+               segment_ids=segment_ids, scale=mla_softmax_scale(cfg))
+    with jax.named_scope("mla"):
+        return out.reshape(B, S, H * dv) @ p["wo"].astype(dt)
+
+
+def _refuse_mla(cfg: ModelConfig) -> None:
+    if cfg.is_mla:
+        raise NotImplementedError(
+            f"{cfg.name}: latent attention has no decode or prefill cache "
+            f"(a latent paged cache); it trains only")
 
 
 def _mask_bias(q_pos: jax.Array, k_pos: jax.Array, *, causal: bool,
@@ -56,7 +140,8 @@ CHUNKED_THRESHOLD = 32 * 1024 * 1024  # Sq·Sk elements above which we go chunke
 
 def chunked_sdpa(q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool,
                  window, segment_ids: Optional[jax.Array] = None,
-                 bq: int = 512, bk: int = 1024) -> jax.Array:
+                 bq: int = 512, bk: int = 1024,
+                 scale: Optional[float] = None) -> jax.Array:
     """Online-softmax attention in pure jnp (flash attention expressed as a
     rolled ``lax.map``/``lax.scan`` nest): O(Sq·bk) memory instead of O(Sq·Sk),
     which is what lets the 32k-prefill shapes compile without materializing
@@ -64,13 +149,13 @@ def chunked_sdpa(q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool,
     global/SWA mix).  ``segment_ids`` (B, S) restricts attention to equal ids
     (packed sequences) — the same mask the flash kernel and einsum path use."""
     B, Sq, Hq, D = q.shape
-    Sk, Hkv = k.shape[1], k.shape[2]
+    Sk, Hkv, Dv = k.shape[1], k.shape[2], v.shape[3]
     g = Hq // Hkv
     bq = min(bq, Sq)
     bk = min(bk, Sk)
     pad_q = (-Sq) % bq
     pad_k = (-Sk) % bk
-    qf = q.astype(jnp.float32) * (D ** -0.5)
+    qf = q.astype(jnp.float32) * (D ** -0.5 if scale is None else scale)
     if pad_q:
         qf = jnp.pad(qf, ((0, 0), (0, pad_q), (0, 0), (0, 0)))
     kf = k.astype(jnp.float32)
@@ -81,7 +166,7 @@ def chunked_sdpa(q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool,
     nq, nk = qf.shape[1] // bq, kf.shape[1] // bk
     qb = jnp.moveaxis(qf.reshape(B, nq, bq, Hkv, g, D), 1, 0)      # (nq,B,bq,Hkv,g,D)
     kb = jnp.moveaxis(kf.reshape(B, nk, bk, Hkv, D), 1, 0)
-    vb = jnp.moveaxis(vf.reshape(B, nk, bk, Hkv, D), 1, 0)
+    vb = jnp.moveaxis(vf.reshape(B, nk, bk, Hkv, Dv), 1, 0)
     if segment_ids is not None:
         segf = segment_ids.astype(jnp.int32)
         # pad q/k tails with distinct ids so padded rows/cols never pair up
@@ -120,21 +205,23 @@ def chunked_sdpa(q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool,
 
         m0 = jnp.full((B, Hkv, g, bq), -1e30, jnp.float32)
         l0 = jnp.zeros((B, Hkv, g, bq), jnp.float32)
-        a0 = jnp.zeros((B, Hkv, g, bq, D), jnp.float32)
+        a0 = jnp.zeros((B, Hkv, g, bq, Dv), jnp.float32)
         (m, l, acc), _ = jax.lax.scan(one_k, (m0, l0, a0),
                                       (jnp.arange(nk), kb, vb, ksb))
         out = acc / jnp.maximum(l, 1e-30)[..., None]               # (B,Hkv,g,bq,D)
         return jnp.moveaxis(out, 3, 1)                             # (B,bq,Hkv,g,D)
 
     outs = jax.lax.map(one_q, (jnp.arange(nq), qb, qsb))           # (nq,B,bq,...)
-    out = jnp.moveaxis(outs, 0, 1).reshape(B, nq * bq, Hq, D)
+    out = jnp.moveaxis(outs, 0, 1).reshape(B, nq * bq, Hq, Dv)
     return out[:, :Sq].astype(q.dtype)
 
 
 def sdpa(q: jax.Array, k: jax.Array, v: jax.Array, bias: Optional[jax.Array],
          *, causal: bool, window=None,
-         segment_ids: Optional[jax.Array] = None) -> jax.Array:
-    """Scaled dot-product attention with GQA. q:(B,Sq,Hq,D) k/v:(B,Sk,Hkv,D).
+         segment_ids: Optional[jax.Array] = None,
+         scale: Optional[float] = None) -> jax.Array:
+    """Scaled dot-product attention with GQA. q:(B,Sq,Hq,D) k:(B,Sk,Hkv,D)
+    v:(B,Sk,Hkv,Dv); the softmax scale is ``scale`` (default ``D ** -0.5``).
 
     Dispatch: Pallas flash kernel (differentiable — training AND prefill take
     it when enabled and the shapes divide the block sizes) → chunked
@@ -150,16 +237,16 @@ def sdpa(q: jax.Array, k: jax.Array, v: jax.Array, bias: Optional[jax.Array],
     if flags.use_flash_attention() and bias is None:
         from repro.kernels import ops
         if ops.flash_supported(q, k, causal=causal, window=window,
-                               segment_ids=segment_ids):
+                               segment_ids=segment_ids, v=v):
             return ops.flash_attention(q, k, v, causal=causal, window=window,
-                                       segment_ids=segment_ids)
+                                       segment_ids=segment_ids, scale=scale)
     if bias is None and q.shape[1] * k.shape[1] > CHUNKED_THRESHOLD:
         return chunked_sdpa(q, k, v, causal=causal, window=window,
-                            segment_ids=segment_ids)
+                            segment_ids=segment_ids, scale=scale)
     B, Sq, Hq, D = q.shape
     Sk, Hkv = k.shape[1], k.shape[2]
     g = Hq // Hkv
-    qf = q.astype(jnp.float32) * (D ** -0.5)
+    qf = q.astype(jnp.float32) * (D ** -0.5 if scale is None else scale)
     qf = qf.reshape(B, Sq, Hkv, g, D)
     scores = jnp.einsum("bqhgd,bkhd->bhgqk", qf, k.astype(jnp.float32))
     if bias is not None:
@@ -178,7 +265,7 @@ def sdpa(q: jax.Array, k: jax.Array, v: jax.Array, bias: Optional[jax.Array],
             scores = jnp.where(ok[None, None, None], scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bhgqk,bkhd->bqhgd", probs, v.astype(jnp.float32))
-    return out.reshape(B, Sq, Hq, D).astype(q.dtype)
+    return out.reshape(B, Sq, Hq, v.shape[3]).astype(q.dtype)
 
 
 def attention_apply(cfg: ModelConfig, p: Params, x: jax.Array, positions: jax.Array,
@@ -193,6 +280,11 @@ def attention_apply(cfg: ModelConfig, p: Params, x: jax.Array, positions: jax.Ar
     pass it)."""
     if segment_ids is not None and kv_source is not None:
         raise ValueError("segment_ids only apply to self-attention")
+    if cfg.is_mla:
+        if kv_source is not None:
+            raise NotImplementedError("latent attention is self-attention")
+        return mla_apply(cfg, p, x, positions, causal=causal, window=window,
+                         segment_ids=segment_ids)
     B, S, d = x.shape
     hd = cfg.hd
     dt = x.dtype
@@ -233,6 +325,7 @@ def attention_prefill(cfg: ModelConfig, p: Params, x: jax.Array, positions: jax.
     ``segment_ids`` carries the batched mixed-length admission mask (id -1
     on right-padded positions, so real tokens never attend into another
     request's pad tail and padded prefills stay on the flash kernel)."""
+    _refuse_mla(cfg)
     B, S, d = x.shape
     hd, dt = cfg.hd, x.dtype
     q = x @ p["wq"].astype(dt)
@@ -303,6 +396,7 @@ def attention_decode_paged(cfg: ModelConfig, p: Params, x: jax.Array,
     guarantees the target page is mapped and exclusively owned
     (``PagedKVManager.ensure_writable`` — the copy-on-write boundary).
     Returns (out, k_pool, v_pool)."""
+    _refuse_mla(cfg)
     B, _, d = x.shape
     hd, dt = cfg.hd, x.dtype
     ps = k_pool.shape[1]
@@ -351,6 +445,7 @@ def attention_prefill_paged(cfg: ModelConfig, p: Params, x: jax.Array,
     positions keeps stale bytes in partially-filled tail pages invisible
     (their logical positions exceed every query position).  Returns
     (out, k_pool, v_pool)."""
+    _refuse_mla(cfg)
     B, S, d = x.shape
     hd, dt = cfg.hd, x.dtype
     ps, n_max = k_pool.shape[1], page_table.shape[1]
@@ -385,6 +480,7 @@ def attention_decode(cfg: ModelConfig, p: Params, x: jax.Array, t: jax.Array,
                      cache: Dict[str, jax.Array], *, window: Optional[int] = None,
                      cross: bool = False):
     """x: (B, 1, d); t: scalar absolute position. Returns (out, new_cache)."""
+    _refuse_mla(cfg)
     B, _, d = x.shape
     hd, dt = cfg.hd, x.dtype
     q = (x @ p["wq"].astype(dt)).reshape(B, 1, cfg.n_heads, hd)

@@ -30,13 +30,32 @@ class ModelConfig:
     tie_embeddings: bool = True
     max_position: int = 524288       # for learned pos-embed archs this is clamped
 
+    # --- latent attention (MLA, DeepSeek-V2); kv_lora_rank 0 = GQA ---
+    kv_lora_rank: int = 0            # width of the normed K/V latent c_kv
+    qk_nope_head_dim: int = 0        # per-head q/k dims without rotary
+    qk_rope_head_dim: int = 0        # rotary dims (k's are shared by all heads)
+    v_head_dim: int = 0
+
+    # --- YaRN rotary scaling (factor 0 = plain rope) ---
+    rope_scaling_factor: float = 0.0
+    rope_original_max: int = 0       # original_max_position_embeddings
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
+
     # --- MoE ---
-    n_experts: int = 0
+    n_experts: int = 0               # experts held here: ids [0, n_experts)
+    router_experts: int = 0          # router width (0 = n_experts)
     n_shared_experts: int = 0
     top_k: int = 0
     moe_d_ff: int = 0                # per-expert hidden (fine-grained MoE)
     first_k_dense: int = 0           # DeepSeekMoE: first k layers use dense FFN
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25    # GShard path (expert axis on a mesh)
+    norm_topk_prob: bool = True      # renormalize the top-k gates to sum 1
+    routed_scaling_factor: float = 1.0
+    moe_aux_coef: float = 0.01       # balance-loss coefficient
+    moe_seq_aux: bool = False        # balance loss per row, then the mean
 
     # --- SSM / hybrid ---
     ssm_state: int = 0               # mamba state size N
@@ -57,6 +76,15 @@ class ModelConfig:
     @property
     def hd(self) -> int:
         return self.head_dim if self.head_dim is not None else self.d_model // self.n_heads
+
+    @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def n_router(self) -> int:
+        """Router width: every expert of the layer, held here or not."""
+        return self.router_experts or self.n_experts
 
     @property
     def compute_dtype(self):
@@ -81,10 +109,18 @@ class ModelConfig:
         att = d * (q + 2 * kv) + q * d
         if self.qkv_bias:
             att += q + 2 * kv
+        if self.is_mla:
+            H, r = self.n_heads, self.qk_rope_head_dim
+            att = (d * H * (self.qk_nope_head_dim + r)          # wq
+                   + d * (self.kv_lora_rank + r)                # wkv_a
+                   + self.kv_lora_rank                          # kv_norm
+                   + self.kv_lora_rank * H * (self.qk_nope_head_dim
+                                              + self.v_head_dim)  # wkv_b
+                   + H * self.v_head_dim * d)                   # wo
         if self.family == "moe":
             ff_moe = 3 * d * self.moe_d_ff  # gate/up/down per expert
             dense_ff = 3 * d * self.d_ff if self.d_ff else 0
-            router = d * self.n_experts
+            router = d * self.n_router
             shared = self.n_shared_experts * 3 * d * self.moe_d_ff
             moe_layer = att + self.n_experts * ff_moe + router + shared + 2 * d
             dense_layer = att + dense_ff + 2 * d
@@ -125,6 +161,7 @@ class ModelConfig:
             head_dim=16,
             vocab_size=256,
             n_experts=min(self.n_experts, 4) or 0,
+            router_experts=min(self.router_experts, 8),
             n_shared_experts=min(self.n_shared_experts, 1),
             top_k=min(self.top_k, 2) if self.top_k else 0,
             moe_d_ff=32 if self.moe_d_ff else 0,
@@ -136,6 +173,11 @@ class ModelConfig:
             enc_frames=32 if self.family == "encdec" else self.enc_frames,
             n_vision_tokens=8 if self.n_vision_tokens else 0,
             swa_window=min(self.swa_window, 32) if self.swa_window else None,
+            kv_lora_rank=min(self.kv_lora_rank, 32),
+            qk_nope_head_dim=min(self.qk_nope_head_dim, 16),
+            qk_rope_head_dim=min(self.qk_rope_head_dim, 8),
+            v_head_dim=min(self.v_head_dim, 8),
+            rope_original_max=min(self.rope_original_max, 64),
             max_position=8192,
             dtype="float32",
         )

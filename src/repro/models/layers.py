@@ -75,10 +75,36 @@ def rope_freqs(head_dim: int, theta: float = 10000.0) -> jax.Array:
     return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float = 10000.0) -> jax.Array:
-    """x: (..., S, H, D); positions: broadcastable to (..., S)."""
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention-temperature factor ``0.1·mscale·ln(factor) + 1``."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_freqs(head_dim: int, theta: float, factor: float, original_max: int,
+               beta_fast: float, beta_slow: float) -> jax.Array:
+    """YaRN (Peng et al. 2023) rotary frequencies, as DeepSeek-V2 computes
+    them: dims rotating faster than ``beta_fast`` turns over the original
+    context keep their frequency, those slower than ``beta_slow`` turns are
+    divided by ``factor``, and a linear ramp blends the dims between."""
+    def turns_dim(turns):
+        return (head_dim * math.log(original_max / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+    lo = max(math.floor(turns_dim(beta_fast)), 0)
+    hi = min(math.ceil(turns_dim(beta_slow)), head_dim - 1)
+    hi = hi + 0.001 if lo == hi else hi
+    extra = rope_freqs(head_dim, theta)
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - lo)
+                    / (hi - lo), 0.0, 1.0)
+    return extra / factor * ramp + extra * (1.0 - ramp)
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float = 10000.0,
+               freqs: jax.Array | None = None) -> jax.Array:
+    """x: (..., S, H, D); positions: broadcastable to (..., S).  ``freqs``
+    (D/2,) replaces the plain ``theta`` frequencies (YaRN)."""
     d = x.shape[-1]
-    freqs = rope_freqs(d, theta)                       # (D/2,)
+    if freqs is None:
+        freqs = rope_freqs(d, theta)                   # (D/2,)
     angles = positions[..., None].astype(jnp.float32) * freqs  # (..., S, D/2)
     sin = jnp.sin(angles)[..., None, :]                # (..., S, 1, D/2)
     cos = jnp.cos(angles)[..., None, :]

@@ -24,7 +24,6 @@ from repro.models.config import ModelConfig
 
 Params = Dict[str, Any]
 
-AUX_LOSS_COEF = 0.01
 BIG_WINDOW = 1 << 30  # "no window" sentinel usable as a traced value
 
 
@@ -54,9 +53,10 @@ def block_init(key, cfg: ModelConfig, *, kind: str) -> Params:
 
 def block_apply(cfg: ModelConfig, p: Params, x: jax.Array, positions: jax.Array,
                 *, kind: str, window,
-                segment_ids: Optional[jax.Array] = None) -> Tuple[jax.Array, jax.Array]:
-    """Returns (x, aux_loss)."""
-    zero = jnp.zeros((), jnp.float32)
+                segment_ids: Optional[jax.Array] = None) -> Tuple[jax.Array, Dict]:
+    """Returns (x, MoE readings: ``moe_mod.no_stats()`` for a block without
+    experts)."""
+    zero = moe_mod.no_stats()
     if kind in ("hymba", "mlstm", "slstm") and segment_ids is not None:
         # recurrent state mixes across the whole row — a segment mask on the
         # attention half alone would silently leak documents into each other
@@ -79,8 +79,8 @@ def block_apply(cfg: ModelConfig, p: Params, x: jax.Array, positions: jax.Array,
     x = sharding.constrain(x, "batch", "seq", None)
     h = layers.norm_apply(cfg.norm, p["norm2"], x)
     if kind == "moe":
-        mo, aux = moe_mod.moe_apply(cfg, p["moe"], h)
-        return x + mo, aux
+        mo, stats = moe_mod.moe_apply(cfg, p["moe"], h)
+        return x + mo, stats
     x = x + layers.mlp_apply(p["mlp"], h, gated=cfg.gated_mlp, act=cfg.act)
     x = sharding.constrain(x, "batch", "seq", None)
     return x, zero
@@ -192,10 +192,10 @@ def _embed_inputs(cfg: ModelConfig, params: Params, batch: Dict[str, jax.Array])
 
 def lm_forward(cfg: ModelConfig, params: Params, batch: Dict[str, jax.Array],
                *, remat_policy: str = "full",
-               last_only: bool = False) -> Tuple[jax.Array, jax.Array]:
+               last_only: bool = False) -> Tuple[jax.Array, Dict]:
     """→ (logits fp32 (B,S,V) — or (B,1,V) when ``last_only``, which slices
     the hidden states BEFORE the unembed so the (S,V) matmul is never built —
-    aux_loss)."""
+    MoE readings summed over the layers, ``moe_mod.add_stats``)."""
     x = _embed_inputs(cfg, params, batch)
     B, S, _ = x.shape
     positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
@@ -204,19 +204,19 @@ def lm_forward(cfg: ModelConfig, params: Params, batch: Dict[str, jax.Array],
     segment_ids = batch.get("segment_ids")
     x = sharding.constrain(x, "batch", "seq", None)
     scanned_kind, n_scanned, pre = layer_plan(cfg)
-    aux = jnp.zeros((), jnp.float32)
+    stats = moe_mod.no_stats()
 
     for (idx, kind), bp in zip(pre, params.get("pre_blocks", [])):
         x, a = block_apply(cfg, bp, x, positions, kind=kind, window=cfg.swa_window,
                            segment_ids=segment_ids)
-        aux = aux + a
+        stats = moe_mod.add_stats(stats, a)
 
     if n_scanned:
         windows = layer_windows(cfg)
         uniform_window = cfg.swa_window
 
         def one_layer(carry, layer_in):
-            x, aux = carry
+            x, stats = carry
             if windows is None:
                 bp = layer_in
                 w = uniform_window
@@ -224,7 +224,7 @@ def lm_forward(cfg: ModelConfig, params: Params, batch: Dict[str, jax.Array],
                 bp, w = layer_in
             x, a = block_apply(cfg, bp, x, positions, kind=scanned_kind, window=w,
                                segment_ids=segment_ids)
-            return (x, aux + a), None
+            return (x, moe_mod.add_stats(stats, a)), None
 
         body = one_layer
         if remat_policy != "none":
@@ -233,7 +233,7 @@ def lm_forward(cfg: ModelConfig, params: Params, batch: Dict[str, jax.Array],
                       else jax.checkpoint_policies.nothing_saveable)
             body = jax.checkpoint(one_layer, policy=policy, prevent_cse=False)
         xs = params["blocks"] if windows is None else (params["blocks"], windows)
-        (x, aux), _ = jax.lax.scan(body, (x, aux), xs)
+        (x, stats), _ = jax.lax.scan(body, (x, stats), xs)
 
     if last_only:
         x = x[:, -1:]
@@ -241,12 +241,12 @@ def lm_forward(cfg: ModelConfig, params: Params, batch: Dict[str, jax.Array],
     table = params.get("lm_head", params["embed"])
     logits = layers.unembed(table, x)
     logits = sharding.constrain(logits, "batch", None, "tp")  # vocab-sharded xent
-    return logits, aux
+    return logits, stats
 
 
 def lm_loss(cfg: ModelConfig, params: Params, batch: Dict[str, jax.Array],
             *, remat_policy: str = "full") -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    logits, aux = lm_forward(cfg, params, batch, remat_policy=remat_policy)
+    logits, stats = lm_forward(cfg, params, batch, remat_policy=remat_policy)
     mask = batch.get("loss_mask")
     if cfg.family == "vlm" and mask is None:
         # vision positions carry no next-token loss
@@ -254,8 +254,12 @@ def lm_loss(cfg: ModelConfig, params: Params, batch: Dict[str, jax.Array],
         mask = (jnp.arange(S)[None] >= cfg.n_vision_tokens).astype(jnp.float32)
         mask = jnp.broadcast_to(mask, batch["tokens"].shape)
     xent = layers.cross_entropy(logits, batch["labels"], mask)
-    loss = xent + AUX_LOSS_COEF * aux
-    return loss, {"xent": xent, "aux": aux}
+    loss = xent + cfg.moe_aux_coef * stats["aux"]
+    metrics = {"xent": xent, "aux": stats["aux"]}
+    if cfg.family == "moe":
+        metrics.update(moe_held_tokens=stats["moe_held_tokens"],
+                       moe_max_load=stats["moe_max_load"])
+    return loss, metrics
 
 
 def block_prefill(cfg: ModelConfig, p: Params, x: jax.Array, positions: jax.Array,

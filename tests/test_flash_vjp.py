@@ -106,6 +106,39 @@ def test_flash_vjp_segment_ids_match_reference_autodiff(causal, window, Hq,
                                    err_msg=f"d{name} mismatch")
 
 
+@pytest.mark.parametrize("causal,Hq,Hkv,D,Dv,scale,lens", [
+    (True, 4, 4, 192, 128, 192 ** -0.5 * 1.2608 ** 2, None),  # MLA's dims
+    (True, 4, 4, 192, 128, 0.11, (40, 50, 38)),    # packed, MLA's dims
+    (True, 4, 2, 96, 64, None, None),              # GQA, Dv < D, default scale
+    (False, 2, 2, 64, 128, 0.2, None),             # bidirectional, Dv > D
+])
+def test_flash_vjp_split_head_dims_and_scale(causal, Hq, Hkv, D, Dv, scale,
+                                             lens):
+    """V's head dim apart from Q/K's and a given softmax scale, forward and
+    VJP of all four kernels, against reference autodiff."""
+    B, S = 2, 128
+    q, k, _ = _qkv(B, S, Hq, Hkv, D)
+    v = jax.random.normal(jax.random.fold_in(KEY, 2), (B, S, Hkv, Dv))
+    seg = None if lens is None else _segments(B, S, lens)
+    cot = jax.random.normal(jax.random.fold_in(KEY, 3), (B, S, Hq, Dv))
+
+    def fl(q, k, v):
+        return flash_attention(q, k, v, segment_ids=seg, causal=causal,
+                               bq=64, bk=64, scale=scale, interpret=True)
+
+    def rf(q, k, v):
+        return ref.mha_reference(q, k, v, causal=causal, segment_ids=seg,
+                                 scale=scale)
+
+    np.testing.assert_allclose(np.asarray(fl(q, k, v)), np.asarray(rf(q, k, v)),
+                               atol=2e-5, rtol=2e-5)
+    for g_fl, g_rf, name in zip(_grads(fl, q, k, v, cot),
+                                _grads(rf, q, k, v, cot), "qkv"):
+        np.testing.assert_allclose(np.asarray(g_fl), np.asarray(g_rf),
+                                   atol=5e-4, rtol=5e-4,
+                                   err_msg=f"d{name} mismatch")
+
+
 def test_flash_vjp_segment_ids_bf16():
     B, S, Hq, Hkv, D = 1, 128, 4, 2, 64
     q, k, v = _qkv(B, S, Hq, Hkv, D, jnp.bfloat16)

@@ -79,6 +79,51 @@ def test_flash_fwd_bwd_compiles(one_chip, packed):
         assert re.search(rf"%flash_attention_{part}(\.\d+)? = ", hlo), part
 
 
+@pytest.mark.parametrize("dv", [128, 192], ids=["dv128", "dv192"])
+def test_flash_mla_head_dims_compile(one_chip, dv):
+    """Latent attention's shapes (deepseek_v2_lite_ep8): 16 heads, Q/K 192
+    wide and V 128 (or as wide as Q/K), packed rows of 8192, the YaRN
+    softmax scale, at the tiles ``ops._flash_blocks`` picks for them."""
+    from repro.kernels import ops
+    Sm, H, Dqk = 8192, 16, 192
+    q = _spec((2, Sm, H, Dqk), jnp.bfloat16, one_chip)
+    v = _spec((2, Sm, H, dv), jnp.bfloat16, one_chip)
+    seg = _spec((2, Sm), jnp.int32, one_chip)
+    bq, bk, gf = ops._flash_blocks(Sm, Sm, g=1, D=Dqk, Dv=dv, packed=True)
+
+    def loss(q, k, v, seg):
+        out = flash_attention(q, k, v, segment_ids=seg, causal=True, bq=bq,
+                              bk=bk, gf=gf, scale=0.1147, interpret=False)
+        return out.astype(jnp.float32).sum()
+
+    hlo = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), q, q, v, seg)
+    for part in ("fwd", "delta", "dq", "dkv"):
+        assert re.search(rf"%flash_attention_{part}(\.\d+)? = ", hlo), part
+
+
+def test_expert_gmm_compiles(one_chip):
+    """The held experts' grouped matmul, forward and backward, at one MoE
+    layer of deepseek_v2_lite_ep8 (16384 tokens, top-6, 8 held experts,
+    hidden 2048, expert width 1408) on its worst-case row layout."""
+    from repro.kernels import grouped_matmul as gm
+    from repro.models.moe import layout_rows
+    M, E, d, ff = layout_rows(16384, 6, 8), 8, 2048, 1408
+    x = _spec((M, d), jnp.bfloat16, one_chip)
+    w = _spec((E, d, ff), jnp.float32, one_chip)
+    te = _spec((M // gm.TILE_M,), jnp.int32, one_chip)
+    n = _spec((1,), jnp.int32, one_chip)
+    sizes = _spec((E,), jnp.int32, one_chip)
+
+    def loss(x, w, te, n, sizes):
+        y = gm.expert_matmul(x, w, te, n, sizes, False)
+        return y.astype(jnp.float32).sum()
+
+    hlo = _compiled_text(jax.value_and_grad(loss, argnums=(0, 1)), x, w, te,
+                         n, sizes)
+    for name in ("expert_gmm", "expert_gmm_dx", "expert_gmm_dw"):
+        assert re.search(rf"%{name}(\.\d+)? = ", hlo), name
+
+
 def test_decode_compiles(one_chip):
     q = _spec((SLOTS, 1, HQ, D), jnp.bfloat16, one_chip)
     kv = _spec((SLOTS, CACHE, HKV, D), jnp.bfloat16, one_chip)
