@@ -344,27 +344,25 @@ def serve_phase(dev) -> None:
     import jax.numpy as jnp
     import numpy as np
     from repro.kernels import ops, ref
-    from repro.runtime import flags
     from repro.session import InferenceSession
 
     rng = np.random.RandomState(SEED)
-    with flags.flag_ctx(flash_decode=True):
-        t0 = time.perf_counter()
-        inf = InferenceSession.from_recipe(full(), seed=SEED)
-        jax.block_until_ready(inf.params)
-        cfg = inf.cfg
-        log(f"serve: {ARCH} all {cfg.n_layers} layers {cfg.dtype} made in "
-            f"{time.perf_counter() - t0:.1f}s")
-        plens = [int(x) for x in rng.randint(64, 700, N_REQUESTS)]
-        gens = [int(x) for x in rng.randint(8, 40, N_REQUESTS)]
-        prompts = [rng.randint(1, cfg.vocab_size, p).astype(np.int32)
-                   for p in plens]
-        t0 = time.perf_counter()
-        outs, stats = inf.serve(prompts, gens, n_slots=N_SLOTS, paged=True,
-                                page_size=PAGE_SIZE)
-        log(f"served {stats.requests} requests ({sum(gens)} tokens) in "
-            f"{time.perf_counter() - t0:.1f}s incl. compiles; pool "
-            f"{stats.pool_pages} pages of {stats.page_size}; {stats}")
+    t0 = time.perf_counter()
+    inf = InferenceSession.from_recipe(full(), seed=SEED)
+    jax.block_until_ready(inf.params)
+    cfg = inf.cfg
+    log(f"serve: {ARCH} all {cfg.n_layers} layers {cfg.dtype} made in "
+        f"{time.perf_counter() - t0:.1f}s")
+    plens = [int(x) for x in rng.randint(64, 700, N_REQUESTS)]
+    gens = [int(x) for x in rng.randint(8, 40, N_REQUESTS)]
+    prompts = [rng.randint(1, cfg.vocab_size, p).astype(np.int32)
+               for p in plens]
+    t0 = time.perf_counter()
+    outs, stats = inf.serve(prompts, gens, n_slots=N_SLOTS, paged=True,
+                            page_size=PAGE_SIZE)
+    log(f"served {stats.requests} requests ({sum(gens)} tokens) in "
+        f"{time.perf_counter() - t0:.1f}s incl. compiles; pool "
+        f"{stats.pool_pages} pages of {stats.page_size}; {stats}")
     check(stats.requests == N_REQUESTS and len(outs) == N_REQUESTS
           and all(len(o) == p + g for o, p, g in zip(outs, plens, gens))
           and all(np.all((o >= 0) & (o < cfg.vocab_size)) for o in outs),
@@ -386,7 +384,8 @@ def serve_phase(dev) -> None:
         used = t // PAGE_SIZE + 1
         pt[b, :used] = rng.choice(np.arange(1, n_pages), used, replace=False)
     pt, ts = jnp.asarray(pt), jnp.asarray(ts)
-    got = ops.paged_decode_attention(q, kp, vp, pt, ts=ts)
+    got = ops.paged_decode_attention(q, kp[None], vp[None], pt, ts=ts,
+                                     layer=jnp.int32(0))
     want = ref.paged_decode_attention_reference(q, kp, vp, pt, ts=ts)
     err = float(jnp.max(jnp.abs(got.astype(jnp.float32)
                                 - want.astype(jnp.float32))))

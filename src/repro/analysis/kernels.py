@@ -196,8 +196,8 @@ def check_kernel(cap: KernelCapture, *,
         where = f"{cap.kernel}/{arg.name}"
         if arg.block_shape is None:
             continue
-        bs = tuple(b if b is not None else s
-                   for b, s in zip(arg.block_shape, arg.shape))
+        # a None block dim is squeezed out of the kernel: one element
+        bs = tuple(1 if b is None else b for b in arg.block_shape)
         if len(bs) != len(arg.shape):
             out.append(Finding(
                 pass_name=pass_name, code="block-rank-mismatch",
@@ -335,12 +335,14 @@ def default_kernel_captures(cfg=None) -> List[KernelCapture]:
                                         t=jnp.int32(Sc - 1), window=None,
                                         bk=bkd, interpret=False)
 
-        n_pages, ps, n_max = 8, 64, 4
-        kp = jax.random.normal(key, (n_pages, ps, Hkv, D), jnp.float32)
-        vp = jax.random.normal(key, (n_pages, ps, Hkv, D), jnp.float32)
+        L, n_pages, ps, n_max = 3, 8, 64, 4
+        kp = jax.random.normal(key, (L, n_pages, ps, Hkv, D), jnp.float32)
+        vp = jax.random.normal(key, (L, n_pages, ps, Hkv, D), jnp.float32)
         pt = jnp.tile(jnp.arange(n_max, dtype=jnp.int32)[None], (B, 1))
-        ts = jnp.full((B,), ps * n_max - 1, jnp.int32)
+        # one slot at its last position, one whose later pages are dead
+        ts = jnp.asarray([ps * n_max - 1, ps + 3], jnp.int32)[:B]
         da.paged_decode_attention.__wrapped__(qd, kp, vp, pt, ts=ts,
+                                              layer=jnp.int32(L - 1),
                                               window=None, interpret=False)
     return records
 

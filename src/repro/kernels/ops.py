@@ -162,20 +162,27 @@ def decode_attention(q, k, v, kpos, *, t, window: Optional[int] = None) -> jax.A
                                interpret=_interpret())
 
 
-def paged_decode_attention(q, k_pool, v_pool, page_table, *, ts,
+def paged_kernel_fits(page_size: int) -> bool:
+    """The Pallas paged decode kernel runs where it is compiled (not on the
+    CPU, where the interpreter would be a slowdown) and where a page fills
+    a lane tile."""
+    return not _interpret() and page_size % 128 == 0
+
+
+def paged_decode_attention(q, k_pool, v_pool, page_table, *, ts, layer,
                            window: Optional[int] = None) -> jax.Array:
-    """Decode attention through a block-paged KV pool (per-request page
-    tables, see ``repro.session.kvpool``).  The Pallas kernel steers its K/V
-    DMAs straight off the scalar-prefetched page table; pools whose page size
-    doesn't fill a TPU lane tile fall back to the gather-einsum oracle."""
+    """Decode attention of layer ``layer`` through a block-paged KV pool
+    (per-request page tables, see ``repro.session.kvpool``); the pool
+    leaves are every layer's, (L, n_pages, page_size, Hkv, D).  The Pallas
+    kernel reads the live pages in place, steered by the scalar-prefetched
+    page table and layer; elsewhere (``paged_kernel_fits``) the
+    gather-einsum oracle runs on the layer's pool."""
     from repro.kernels import decode_attention as da
-    ps = k_pool.shape[1]
-    if ps % 128 and not _interpret():
-        return ref.paged_decode_attention_reference(q, k_pool, v_pool,
-                                                    page_table, ts=ts,
-                                                    window=window)
+    if not paged_kernel_fits(k_pool.shape[2]):
+        return ref.paged_decode_attention_reference(
+            q, k_pool[layer], v_pool[layer], page_table, ts=ts, window=window)
     return da.paged_decode_attention(q, k_pool, v_pool, page_table, ts=ts,
-                                     window=window,
+                                     layer=layer, window=window,
                                      interpret=_interpret())
 
 

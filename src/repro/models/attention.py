@@ -383,23 +383,24 @@ def _pool_positions(page_table: jax.Array, page_size: int):
 
 def attention_decode_paged(cfg: ModelConfig, p: Params, x: jax.Array,
                            ts: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
-                           page_table: jax.Array, *,
+                           page_table: jax.Array, layer: jax.Array, *,
                            window: Optional[int] = None):
-    """One-token attention against a block-paged KV pool.
+    """One-token attention of layer ``layer`` against the block-paged pool.
 
     x: (B, 1, d); ts: (B,) per-request absolute positions;
-    k_pool/v_pool: (n_pages, page_size, Hkv, hd) shared across requests;
-    page_table: (B, n_max) physical page per logical page, -1 = unmapped.
-    Token j of logical page i sits at position i*page_size + j.  The new K/V
-    is scattered through the table (an inactive row whose page is unmapped
-    lands on the reserved trash page 0 and is never read); the scheduler
-    guarantees the target page is mapped and exclusively owned
+    k_pool/v_pool: (L, n_pages, page_size, Hkv, hd), every layer's pages,
+    shared across requests; page_table: (B, n_max) physical page per
+    logical page, -1 = unmapped.  Token j of logical page i sits at position
+    i*page_size + j.  The new K/V row is written in place at
+    ``[layer, page, j]`` through the table (an inactive row whose page is
+    unmapped writes the reserved trash page 0, which is never read); the
+    scheduler guarantees the target page is mapped and exclusively owned
     (``PagedKVManager.ensure_writable`` — the copy-on-write boundary).
     Returns (out, k_pool, v_pool)."""
     _refuse_mla(cfg)
     B, _, d = x.shape
     hd, dt = cfg.hd, x.dtype
-    ps = k_pool.shape[1]
+    ps = k_pool.shape[2]
     q = (x @ p["wq"].astype(dt)).reshape(B, 1, cfg.n_heads, hd)
     knew = x @ p["wk"].astype(dt)
     vnew = x @ p["wv"].astype(dt)
@@ -413,19 +414,19 @@ def attention_decode_paged(cfg: ModelConfig, p: Params, x: jax.Array,
         knew = layers.apply_rope(knew, ts[:, None], cfg.rope_theta)
     pidx = jnp.take_along_axis(page_table, (ts // ps)[:, None], axis=1)[:, 0]
     pidx = jnp.maximum(pidx, 0)
-    slot = ts % ps
-    k_pool = k_pool.at[pidx, slot].set(knew[:, 0].astype(k_pool.dtype))
-    v_pool = v_pool.at[pidx, slot].set(vnew[:, 0].astype(v_pool.dtype))
-    from repro.runtime import flags
-    if flags.use_flash_decode():
-        from repro.kernels import ops
-        out = ops.paged_decode_attention(q, k_pool.astype(dt), v_pool.astype(dt),
-                                         page_table, ts=ts, window=window)
-    else:
-        from repro.kernels import ref
-        out = ref.paged_decode_attention_reference(
-            q, k_pool.astype(dt), v_pool.astype(dt), page_table, ts=ts,
-            window=window)
+    # each row rewrites its whole page with the new row blended in: a TPU
+    # keeps a pool whose head dim is under a lane tile with the page's
+    # positions minor (``decode_attention.keys_minor``), and there a
+    # one-row scatter makes XLA relayout the whole pool; a page-wide one
+    # updates it in place
+    at = (jnp.arange(ps) == (ts % ps)[:, None])[:, :, None, None]
+    k_pool = k_pool.at[layer, pidx].set(
+        jnp.where(at, knew.astype(k_pool.dtype), k_pool[layer, pidx]))
+    v_pool = v_pool.at[layer, pidx].set(
+        jnp.where(at, vnew.astype(v_pool.dtype), v_pool[layer, pidx]))
+    from repro.kernels import ops
+    out = ops.paged_decode_attention(q, k_pool, v_pool, page_table, ts=ts,
+                                     layer=layer, window=window)
     out = out.reshape(B, 1, cfg.n_heads * hd)
     return out @ p["wo"].astype(dt), k_pool, v_pool
 

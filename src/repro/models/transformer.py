@@ -373,10 +373,10 @@ def lm_paged_pool_init(cfg: ModelConfig, n_pages: int, page_size: int,
 
 
 def block_decode_paged(cfg: ModelConfig, p: Params, x, ts, pk, pv, page_table,
-                       *, window):
+                       layer, *, window):
     h = layers.norm_apply(cfg.norm, p["norm1"], x)
     h, pk, pv = attention_decode_paged(cfg, p["attn"], h, ts, pk, pv,
-                                       page_table, window=window)
+                                       page_table, layer, window=window)
     x = x + h
     h = layers.norm_apply(cfg.norm, p["norm2"], x)
     x = x + layers.mlp_apply(p["mlp"], h, gated=cfg.gated_mlp, act=cfg.act)
@@ -399,22 +399,28 @@ def lm_paged_decode_step(cfg: ModelConfig, params: Params, token: jax.Array,
                          ts: jax.Array, pool, page_tables):
     """One decode step where every batch row reads/writes KV through its OWN
     page-table row at its OWN position.  token/ts: (B,);
-    page_tables: (B, n_max) int32.  → (logits (B, V), pool)."""
-    _require_paged_plan(cfg)
+    page_tables: (B, n_max) int32.  → (logits (B, V), pool).
+
+    The stacked pool rides the layer scan as carry: each layer writes its
+    new K/V rows and reads its pages in place by layer index, so no layer's
+    pool is sliced out or restacked."""
+    L = _require_paged_plan(cfg)
     dt = cfg.compute_dtype
     x = layers.embed_lookup(params["embed"], token[:, None], dt)
     if cfg.pos_embed == "learned":
         maxp = params["pos_embed"].shape[0]
         x = x + params["pos_embed"][jnp.minimum(ts, maxp - 1)].astype(dt)[:, None]
 
-    def step(x, layer_in):
-        bp, pk, pv = layer_in
+    def step(carry, layer_in):
+        x, pk, pv = carry
+        bp, layer = layer_in
         x, pk, pv = block_decode_paged(cfg, bp, x, ts, pk, pv, page_tables,
-                                       window=cfg.swa_window)
-        return x, (pk, pv)
+                                       layer, window=cfg.swa_window)
+        return (x, pk, pv), None
 
-    x, (nk, nv) = jax.lax.scan(
-        step, x, (params["blocks"], pool["blocks"]["k"], pool["blocks"]["v"]))
+    (x, nk, nv), _ = jax.lax.scan(
+        step, (x, pool["blocks"]["k"], pool["blocks"]["v"]),
+        (params["blocks"], jnp.arange(L, dtype=jnp.int32)))
     x = layers.norm_apply(cfg.norm, params["final_norm"], x)
     table = params.get("lm_head", params["embed"])
     logits = layers.unembed(table, x)[:, 0]

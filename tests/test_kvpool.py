@@ -198,16 +198,27 @@ def test_paged_reference_matches_contiguous_bitwise(g):
                                       np.asarray(out_c))
 
 
+def _stack(pool, layer, L=3, seed=1):
+    """``pool`` at ``layer`` of an L-layer stack whose other layers hold
+    other numbers, so a kernel that reads the wrong layer cannot pass."""
+    rng = np.random.default_rng(seed)
+    stack = rng.standard_normal((L,) + pool.shape).astype(np.float32)
+    stack[layer] = np.asarray(pool)
+    return jnp.asarray(stack)
+
+
 @pytest.mark.parametrize("window", [None, 160])
 def test_paged_kernel_matches_contiguous_kernel_bitwise(window):
     """The Pallas paged kernel sweeps logical pages with the same online
     softmax as the contiguous kernel: with bk == page_size the two are
-    bit-identical (incl. sliding-window masking)."""
+    bit-identical (incl. sliding-window masking), read from one layer of
+    a stacked pool."""
     from repro.kernels import decode_attention as da
     ts_list = [5, 130, 383]
     q, kp, vp, pt, ts, kc, vc, pos = _paged_case(2, ts_list)
-    out_p = da.paged_decode_attention(q, kp, vp, pt, ts=ts, window=window,
-                                      interpret=True)
+    out_p = da.paged_decode_attention(q, _stack(kp, 1), _stack(vp, 1, seed=2),
+                                      pt, ts=ts, layer=jnp.int32(1),
+                                      window=window, interpret=True)
     for b, t in enumerate(ts_list):
         out_c = da.decode_attention(
             q[b:b + 1], kc[b:b + 1], vc[b:b + 1], pos[b:b + 1],
@@ -221,12 +232,71 @@ def test_paged_kernel_window_matches_reference():
     from repro.kernels import ref
     ts_list = [60, 300]
     q, kp, vp, pt, ts, *_ = _paged_case(2, ts_list)
-    out_k = da.paged_decode_attention(q, kp, vp, pt, ts=ts, window=100,
+    out_k = da.paged_decode_attention(q, kp[None], vp[None], pt, ts=ts,
+                                      layer=jnp.int32(0), window=100,
                                       interpret=True)
     out_r = ref.paged_decode_attention_reference(q, kp, vp, pt, ts=ts,
                                                  window=100)
     np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_r),
                                atol=2e-6, rtol=2e-6)
+
+
+@pytest.mark.parametrize("layer,window,table,D", [
+    (0, None, "live", 16), (2, None, "live", 16), (1, 160, "live", 16),
+    (1, None, "dead_tail", 16), (2, 100, "dead_tail", 16),
+    (0, None, "holes", 16), (2, 160, "holes", 16),
+    (1, None, "dead_tail", 128), (2, 160, "holes", 128)])
+def test_paged_kernel_reads_stacked_pool_by_layer(layer, window, table, D):
+    """The kernel reads layer ``layer`` of the stacked pool in place and
+    equals the reference on that layer's pool: with only live pages
+    mapped; with pages mapped past a slot's position (``dead_tail``: the
+    index map repeats the last live page and the compute is skipped); and
+    with an unmapped page inside the live range (``holes``: masked).  A
+    head dim under a lane tile reads pages as (Hkv, D, page_size), a lane
+    multiple as (page_size, Hkv, D)."""
+    from repro.kernels import decode_attention as da
+    from repro.kernels import ref
+    ts_list = [5, 130, 200, 383, 0]
+    q, kp, vp, pt, ts, *_ = _paged_case(2, ts_list, n_max=4, D=D)
+    pt = np.asarray(pt).copy()
+    if table == "dead_tail":
+        free = iter(range(1 + int(pt.max()), kp.shape[0]))
+        for b, t in enumerate(ts_list):
+            for i in range(t // 128 + 1, pt.shape[1]):
+                pt[b, i] = next(free)
+    elif table == "holes":
+        pt[1, 0] = -1
+        pt[3, 1] = -1
+    pt = jnp.asarray(pt)
+    k_stack, v_stack = _stack(kp, layer), _stack(vp, layer, seed=2)
+    out_k = da.paged_decode_attention(q, k_stack, v_stack, pt, ts=ts,
+                                      layer=jnp.int32(layer), window=window,
+                                      interpret=True)
+    out_r = ref.paged_decode_attention_reference(
+        q, k_stack[layer], v_stack[layer], pt, ts=ts, window=window)
+    np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_r),
+                               atol=2e-6, rtol=2e-6)
+
+
+def test_paged_decode_step_kernel_matches_reference_path(monkeypatch):
+    """The serving decode step through the Pallas kernel (interpreted here;
+    the backend check is forced) emits the same greedy tokens as the
+    reference path on a dense GQA config."""
+    from repro.kernels import ops
+    prompts = _prompts(_session("granite_3_2b"), (5, 19, 9))
+    budgets = [12, 6, 9]
+
+    def serve():
+        sess = InferenceSession.from_recipe("granite_3_2b", reduced=True,
+                                            seed=0)
+        return sess.serve(prompts, budgets, n_slots=2, paged=True,
+                          page_size=8)[0]
+
+    want = serve()
+    monkeypatch.setattr(ops, "paged_kernel_fits", lambda page_size: True)
+    got = serve()
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g, w)
 
 
 # ---------------------------------------------------------------------------

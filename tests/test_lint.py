@@ -259,6 +259,21 @@ def test_kernel_out_of_bounds_and_rank():
     assert {f.code for f in check_kernel(rank)} == {"block-rank-mismatch"}
 
 
+def test_kernel_squeezed_dim_is_one_element():
+    """A None block dim (squeezed out of the kernel, as the paged decode
+    kernel's layer axis) indexes single elements: every layer of a stack
+    of 3 is in bounds, a fourth is not."""
+    def cap(n):
+        return KernelCapture(
+            kernel="squeezed", grid=(n,), in_args=[
+                KernelArg("in0", (3, 8, 128), (None, 8, 128),
+                          lambda i: (i, 0, 0))],
+            out_args=[], num_scalar_prefetch=0, scalar_values=(),
+            dimension_semantics=None)
+    assert check_kernel(cap(3)) == []
+    assert {f.code for f in check_kernel(cap(4))} == {"index-out-of-bounds"}
+
+
 def test_capture_does_not_execute_kernel():
     from jax.experimental import pallas as pl
     ran = []

@@ -139,15 +139,68 @@ def test_decode_compiles(one_chip):
 
 def test_paged_decode_compiles(one_chip):
     q = _spec((SLOTS, 1, HQ, D), jnp.bfloat16, one_chip)
-    pool = _spec((N_PAGES, PAGE, HKV, D), jnp.bfloat16, one_chip)
+    pool = _spec((4, N_PAGES, PAGE, HKV, D), jnp.bfloat16, one_chip)
     table = _spec((SLOTS, CACHE // PAGE), jnp.int32, one_chip)
     ts = _spec((SLOTS,), jnp.int32, one_chip)
+    layer = _spec((), jnp.int32, one_chip)
     hlo = _compiled_text(
-        lambda q, kp, vp, pt, ts: paged_decode_attention(
-            q, kp, vp, pt, ts=ts, interpret=False),
-        q, pool, pool, table, ts)
+        lambda q, kp, vp, pt, ts, layer: paged_decode_attention(
+            q, kp, vp, pt, ts=ts, layer=layer, interpret=False),
+        q, pool, pool, table, ts, layer)
     assert "tpu_custom_call" in hlo
     assert re.search(r"%paged_decode_attention(\.\d+)? = ", hlo)
+
+
+@pytest.mark.parametrize("head_dim", [64, 128])
+def test_paged_serve_step_reads_pool_in_place(one_chip, monkeypatch,
+                                              head_dim):
+    """The whole serving decode step (``make_paged_serve_step``, jitted
+    with the pool donated as ``InferenceSession.paged_slot_step`` does) at
+    the serve cell's widths: granite_3_2b's head layout, 8 slots, 12 pages
+    of 128 a slot, a pool of 97 pages; 4 of the 40 layers.  The paged
+    kernel runs in every layer, and nothing copies, gathers, slices or
+    restacks the pool: it stays in place from input to output, both where
+    the TPU keeps a page's positions minor (head dim 64, the cell's) and
+    where it keeps the head dim minor (128)."""
+    import dataclasses
+    import numpy as np
+    from repro.configs import get_config
+    from repro.core import stepfn
+    from repro.core.recipe import ParallelismConfig
+    from repro.kernels import ops
+    from repro.models import api as model_api
+    # the dispatch would take the CPU branch in this process
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    cfg = dataclasses.replace(get_config("granite_3_2b"), n_layers=4,
+                              head_dim=head_dim, dtype="bfloat16")
+    L, n_pages, n_max = cfg.n_layers, 97, 12
+    params = jax.eval_shape(lambda: model_api.init_params(
+        cfg, jax.random.PRNGKey(0)))
+    params = jax.tree_util.tree_map(
+        lambda x: _spec(x.shape, jnp.bfloat16, one_chip), params)
+    pool_shape = (L, n_pages, PAGE, cfg.n_kv_heads, cfg.hd)
+    pool = {"blocks": {"k": _spec(pool_shape, jnp.bfloat16, one_chip),
+                       "v": _spec(pool_shape, jnp.bfloat16, one_chip)}}
+    step = stepfn.make_paged_serve_step(cfg, ParallelismConfig())
+    compiled = jax.jit(step, donate_argnums=(3,)).lower(
+        params, _spec((SLOTS,), jnp.int32, one_chip),
+        _spec((SLOTS,), jnp.int32, one_chip), pool,
+        _spec((SLOTS, n_max), jnp.int32, one_chip)).compile()
+    hlo = compiled.as_text()
+    assert re.search(r"%paged_decode_attention(\.\d+)? = ", hlo)
+    # an op whose result holds as many elements as one layer's pool or the
+    # stacked pool, in any order of the dims, moves the pool
+    pool_sizes = {int(np.prod(pool_shape[1:])), int(np.prod(pool_shape))}
+    op = re.compile(r"^\s*(?:ROOT )?%(\S+) = \w+\[([\d,]*)\]\S* ([\w-]+)\(")
+    moves = re.compile(r"copy|gather|dynamic-slice|dynamic-update-slice")
+    for line in hlo.splitlines():
+        m = op.match(line)
+        if m and m.group(2) and int(np.prod(
+                [int(d) for d in m.group(2).split(",")])) in pool_sizes:
+            assert not moves.search(m.group(1) + " " + m.group(3)), line
+    pool_bytes = 2 * int(np.prod(pool_shape)) * 2
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < pool_bytes / 10, (temp, pool_bytes)
 
 
 def test_rmsnorm_compiles(one_chip):
