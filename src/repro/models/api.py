@@ -67,10 +67,6 @@ def supports_paged_cache(cfg: ModelConfig) -> bool:
     return family_of(cfg).supports_paged_cache(cfg)
 
 
-def init_paged_pool(cfg: ModelConfig, params, n_pages: int, page_size: int):
-    return family_of(cfg).init_paged_pool(cfg, params, n_pages, page_size)
-
-
 def paged_decode_step(cfg: ModelConfig, params, token, ts, pool, page_tables):
     return family_of(cfg).paged_decode_step(cfg, params, token, ts, pool,
                                             page_tables)

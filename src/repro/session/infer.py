@@ -168,12 +168,6 @@ class InferenceSession:
     # ------------------------------------------------------------------
     # block-paged KV pool steps (repro.session.kvpool)
     # ------------------------------------------------------------------
-    def init_paged_pool(self, n_pages: int, page_size: int):
-        """Device-side KV page pool, leaves (layers, n_pages, page_size,
-        n_kv_heads, head_dim) in compute dtype (page 0 is the trash page)."""
-        return model_api.init_paged_pool(self.cfg, self.params, n_pages,
-                                         page_size)
-
     @property
     def paged_prefill_step(self):
         """Jitted suffix prefill through page tables:
@@ -254,28 +248,23 @@ class InferenceSession:
               stop_token: Optional[int] = None,
               n_slots: Optional[int] = None,
               max_len: Optional[int] = None,
-              bucket_prefills: bool = True,
               paged: bool = False,
               page_size: int = 16,
-              n_pages: Optional[int] = None,
-              prefix_sharing: bool = True,
-              scheduler: Optional["ContinuousBatchingScheduler"] = None):
+              n_pages: Optional[int] = None):
         """Continuous-batching serve of a mixed-length request set.
         Returns (list of per-request 1-D token arrays in submit order,
         ``ServingStats``).
 
-        ``bucket_prefills`` pads admission prefills to power-of-two prompt
-        lengths (masked — outputs are unchanged) so a mixed-length workload
-        compiles O(log max_len) prefill shapes instead of one per distinct
-        prompt length; it is automatically disabled for families whose
-        prefill cannot mask padding (recurrent/state caches).
+        Admission prefills are padded to power-of-two prompt lengths
+        (masked — outputs are unchanged) on families whose prefill can mask
+        padding, so a mixed-length workload compiles O(log max_len) prefill
+        shapes instead of one per distinct prompt length.
 
         ``paged=True`` serves from the block-paged KV pool
         (``repro.session.kvpool``): per-request page tables over shared
         physical pages, prefix-cache reuse of identical prompt prefixes, and
         copy-on-write growth — greedy outputs stay token-identical to the
-        fixed-slot path.  Pass a previously returned ``scheduler`` to keep
-        its prefix cache warm across calls."""
+        fixed-slot path."""
         import numpy as np
         from repro.session.scheduler import (ContinuousBatchingScheduler,
                                              RequestQueue, ServingStats)
@@ -297,13 +286,10 @@ class InferenceSession:
         queue = RequestQueue()
         rids = [queue.submit(p, m, stop_token=stop_token)
                 for p, m in zip(prompts, mnt)]
-        sched = scheduler if scheduler is not None else \
-            ContinuousBatchingScheduler(self, n_slots=n_slots,
-                                        max_len=max_len,
-                                        bucket_prefills=bucket_prefills,
-                                        paged=paged, page_size=page_size,
-                                        n_pages=n_pages,
-                                        prefix_sharing=prefix_sharing)
+        sched = ContinuousBatchingScheduler(self, n_slots=n_slots,
+                                            max_len=max_len, paged=paged,
+                                            page_size=page_size,
+                                            n_pages=n_pages)
         outputs, stats = sched.run(queue)
         self.last_stats = stats
         return [outputs[r] for r in rids], stats

@@ -3,8 +3,7 @@
 The fixed-slot scheduler strands memory two ways: a 32-token reply in a
 4k-token slot wastes the slot's tail, and identical system prompts are
 re-prefilled (and re-stored) per request.  This module is the vLLM-style
-answer, host-side only — device pool arrays and page copies stay in the
-scheduler/session:
+answer; all but ``PagedLayout`` is host bookkeeping:
 
 ``PagePool``
     Free-list allocator over ``n_pages`` physical pages of ``page_size``
@@ -28,6 +27,9 @@ scheduler/session:
     COW boundary every device write crosses (allocate past the end, copy a
     page whose refcount exceeds one), retire releases the row.
 
+``PagedLayout``
+    The serving scheduler's cache layout over a manager and the device pool.
+
 Token j of logical page i sits at absolute position ``i*page_size + j`` —
 positions are implicit in the table, there is no per-token ``pos`` array.
 """
@@ -35,9 +37,11 @@ positions are implicit in the table, there is no per-token ``pos`` array.
 from __future__ import annotations
 
 import hashlib
+import weakref
 from collections import OrderedDict, deque
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
+import jax.numpy as jnp
 import numpy as np
 
 TRASH_PAGE = 0
@@ -247,8 +251,7 @@ class PagedKVManager:
             self.cache.evict(n)
             return self.pool.alloc(n)
 
-    def admit(self, slot: int, prompt: np.ndarray, *,
-              share: bool = True) -> int:
+    def admit(self, slot: int, prompt: np.ndarray) -> int:
         """Map pages for ``prompt`` into ``slot``: the longest cached prefix
         is shared (a partially-filled boundary page is COW-copied up front —
         the suffix prefill writes into it), fresh pages cover the rest.
@@ -259,7 +262,7 @@ class PagedKVManager:
         prompt = np.ascontiguousarray(prompt, dtype=np.int32)
         Lp = len(prompt)
         ps = self.pool.page_size
-        if share and self.cache is not None:
+        if self.cache is not None:
             pages, hist = self.cache.lookup(prompt, limit=Lp - 1)
         else:
             pages, hist = [], 0
@@ -339,3 +342,136 @@ class PagedKVManager:
 
     def capacity_tokens(self, slot: int) -> int:
         return int(self.n_mapped[slot]) * self.pool.page_size
+
+
+class PagedLayout:
+    """The scheduler's cache layout over the block-paged pool: admission
+    maps pages and prefills only the unshared suffix (rows carry their
+    ``hist_lens``, so mixed history depths share a trace); a decode step
+    first makes each write position writable (``ensure_writable``); retire
+    releases the slot's pages.
+
+    Admission control is by worst-case RESERVATION, not free pages: a
+    request enters only when its fully-decoded page count fits next to
+    every held request's.  That guarantee makes decode growth infallible —
+    live pages never exceed the reservation sum, and anything else in the
+    pool is cache-owned and evictable.  Requests that don't fit are
+    deferred (FIFO preserved).  The manager and the device pool are built
+    on the first run and kept, so the prefix cache persists between
+    request waves (the shared-system-prompt case)."""
+
+    def __init__(self, session, n_slots: int, max_len: int, *,
+                 page_size: int, n_pages: Optional[int] = None):
+        if not session.family.supports_paged_cache(session.cfg):
+            raise ValueError(
+                f"family {session.family.name!r} does not support the paged "
+                "KV pool (supports_paged_cache is False) — recurrent/state "
+                "caches stay on contiguous slots")
+        self.session = session
+        self.n_slots = n_slots
+        self.page_size = int(page_size)
+        self.n_max = -(-max_len // self.page_size)
+        self.cap = self.n_max * self.page_size   # widest prefill a row may take
+        # default pool: worst case of every slot fully grown, + trash page 0
+        self.n_pages = int(n_pages) if n_pages is not None \
+            else 1 + n_slots * self.n_max
+        self.mgr: Optional[PagedKVManager] = None
+        self.pool = None                # device pool, rebound by every write
+
+    def __str__(self) -> str:
+        return f"paged pool ({self.n_pages - 1} pages of {self.page_size})"
+
+    def _reserve_pages(self, req) -> int:
+        """Worst-case page count of a request fully decoded (every shared
+        page COW'd into an exclusive copy)."""
+        return -(-(len(req.prompt) + req.max_new_tokens) // self.page_size)
+
+    def check_fits(self, req) -> None:
+        need = self._reserve_pages(req)
+        if need > self.n_pages - 1:
+            raise ValueError(
+                f"request {req.rid}: needs {need} pages of "
+                f"{self.page_size} tokens but the pool only has "
+                f"{self.n_pages - 1} allocatable pages")
+
+    def start(self) -> None:
+        if self.mgr is None:
+            sess = self.session
+            self.pool = sess.family.init_paged_pool(
+                sess.cfg, sess.params, self.n_pages, self.page_size)
+            pages = PagePool(self.n_pages, self.page_size)
+            # a weak reference, not a bound method: a cycle would keep the
+            # device pool alive past the scheduler, until a cyclic gc
+            layout = weakref.ref(self)
+            self.mgr = PagedKVManager(
+                pages, self.n_slots, self.n_max,
+                prefix_cache=PrefixCache(pages),
+                copy_page=lambda src, dst: layout()._copy_page(src, dst))
+        self._hits0 = self.mgr.cache.hits
+        self._occupancy = 0.0
+
+    def _copy_page(self, src: int, dst: int) -> None:
+        self.pool = self.session.pool_copy_page(self.pool, jnp.int32(src),
+                                                jnp.int32(dst))
+
+    def admit(self, assignments, held):
+        """Map pages for ``assignments`` [(slot, request)] next to the
+        ``held`` requests' reservations.  Returns ([(slot, request, shared
+        history tokens)], [deferred requests])."""
+        avail = self.n_pages - 1 - sum(self._reserve_pages(r) for r in held)
+        rows, deferred = [], []
+        for slot, req in assignments:
+            need = self._reserve_pages(req)
+            if deferred or need > avail:        # keep FIFO order on pressure
+                deferred.append(req)
+                continue
+            try:
+                rows.append((slot, req, self.mgr.admit(slot, req.prompt)))
+                avail -= need
+            except MemoryError:
+                deferred.append(req)
+        return rows, deferred
+
+    def prefill(self, tokens, lengths, hists, slot_ids):
+        sess = self.session
+        batch = {"tokens": jnp.asarray(tokens),
+                 "hist_lens": jnp.asarray(hists),
+                 "lengths": jnp.asarray(lengths)}
+        logits, self.pool = sess.paged_prefill_step(
+            sess.params, batch, self.pool,
+            jnp.asarray(self.mgr.tables[slot_ids]))
+        return logits, None
+
+    def commit(self, rows, slot_ids, prompts) -> None:
+        """Publish the prefilled prompts' pages for future hits."""
+        for slot, prompt in zip(slot_ids, prompts):
+            self.mgr.register(slot, prompt)
+
+    def step(self, toks, ts, active: List[int]):
+        for slot in active:
+            self.mgr.ensure_writable(slot, int(ts[slot]))
+        sess = self.session
+        nxt, self.pool = sess.paged_slot_step(
+            sess.params, jnp.asarray(toks), jnp.asarray(ts), self.pool,
+            jnp.asarray(self.mgr.tables))
+        return nxt
+
+    def release(self, slot: int) -> None:
+        # no device zeroing needed: unmapped rows are masked at read time
+        self.mgr.free_slot(slot)
+
+    def capacity(self, active: List[int]) -> int:
+        """Tokens the active slots' mapped pages hold; also samples the
+        pool's occupancy for the step."""
+        self._occupancy += self.mgr.pool.n_used / (self.n_pages - 1)
+        return sum(self.mgr.capacity_tokens(i) for i in active)
+
+    def stats_fields(self, stats) -> dict:
+        steps, prompt = stats.decode_steps, stats.prompt_tokens
+        return dict(
+            page_size=self.page_size,
+            pool_pages=self.n_pages - 1,
+            pool_occupancy=self._occupancy / steps if steps else 0.0,
+            prefix_hits=self.mgr.cache.hits - self._hits0,
+            prefix_hit_rate=((prompt - stats.prefill_tokens) / prompt
+                             if prompt else 0.0))

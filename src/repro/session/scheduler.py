@@ -12,24 +12,23 @@ fixed ring of ``n_slots`` cache slots, where
     immediately;
   * queued requests are admitted mid-flight: ALL free slots are filled in
     one pass, and requests sharing a prefill width go through ONE batched
-    mixed-length prefill call (right-padded rows with per-row ``lengths``
-    and ``segment_ids`` = -1 on the pad tail, so the masked prefill stays on
-    the flash kernel); each resulting cache row is sliced out
-    (``stepfn.cache_take_slot``) and written into its slot
-    (``stepfn.cache_insert_slot``) — no other slot ever stalls or recompiles;
+    mixed-length prefill call (right-padded rows with per-row lengths) —
+    no other slot ever stalls or recompiles;
   * admission prefills are bucketed to power-of-two prompt lengths (pad to
     the bucket, gather logits at ``lengths-1``, invalidate padded cache
     slots) on causal-attention families, so mixed-length workloads compile
     at most log2(max_len) × n_slots prefill shapes instead of one per
     distinct length.
 
-Slot lifecycle works across every registered family's cache layout through
-the ``ModelFamily.cache_slot_axes`` hook (ring-buffer KV, SSM/sLSTM states,
-hybrid lists, cross-KV stacks).  Greedy decode here is token-for-token
-identical to running each request alone through ``generate()``.  Requests
-carry token prompts only: for encdec the slot template is built from the
-family's stubbed zero encoder frames, so per-request encoder inputs are a
-follow-up (the slot mechanics already cover the cross-KV layout).
+One loop serves two cache layouts: ``SlotLayout`` (contiguous per-slot
+caches, every family through the ``ModelFamily.cache_slot_axes`` hook:
+ring-buffer KV, SSM/sLSTM states, hybrid lists, cross-KV stacks) and
+``kvpool.PagedLayout`` (the block-paged pool, attention stacks only).
+Greedy decode here is token-for-token identical to running each request
+alone through ``generate()``.  Requests carry token prompts only: for
+encdec the slot template is built from the family's stubbed zero encoder
+frames, so per-request encoder inputs are a follow-up (the slot mechanics
+already cover the cross-KV layout).
 """
 
 from __future__ import annotations
@@ -42,6 +41,8 @@ from typing import Dict, List, Optional, Tuple
 import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
+
+from repro.session.kvpool import PagedLayout
 
 
 @dataclasses.dataclass
@@ -167,7 +168,7 @@ class RequestQueue:
 
     def push_front(self, req: Request) -> None:
         """Return a popped-but-unadmitted request to the head of the queue
-        (the paged scheduler defers admissions under pool pressure)."""
+        (the paged layout defers admissions under pool pressure)."""
         self._q.appendleft(req)
 
     def pending(self) -> Tuple[Request, ...]:
@@ -188,45 +189,30 @@ class _Slot:
     first_step: int = 0                # first decode step it takes part in
 
 
-class ContinuousBatchingScheduler:
-    """Slot-based continuous batching over an ``InferenceSession``.
+class SlotLayout:
+    """Contiguous per-slot caches, for every family's cache layout
+    (``ModelFamily.cache_slot_axes``): each slot holds ``max_len``
+    positions, an admitted row is prefilled into a zeroed template and
+    written into its slot whole, and a retired slot is zeroed at once.
+    ``kvpool.PagedLayout`` answers the same calls from the scheduler."""
 
-    ``n_slots`` is the decode batch width; ``max_len`` the per-slot cache
-    length (every admitted request needs prompt + max_new_tokens ≤ max_len).
-    """
-
-    def __init__(self, session, *, n_slots: int, max_len: int,
-                 bucket_prefills: bool = True, paged: bool = False,
-                 page_size: int = 16, n_pages: Optional[int] = None,
-                 prefix_sharing: bool = True):
-        if n_slots < 1:
-            raise ValueError("n_slots must be >= 1")
+    def __init__(self, session, n_slots: int, max_len: int):
         self.session = session
-        self.n_slots = int(n_slots)
-        self.max_len = int(max_len)
-        # admission prefills retrace per distinct prompt shape; padding to
-        # power-of-two buckets bounds the trace count at log2(max_len) on
-        # families whose prefill honors batch["lengths"] (causal attention
-        # stacks — see ModelFamily.supports_padded_prefill)
-        self.bucket_prefills = bool(bucket_prefills) and \
-            session.family.supports_padded_prefill(session.cfg)
-        self._fresh = None             # immutable width-n_slots cache template
-        # --- block-paged KV pool mode (repro.session.kvpool) ----------
-        self.paged = bool(paged)
-        if self.paged and not session.family.supports_paged_cache(session.cfg):
-            raise ValueError(
-                f"family {session.family.name!r} does not support the paged "
-                "KV pool (supports_paged_cache is False) — recurrent/state "
-                "caches stay on contiguous slots")
-        self.page_size = int(page_size)
-        self.n_max = -(-self.max_len // self.page_size)
-        # default pool: worst case of every slot fully grown, + trash page 0
-        self.n_pages = int(n_pages) if n_pages is not None \
-            else 1 + self.n_slots * self.n_max
-        self.prefix_sharing = bool(prefix_sharing)
-        self._paged_state = None       # (PagedKVManager, device-pool holder)
+        self.n_slots = n_slots
+        self.max_len = max_len
+        self.cap = max_len                # widest prefill a row may take
+        self._fresh = None                # immutable width-n_slots template
+        self.caches = None
 
-    # ------------------------------------------------------------------
+    def start(self) -> None:
+        self.caches = self.session.init_cache(self.n_slots, self.max_len)
+
+    def check_fits(self, req: Request) -> None:
+        """A request within ``max_len`` fits any slot."""
+
+    def admit(self, assignments, held):
+        return [(slot, req, 0) for slot, req in assignments], []
+
     def _fresh_cache(self, width: int):
         """Zeroed width-``width`` prefill template.  Only the full-width
         template is retained; narrower admissions slice it, so the scheduler
@@ -238,20 +224,75 @@ class ContinuousBatchingScheduler:
             return self._fresh
         return stepfn.cache_slice_slots(self.session.cfg, self._fresh, 0, width)
 
-    def _check_fits(self, req: Request) -> None:
-        P = len(req.prompt)
-        if P + req.max_new_tokens > self.max_len:
-            raise ValueError(
-                f"request {req.rid}: prompt {P} + max_new {req.max_new_tokens} "
-                f"exceeds scheduler max_len {self.max_len}")
-        if self.paged:
-            need = -(-(P + req.max_new_tokens) // self.page_size)
-            if need > self.n_pages - 1:
-                raise ValueError(
-                    f"request {req.rid}: needs {need} pages of "
-                    f"{self.page_size} tokens but the pool only has "
-                    f"{self.n_pages - 1} allocatable pages")
+    def prefill(self, tokens, lengths, hists, slot_ids):
+        sess = self.session
+        W, L = tokens.shape
+        batch = {"tokens": jnp.asarray(tokens)}
+        if (lengths != L).any():
+            batch["lengths"] = jnp.asarray(lengths)
+            # real tokens get segment 0, the pad tail -1: no row ever
+            # attends into its padding, on any sdpa path
+            batch["segment_ids"] = jnp.asarray(
+                np.where(np.arange(L)[None] < lengths[:, None], 0, -1)
+                .astype(np.int32))
+        return sess.prefill_cache_step(sess.params, batch,
+                                       self._fresh_cache(W))
 
+    def commit(self, rows, slot_ids, prompts) -> None:
+        """Write each prefilled width-1 cache row into its slot."""
+        sess = self.session
+        for j, slot in enumerate(slot_ids):
+            row = rows if len(slot_ids) == 1 else sess.take_slot(
+                rows, jnp.int32(j))
+            self.caches = sess.insert_slot(self.caches, row, jnp.int32(slot))
+
+    def step(self, toks, ts, active: List[int]):
+        sess = self.session
+        nxt, self.caches = sess.slot_step(sess.params, jnp.asarray(toks),
+                                          jnp.asarray(ts), self.caches)
+        return nxt
+
+    def release(self, slot: int) -> None:
+        # reset the freed slot on device (pos → -1, state → 0): stale K/V
+        # must be invalid the moment the slot is free, not only after the
+        # next admission happens to overwrite it
+        self.caches = self.session.zero_slot(self.caches, jnp.int32(slot))
+
+    def capacity(self, active: List[int]) -> int:
+        return len(active) * self.max_len
+
+    def stats_fields(self, stats: ServingStats) -> dict:
+        return {}
+
+
+class ContinuousBatchingScheduler:
+    """Slot-based continuous batching over an ``InferenceSession``.
+
+    ``n_slots`` is the decode batch width; ``max_len`` the per-slot cache
+    length (every admitted request needs prompt + max_new_tokens ≤ max_len).
+    ``paged=True`` keeps the KV state in ``kvpool.PagedLayout`` (``n_pages``
+    pages of ``page_size`` tokens), else in ``SlotLayout``.
+    """
+
+    def __init__(self, session, *, n_slots: int, max_len: int,
+                 paged: bool = False, page_size: int = 16,
+                 n_pages: Optional[int] = None):
+        if n_slots < 1:
+            raise ValueError("n_slots must be >= 1")
+        self.session = session
+        self.n_slots = int(n_slots)
+        self.max_len = int(max_len)
+        # admission prefills retrace per distinct prompt shape; padding to
+        # power-of-two buckets bounds the trace count at log2(max_len) on
+        # families whose prefill honors batch["lengths"] (causal attention
+        # stacks — see ModelFamily.supports_padded_prefill)
+        self.bucket_prefills = session.family.supports_padded_prefill(
+            session.cfg)
+        self.layout = PagedLayout(session, self.n_slots, self.max_len,
+                                  page_size=page_size, n_pages=n_pages) \
+            if paged else SlotLayout(session, self.n_slots, self.max_len)
+
+    # ------------------------------------------------------------------
     def _bucket_len(self, P: int) -> int:
         """Power-of-two prefill bucket for a prompt of length ``P``, capped
         at the slot's cache length (position p and p+size would collide in
@@ -259,102 +300,90 @@ class ContinuousBatchingScheduler:
         assert P >= 1, "empty prompts are rejected at RequestQueue.submit"
         return min(max(1 << (P - 1).bit_length(), 16), self.max_len)
 
-    def _admit_many(self, caches, assignments: List[Tuple[int, Request]],
-                    clock) -> Tuple:
-        """Batched prefill-then-insert: requests sharing a prefill width
-        (their bucket, or exact length when bucketing is off) are ingested in
-        ONE mixed-length prefill call — shorter prompts ride right-padded
-        with per-row ``lengths`` and ``segment_ids`` (-1 on the pad tail, so
-        the masked prefill stays on the flash kernel) — and each resulting
-        width-1 cache row is written into its slot.  Returns
-        (caches, {slot_idx: _Slot})."""
-        sess = self.session
-        groups: Dict[int, List[Tuple[int, Request]]] = {}
-        for slot_idx, req in assignments:
-            self._check_fits(req)
-            P = len(req.prompt)
-            L = self._bucket_len(P) if self.bucket_prefills else P
-            groups.setdefault(L, []).append((slot_idx, req))
+    def _admit(self, assignments: List[Tuple[int, Request]],
+               held: List[Request], clock) -> Tuple:
+        """Batched admission next to the requests ``held`` in other slots.
+        The layout maps each request, giving the prompt tokens its cache
+        already holds (``hist``), or defers it.  Requests sharing a prefill
+        width (their suffix's bucket, or exact length when the family cannot
+        bucket) are ingested in ONE mixed-length prefill call — shorter
+        suffixes ride right-padded with per-row lengths — and their first
+        tokens are read once.  Returns ({slot: _Slot}, [deferred requests],
+        prefilled tokens)."""
+        rows, deferred = self.layout.admit(assignments, held)
+        groups: Dict[int, List[Tuple[int, Request, int]]] = {}
+        for slot, req, hist in rows:
+            n = len(req.prompt) - hist
+            L = min(self._bucket_len(n), self.layout.cap - hist) \
+                if self.bucket_prefills else n
+            groups.setdefault(L, []).append((slot, req, hist))
 
         states: Dict[int, _Slot] = {}
+        prefilled = 0
         for L, items in sorted(groups.items()):
             W = len(items)
             tokens = np.zeros((W, L), np.int32)
             lengths = np.zeros((W,), np.int32)
-            for j, (_, req) in enumerate(items):
-                tokens[j, :len(req.prompt)] = req.prompt
-                lengths[j] = len(req.prompt)
-            batch = {"tokens": jnp.asarray(tokens)}
-            if (lengths != L).any():
-                batch["lengths"] = jnp.asarray(lengths)
-                # real tokens get segment 0, the pad tail -1: no row ever
-                # attends into its padding, on any sdpa path
-                batch["segment_ids"] = jnp.asarray(
-                    np.where(np.arange(L)[None] < lengths[:, None], 0, -1)
-                    .astype(np.int32))
-            logits, group_c = sess.prefill_cache_step(
-                sess.params, batch, self._fresh_cache(W))
+            hists = np.zeros((W,), np.int32)
+            for j, (_, req, hist) in enumerate(items):
+                suffix = req.prompt[hist:]
+                tokens[j, :len(suffix)] = suffix
+                lengths[j] = len(suffix)
+                hists[j] = hist
+            slot_ids = [slot for slot, _, _ in items]
+            logits, written = self.layout.prefill(tokens, lengths, hists,
+                                                  slot_ids)
             toks0 = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
             first_token_time = clock()
-            for j, (slot_idx, req) in enumerate(items):
-                slot_c = group_c if W == 1 else sess.take_slot(
-                    group_c, jnp.int32(j))
-                caches = sess.insert_slot(caches, slot_c, jnp.int32(slot_idx))
+            self.layout.commit(written, slot_ids,
+                               [req.prompt for _, req, _ in items])
+            prefilled += int(lengths.sum())
+            for j, (slot, req, _) in enumerate(items):
                 req.first_token_time = first_token_time
-                P = len(req.prompt)
-                states[slot_idx] = _Slot(
-                    req=req, t=P, last=int(toks0[j]),
+                states[slot] = _Slot(
+                    req=req, t=len(req.prompt), last=int(toks0[j]),
                     out=list(map(int, req.prompt)) + [int(toks0[j])],
                     remaining=req.max_new_tokens - 1)
-        return caches, states
+        return states, deferred, prefilled
 
     @staticmethod
     def _finished(state: _Slot) -> bool:
         stop = state.req.stop_token
         return state.remaining <= 0 or (stop is not None and state.last == stop)
 
-    @staticmethod
-    def _admitted(state: _Slot, t_admit: float, steps: int) -> None:
-        """Stamp an admission that started at ``t_admit``, before decode
-        step ``steps``."""
-        state.req.admit_time = t_admit
-        state.first_step = steps
-
     # ------------------------------------------------------------------
     def run(self, queue: RequestQueue,
             clock=time.perf_counter) -> Tuple[Dict[int, np.ndarray], ServingStats]:
         """Drain ``queue``; returns ({rid: prompt+generated token array},
         ``ServingStats``)."""
-        if self.paged:
-            return self._run_paged(queue, clock)
-        sess = self.session
+        layout = self.layout
         B = self.n_slots
         # preflight: reject impossible requests before ANY decode work, so a
         # bad request can't abort a half-drained queue and lose finished
         # outputs (requests are only popped once they fit)
         for req in queue.pending():
-            self._check_fits(req)
-        caches = sess.init_cache(B, self.max_len)
+            P = len(req.prompt)
+            if P + req.max_new_tokens > self.max_len:
+                raise ValueError(
+                    f"request {req.rid}: prompt {P} + max_new "
+                    f"{req.max_new_tokens} exceeds scheduler max_len "
+                    f"{self.max_len}")
+            layout.check_fits(req)
+        layout.start()
         slots: List[Optional[_Slot]] = [None] * B
         outputs: Dict[int, np.ndarray] = {}
         stats = ServingStats()
-        steps = 0
-        occupied = 0
-        generated = 0
-        n_requests = 0
-        prompt_tokens = 0
+        steps = occupied = generated = n_requests = 0
+        prompt_tokens = prefill_tokens = 0
         stranded = 0.0
 
         def retire(i: int):
-            nonlocal generated, caches
+            nonlocal generated
             st = slots[i]
             outputs[st.req.rid] = np.asarray(st.out, np.int32)
             stats.finished(st, steps)
             generated += len(st.out) - len(st.req.prompt)
-            # reset the freed slot on device (pos → -1, state → 0): stale
-            # K/V must be invalid the moment the slot is free, not only
-            # after the next admission happens to overwrite it
-            caches = sess.zero_slot(caches, jnp.int32(i))
+            layout.release(i)
             slots[i] = None
 
         while len(queue) or any(s is not None for s in slots):
@@ -366,11 +395,22 @@ class ContinuousBatchingScheduler:
                     t_admit = clock()
                     assignments = [(i, queue.pop())
                                    for i in free[:min(len(free), len(queue))]]
-                    caches, admitted = self._admit_many(caches, assignments,
-                                                        clock)
+                    admitted, deferred, prefilled = self._admit(
+                        assignments, [s.req for s in slots if s is not None],
+                        clock)
+                    for req in reversed(deferred):
+                        queue.push_front(req)
+                    if deferred and not admitted and \
+                            all(s is None for s in slots):
+                        raise MemoryError(
+                            f"{layout} cannot admit request "
+                            f"{deferred[0].rid} even with every slot idle — "
+                            "grow n_pages or shrink max_len")
+                    prefill_tokens += prefilled
                     for i, st in admitted.items():
                         slots[i] = st
-                        self._admitted(st, t_admit, steps)
+                        st.req.admit_time = t_admit
+                        st.first_step = steps
                         n_requests += 1
                         prompt_tokens += len(st.req.prompt)
                         if self._finished(st):     # stop token in prefill,
@@ -388,8 +428,7 @@ class ContinuousBatchingScheduler:
                 for i in active:
                     toks[i] = slots[i].last
                     ts[i] = slots[i].t
-                nxt, caches = sess.slot_step(sess.params, jnp.asarray(toks),
-                                             jnp.asarray(ts), caches)
+                nxt = layout.step(toks, ts, active)
             with TraceAnnotation("serve.read"):
                 nxt = np.asarray(nxt)
             stats.step_times.append(clock())
@@ -397,223 +436,7 @@ class ContinuousBatchingScheduler:
                 steps += 1
                 occupied += len(active)
                 live = sum(slots[i].t for i in active)
-                stranded += 1.0 - live / (len(active) * self.max_len)
-
-                for i in active:
-                    st = slots[i]
-                    st.last = int(nxt[i])
-                    st.out.append(st.last)
-                    st.t += 1
-                    st.remaining -= 1
-                    if self._finished(st):
-                        retire(i)
-
-        stats = dataclasses.replace(
-            stats,
-            requests=n_requests,
-            generated_tokens=generated,
-            decode_steps=steps,
-            occupancy=occupied / (steps * B) if steps else 0.0,
-            max_queue_depth=queue.max_depth,
-            stranded_fraction=stranded / steps if steps else 0.0,
-            prompt_tokens=prompt_tokens,
-            prefill_tokens=prompt_tokens,     # fixed slots re-prefill it all
-        )
-        return outputs, stats
-
-    # ------------------------------------------------------------------
-    # block-paged KV pool mode (repro.session.kvpool)
-    # ------------------------------------------------------------------
-    def _paged(self):
-        """Lazy (manager, device-pool holder) — built once and kept across
-        ``run()`` calls so the prefix cache persists between request waves
-        (the shared-system-prompt case)."""
-        if self._paged_state is None:
-            from repro.session import kvpool
-            sess = self.session
-            holder = {"pool": sess.init_paged_pool(self.n_pages,
-                                                   self.page_size)}
-
-            def copy_page(src: int, dst: int) -> None:
-                holder["pool"] = sess.pool_copy_page(
-                    holder["pool"], jnp.int32(src), jnp.int32(dst))
-
-            pool = kvpool.PagePool(self.n_pages, self.page_size)
-            cache = kvpool.PrefixCache(pool) if self.prefix_sharing else None
-            mgr = kvpool.PagedKVManager(pool, self.n_slots, self.n_max,
-                                        prefix_cache=cache,
-                                        copy_page=copy_page)
-            self._paged_state = (mgr, holder)
-        return self._paged_state
-
-    def _reserve_pages(self, req: Request) -> int:
-        """Worst-case page count of a request fully decoded (every shared
-        page COW'd into an exclusive copy)."""
-        return -(-(len(req.prompt) + req.max_new_tokens) // self.page_size)
-
-    def _admit_many_paged(self, mgr, holder, assignments, clock, reserved):
-        """Paged admission: map pages (longest cached prefix shared, COW on
-        a partial boundary page), then ONE batched suffix prefill per shared
-        padded width — rows carry per-request ``hist_lens`` so mixed history
-        depths share a trace.
-
-        Admission control is by worst-case RESERVATION, not free pages: a
-        request enters only when its fully-decoded page count fits next to
-        every active request's (``reserved``).  That guarantee makes decode
-        growth infallible — live pages never exceed the reservation sum, and
-        anything else in the pool is cache-owned and evictable.  Requests
-        that don't fit are handed back for re-queueing (FIFO preserved).
-        Returns ({slot: _Slot}, [deferred requests], prompt_toks,
-        prefill_toks, shared_toks)."""
-        sess = self.session
-        ps = mgr.pool.page_size
-        avail = self.n_pages - 1 - reserved
-        items = []                              # (slot, req, hist)
-        deferred = []
-        for slot_idx, req in assignments:
-            self._check_fits(req)
-            need = self._reserve_pages(req)
-            if deferred or need > avail:        # keep FIFO order on pressure
-                deferred.append(req)
-                continue
-            try:
-                items.append((slot_idx, req, mgr.admit(slot_idx, req.prompt,
-                                                       share=self.prefix_sharing)))
-                avail -= need
-            except MemoryError:
-                deferred.append(req)
-
-        groups: Dict[int, List[Tuple[int, Request, int]]] = {}
-        for slot_idx, req, hist in items:
-            Ls = len(req.prompt) - hist
-            L = min(self._bucket_len(Ls), mgr.n_max * ps - hist) \
-                if self.bucket_prefills else Ls
-            groups.setdefault(L, []).append((slot_idx, req, hist))
-
-        states: Dict[int, _Slot] = {}
-        prompt_toks = prefill_toks = shared_toks = 0
-        for L, rows in sorted(groups.items()):
-            W = len(rows)
-            tokens = np.zeros((W, L), np.int32)
-            hists = np.zeros((W,), np.int32)
-            lens = np.zeros((W,), np.int32)
-            slot_ids = np.zeros((W,), np.int64)
-            for j, (slot_idx, req, hist) in enumerate(rows):
-                suffix = req.prompt[hist:]
-                tokens[j, :len(suffix)] = suffix
-                hists[j] = hist
-                lens[j] = len(suffix)
-                slot_ids[j] = slot_idx
-            batch = {"tokens": jnp.asarray(tokens),
-                     "hist_lens": jnp.asarray(hists),
-                     "lengths": jnp.asarray(lens)}
-            logits, holder["pool"] = sess.paged_prefill_step(
-                sess.params, batch, holder["pool"],
-                jnp.asarray(mgr.tables[slot_ids]))
-            toks0 = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
-            first_token_time = clock()
-            for j, (slot_idx, req, hist) in enumerate(rows):
-                mgr.register(slot_idx, req.prompt)  # publish for future hits
-                req.first_token_time = first_token_time
-                P = len(req.prompt)
-                prompt_toks += P
-                prefill_toks += P - hist
-                shared_toks += hist
-                states[slot_idx] = _Slot(
-                    req=req, t=P, last=int(toks0[j]),
-                    out=list(map(int, req.prompt)) + [int(toks0[j])],
-                    remaining=req.max_new_tokens - 1)
-        return states, deferred, prompt_toks, prefill_toks, shared_toks
-
-    def _run_paged(self, queue: RequestQueue,
-                   clock=time.perf_counter) -> Tuple[Dict[int, np.ndarray], ServingStats]:
-        """The ``run()`` loop over the block-paged pool: admission maps
-        pages instead of copying slot caches, every decode step grows each
-        request by at most one page (``ensure_writable`` — the COW
-        boundary), retire releases pages back to the free list."""
-        sess = self.session
-        B = self.n_slots
-        for req in queue.pending():
-            self._check_fits(req)
-        mgr, holder = self._paged()
-        hits0 = mgr.cache.hits if mgr.cache is not None else 0
-        slots: List[Optional[_Slot]] = [None] * B
-        outputs: Dict[int, np.ndarray] = {}
-        stats = ServingStats()
-        steps = occupied = generated = n_requests = 0
-        prompt_tokens = prefill_tokens = shared_tokens = 0
-        pool_occ = stranded = 0.0
-
-        def retire(i: int):
-            nonlocal generated
-            st = slots[i]
-            outputs[st.req.rid] = np.asarray(st.out, np.int32)
-            stats.finished(st, steps)
-            generated += len(st.out) - len(st.req.prompt)
-            mgr.free_slot(i)        # release pages; no device zeroing needed:
-            slots[i] = None         # unmapped rows are masked at read time
-
-        while len(queue) or any(s is not None for s in slots):
-            free = [i for i in range(B) if slots[i] is None]
-            if free and len(queue):
-                with TraceAnnotation("serve.admit"):
-                    t_admit = clock()
-                    assignments = [(i, queue.pop())
-                                   for i in free[:min(len(free), len(queue))]]
-                    reserved = sum(self._reserve_pages(slots[i].req)
-                                   for i in range(B) if slots[i] is not None)
-                    admitted, deferred, ptk, ftk, stk = \
-                        self._admit_many_paged(mgr, holder, assignments,
-                                               clock, reserved)
-                    for req in reversed(deferred):
-                        queue.push_front(req)
-                    if deferred and not admitted and \
-                            all(s is None for s in slots):
-                        raise MemoryError(
-                            f"paged pool ({self.n_pages - 1} pages of "
-                            f"{self.page_size}) cannot admit request "
-                            f"{deferred[0].rid} even with every slot idle — "
-                            "grow n_pages or shrink max_len")
-                    prompt_tokens += ptk
-                    prefill_tokens += ftk
-                    shared_tokens += stk
-                    for i, st in admitted.items():
-                        slots[i] = st
-                        self._admitted(st, t_admit, steps)
-                        n_requests += 1
-                        if self._finished(st):
-                            retire(i)
-                    stats.admit_s += clock() - t_admit
-
-            active = [i for i in range(B) if slots[i] is not None]
-            if not active:
-                continue
-
-            with TraceAnnotation("serve.decode"):
-                # next write position must be mapped & exclusively owned
-                # (lazy page growth + the COW copy of shared/registered
-                # pages)
-                for i in active:
-                    mgr.ensure_writable(i, slots[i].t)
-
-                toks = np.zeros((B,), np.int32)
-                ts = np.zeros((B,), np.int32)
-                for i in active:
-                    toks[i] = slots[i].last
-                    ts[i] = slots[i].t
-                nxt, holder["pool"] = sess.paged_slot_step(
-                    sess.params, jnp.asarray(toks), jnp.asarray(ts),
-                    holder["pool"], jnp.asarray(mgr.tables))
-            with TraceAnnotation("serve.read"):
-                nxt = np.asarray(nxt)
-            stats.step_times.append(clock())
-            with TraceAnnotation("serve.retire"):
-                steps += 1
-                occupied += len(active)
-                pool_occ += mgr.pool.n_used / (self.n_pages - 1)
-                live = sum(slots[i].t for i in active)
-                cap = sum(mgr.capacity_tokens(i) for i in active)
-                stranded += 1.0 - live / cap if cap else 0.0
+                stranded += 1.0 - live / layout.capacity(active)
 
                 for i in active:
                     st = slots[i]
@@ -634,12 +457,6 @@ class ContinuousBatchingScheduler:
             stranded_fraction=stranded / steps if steps else 0.0,
             prompt_tokens=prompt_tokens,
             prefill_tokens=prefill_tokens,
-            page_size=self.page_size,
-            pool_pages=self.n_pages - 1,
-            pool_occupancy=pool_occ / steps if steps else 0.0,
-            prefix_hits=(mgr.cache.hits - hits0 if mgr.cache is not None
-                         else 0),
-            prefix_hit_rate=(shared_tokens / prompt_tokens
-                             if prompt_tokens else 0.0),
         )
-        return outputs, stats
+        return outputs, dataclasses.replace(stats,
+                                            **layout.stats_fields(stats))

@@ -390,6 +390,32 @@ def test_cow_sibling_isolation():
             np.testing.assert_array_equal(outputs[rid], ref)
 
 
+def test_device_pool_freed_with_scheduler():
+    """The paged layout's device pool goes when its scheduler goes, without
+    waiting for Python's cyclic collector: serving a bench's warm-up and
+    window calls back to back holds one pool on the device, not several."""
+    import gc
+    import weakref
+
+    import jax
+    from repro.session import RequestQueue
+    sess = _session("granite_3_2b")
+    sysp = _prompts(sess, (8,), seed=11)[0]
+    sched = ContinuousBatchingScheduler(sess, n_slots=2, max_len=32,
+                                        paged=True, page_size=8)
+    queue = RequestQueue()
+    for p in _prompts(sess, (3, 5), seed=12, prefix=sysp):
+        queue.submit(p, 4)
+    gc.disable()
+    try:
+        sched.run(queue)
+        leaf = weakref.ref(jax.tree_util.tree_leaves(sched.layout.pool)[0])
+        del sched
+        assert leaf() is None
+    finally:
+        gc.enable()
+
+
 def test_pool_pressure_defers_admission():
     """A pool too small for every request at once still drains the queue
     correctly: admissions the free list can't hold are deferred (FIFO
@@ -397,11 +423,10 @@ def test_pool_pressure_defers_admission():
     sess = _session("granite_3_2b")
     prompts = _prompts(sess, (8, 8, 8))
     budgets = [6, 6, 6]
-    # each request needs ceil(14/8)=2 pages; 5 allocatable pages < 3*2, so
-    # the third admission must wait for a retire (prefix sharing off keeps
-    # the arithmetic exact)
+    # each request reserves ceil(14/8)=2 pages; 5 allocatable pages < 3*2,
+    # so the third admission must wait for a retire
     outs, stats = sess.serve(prompts, budgets, n_slots=3, paged=True,
-                             page_size=8, n_pages=6, prefix_sharing=False)
+                             page_size=8, n_pages=6)
     for p, m, o in zip(prompts, budgets, outs):
         ref = np.asarray(sess.generate(jnp.asarray(p)[None], m)[0])
         np.testing.assert_array_equal(o, ref)

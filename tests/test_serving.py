@@ -129,6 +129,30 @@ def test_stats_stamp_steps_and_requests(paged):
     assert sorted(stats.token_gaps()) == [1.0, 1.0, 2.0, 2.0, 4.0]
 
 
+@pytest.mark.parametrize("n_slots", [2, 3])
+def test_layouts_serve_identically(n_slots):
+    """The slot caches and the paged pool are two cache layouts behind one
+    loop: a mixed-length request set with a stop token gives the same
+    tokens, the same counts and the same number of clock stamps through
+    both."""
+    sess = _session("granite_3_2b")
+    prompts = _prompts(sess, (5, 9, 17, 3, 12), seed=3)
+    budgets = [10, 3, 6, 8, 5]
+    free = np.asarray(sess.generate(jnp.asarray(prompts[0])[None], 10)[0])
+    stop = int(free[len(prompts[0]) + 4])        # request 0 stops early
+    (slot_outs, slots), (paged_outs, paged) = [
+        sess.serve(prompts, budgets, stop_token=stop, n_slots=n_slots,
+                   paged=p, page_size=8) for p in (False, True)]
+    assert len(slot_outs[0]) <= len(prompts[0]) + 5
+    for a, b in zip(slot_outs, paged_outs):
+        np.testing.assert_array_equal(a, b)
+    for field in ("requests", "generated_tokens", "decode_steps",
+                  "occupancy"):
+        assert getattr(slots, field) == getattr(paged, field), field
+    assert len(slots.step_times) == len(paged.step_times)
+    assert len(slots.token_gaps()) == len(paged.token_gaps())
+
+
 def test_stop_token_frees_slot_early():
     """A request whose greedy decode hits its stop token ends there: the
     scheduler returns the truncated sequence and the static ``generate``
@@ -257,7 +281,7 @@ def test_serve_empty_and_stats():
 def test_prefill_bucketing_bounds_shapes_and_preserves_outputs():
     """Admission prefills are padded to power-of-two buckets: distinct prompt
     lengths hit at most log2(max_len) prefill shapes, and outputs stay
-    token-for-token identical to the unbucketed path."""
+    token-for-token identical to each request decoded alone."""
     sess = _session("granite_3_2b")
     lens = (5, 6, 7, 9, 11, 12)
     prompts = _prompts(sess, lens)
@@ -276,10 +300,9 @@ def test_prefill_bucketing_bounds_shapes_and_preserves_outputs():
     finally:
         sess._prefill_cache_step = inner
     assert set(shapes) == {16}, shapes           # all six lengths → one bucket
-    outs_raw, _ = sess.serve(prompts, budgets, n_slots=2, max_len=32,
-                             bucket_prefills=False)
-    for a, b in zip(outs, outs_raw):
-        np.testing.assert_array_equal(a, b)
+    for p, m, o in zip(prompts, budgets, outs):
+        ref = np.asarray(sess.generate(jnp.asarray(p)[None], m)[0])
+        np.testing.assert_array_equal(o, ref)
 
 
 def test_cache_zero_slot_resets_to_init_state():
